@@ -56,7 +56,7 @@ def bench_cct(num_clients: int, client_block: int, timed_rounds: int = 5,
                          malicious_prefix=f)
 
     state, m = step(state, x, y, ln, mal, jax.random.PRNGKey(1))
-    _ = float(m["train_loss"])  # concrete fetch (relay-safe timing)
+    _ = float(m["train_loss"])  # concrete fetch inside the timed region
 
     t0 = time.perf_counter()
     for r in range(timed_rounds):

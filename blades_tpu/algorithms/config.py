@@ -118,7 +118,8 @@ class FedavgConfig:
         # (ops/pallas_round.py): None = defer to the
         # BLADES_TPU_MXU_FINISH env default, "" = VPU reductions,
         # "counts" = radix counts on the MXU (bit-exact), "all" = also
-        # the forged-row stats (f32 reassociation ulps).  The env var
+        # the forged-row stats (bf16-pass MXU precision, ~5e-3 relative
+        # on a v5e).  The env var
         # remains an explicit per-process override over this field.
         self.mxu_finish: Optional[str] = None
         # Execution autotuner (perf/autotune.py): False/"off" disables;

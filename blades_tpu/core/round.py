@@ -679,9 +679,8 @@ class FedRound:
     ) -> Tuple[RoundState, dict]:
         """``num_rounds`` FL rounds as ONE ``lax.scan``-ed XLA program.
 
-        The hot-loop form: host dispatch (and, under remote-execution
-        relays, per-call latency) is paid once per chunk instead of once
-        per round.  Metrics come back stacked ``(num_rounds, ...)``.
+        The hot-loop form: host dispatch is paid once per chunk instead
+        of once per round.  Metrics come back stacked ``(num_rounds, ...)``.
         Jit with ``static_argnums`` on ``num_rounds`` or wrap in a
         functools.partial.
         """

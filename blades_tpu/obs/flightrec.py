@@ -1,7 +1,7 @@
 """Divergence flight recorder: the last K rounds, durable on failure.
 
-Motivation (ISSUE 12): a failure on the relay box today leaves nothing
-behind but a truncated ``metrics.jsonl`` — no answer to *which round*
+Motivation (ISSUE 12): without it a failure leaves nothing behind but
+a truncated ``metrics.jsonl`` — no answer to *which round*
 diverged, *what the update matrix looked like*, or *how to re-execute
 it*.  The recorder keeps a bounded host-side ring of per-round digests
 (the finalized metrics row: norms, aggregate norm, diagnose masks,

@@ -36,8 +36,7 @@ coordinate from HBM — a 4x traffic cut against the f32 matrix the
 f32-domain path traverses — and the self-contractions ride the MXU's
 int8 path: Gram stripes and row squared norms accumulate int8*int8 ->
 int32 EXACTLY (|q| <= 127 over a 512-wide stripe is ~8.3e6 << 2^31)
-before joining the cross-stripe f32 accumulator, and the sign counts
-read comparisons straight off the integers.  Mixed contractions (dots
+before joining the cross-stripe f32 accumulator.  Mixed contractions (dots
 against replicated f32 vectors, f32 row weights) cast the resident
 stripe to f32 in VMEM — the HBM read is still one byte.  Per-row scale
 algebra (``s_i s_j`` on the Gram, ``s_i²`` on the norms, weight folding)
@@ -61,12 +60,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from blades_tpu.ops.pallas_select import _BLOCK_D
+from blades_tpu.ops.pallas_select import _BLOCK_D, stripe_compiler_params
 from blades_tpu.ops.pallas_select import kernel_applicable as _select_gate
 
 # VMEM height bound when the (n, n) f32 Gram accumulator is in the
-# bundle: 1024^2 f32 = 4 MiB + the (n, 512) stripe ~2 MiB against the
-# ~16 MiB budget; past it the planner chunk-loops the bundle instead.
+# bundle: 1024^2 f32 = 4 MiB a buffer beside the stripe and a 4 MiB dot
+# temporary; past it the planner chunk-loops the bundle instead.
 _GRAM_MAX_N = 1024
 
 
@@ -141,8 +140,11 @@ def _rowstats_kernel(*refs, want_sq: bool, want_gram: bool, want_signs: bool,
                 x, x, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
     if signs_ref is not None:
-        pos = jnp.sum((raw > 0).astype(jnp.float32), axis=1, keepdims=True)
-        neg = jnp.sum((raw < 0).astype(jnp.float32), axis=1, keepdims=True)
+        # Compared on the f32 cast: Mosaic refuses comparisons on packed
+        # bf16/int8 vectors on a v5e ("Target does not support this
+        # comparison"), and the cast is exact for both.
+        pos = jnp.sum((x > 0).astype(jnp.float32), axis=1, keepdims=True)
+        neg = jnp.sum((x < 0).astype(jnp.float32), axis=1, keepdims=True)
         signs_ref[...] += jnp.concatenate([pos, neg], axis=1)
     if dots_ref is not None:
         v = dv_ref[...]  # (R, block_d) stripe of the replicated vectors
@@ -281,6 +283,10 @@ def row_stats_bundle(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
+        # The (n, n) f32 Gram block and its dot temporary share VMEM
+        # with the stripe.
+        compiler_params=stripe_compiler_params(
+            npad, extra_bytes=4 * npad * npad * 4 if gram else 0),
         interpret=interpret,
     )(*inputs)
 

@@ -24,33 +24,73 @@ single-chip streamed round (:mod:`blades_tpu.parallel.streamed`).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Column-stripe width per grid step: (n, 512) f32 keys + values fit VMEM
-# comfortably up to n ≈ 4000.
+# Column-stripe width per grid step.
 _BLOCK_D = 512
+
+
+def stripe_compiler_params(rows: int, extra_bytes: int = 0):
+    """Mosaic parameters for a kernel that holds a full-height
+    ``(rows, _BLOCK_D)`` stripe in VMEM.
+
+    libtpu's default scoped-VMEM limit is 16 MiB, and a stripe kernel
+    needs several stripe-sized buffers at once: the double-buffered
+    input, the uint32 keys and the compare temporaries of the radix
+    search.  Measured on a v5e (libtpu 0.0.34): 22.1 MiB for the f32
+    trimmed finish at ``rows=2048`` — 5.5 f32 stripes — which the
+    default refuses.  Eight f32 stripes (plus ``extra_bytes`` for
+    non-stripe residents such as a Gram block) clears every kernel at
+    the gate's height bound; below 16 MiB the default stands.
+    """
+    need = 8 * rows * _BLOCK_D * 4 + extra_bytes
+    return pltpu.CompilerParams(vmem_limit_bytes=max(16 << 20, need))
+
+
+_trace_scope = threading.local()
+
+
+@contextlib.contextmanager
+def auto_partitioned():
+    """Scope for TRACING a program that GSPMD will partition (``jit``
+    with shardings over several devices, :mod:`blades_tpu.parallel.
+    sharded`).  A Mosaic kernel cannot be partitioned automatically —
+    its lowering raises ``NotImplementedError`` unless the call sits
+    inside a ``shard_map`` — so inside this scope every kernel gate says
+    no and the ``jnp`` paths trace instead.  Thread-local: a prefetcher
+    thread tracing its own program is not affected."""
+    prev = getattr(_trace_scope, "auto_partitioned", False)
+    _trace_scope.auto_partitioned = True
+    try:
+        yield
+    finally:
+        _trace_scope.auto_partitioned = prev
+
 
 def kernel_applicable(n: int, d: int) -> bool:
     """Shared gate for the rank-select kernels here and the fused round
-    kernel (:mod:`blades_tpu.ops.pallas_round`): TPU backend, tall enough
-    to select from, short enough that full-height ``(n, _BLOCK_D)``
-    stripes fit VMEM (f32 values + uint32 keys ≈ n * 4 KiB against the
-    ~16 MiB budget), and big enough that a single-pass kernel beats the
-    fused-but-multi-pass XLA sort.  ``BLADES_TPU_NO_PALLAS=1`` (read per
-    call) is the escape hatch forcing the jnp paths."""
+    kernel (:mod:`blades_tpu.ops.pallas_round`): TPU backend, not under
+    :func:`auto_partitioned`, tall enough to select from, short enough
+    that full-height ``(n, _BLOCK_D)`` stripes fit VMEM under
+    :func:`stripe_compiler_params`' budget (every kernel behind this
+    gate compiles at n = 2048 on a v5e: tools/chip_kernels.py), and big
+    enough that a single-pass kernel beats the fused-but-multi-pass XLA
+    sort.  ``BLADES_TPU_NO_PALLAS=1`` (read per call) is the escape
+    hatch forcing the jnp paths."""
     if bool(int(os.environ.get("BLADES_TPU_NO_PALLAS", "0"))):  # blades-lint: disable=jit-purity — documented fresh-process escape hatch, resolved at trace time by contract (docstring)
         return False
-    try:
-        backend = jax.default_backend()
-    except RuntimeError:  # no backend yet
+    if getattr(_trace_scope, "auto_partitioned", False):
         return False
-    return backend == "tpu" and 8 <= n <= 2048 and n * d >= (1 << 22)
+    return (jax.default_backend() == "tpu" and 8 <= n <= 2048
+            and n * d >= (1 << 22))
 
 
 def should_use(x: jax.Array) -> bool:
@@ -197,6 +237,7 @@ def _run_columnwise(kernel, x, interpret):
         out_specs=pl.BlockSpec((1, _BLOCK_D), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, dpad), jnp.float32),
+        compiler_params=stripe_compiler_params(x.shape[0]),
         interpret=interpret,
     )(x)
     return out[0, :d]

@@ -43,10 +43,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from blades_tpu.parallel.compat import shard_map
 
 from blades_tpu.core.round import FedRound, RoundState
 from blades_tpu.data.sampler import sample_client_batches_with_keys

@@ -23,9 +23,9 @@ That is the pinned tolerance of the robustness-grid acceptance test: zero.
 ICI accounting: every collective the traced program issues is counted on
 the :class:`~blades_tpu.parallel.streamed_geometry.PassRecorder` with the
 same ``(kind, payload)`` vocabulary as :mod:`blades_tpu.parallel.comm_model`
-(ring wire factors applied per chip), and the per-round ``ici_bytes`` /
-``preagg_kept`` metrics are stamped trace-time like ``hbm_passes``.  The
-recorder's totals reconcile event-by-event against
+(ring wire factors applied per chip); the driver stamps the recorder's
+``ici_bytes`` into every row next to the trace-time ``preagg_kept``
+metric.  The recorder's totals reconcile event-by-event against
 :func:`~blades_tpu.parallel.comm_model.hier_round_volumes` in both
 directions (tests/test_hier.py).
 """
@@ -37,10 +37,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from blades_tpu.parallel.compat import shard_map
 
 from blades_tpu.core.round import FedRound, RoundState
 from blades_tpu.data.sampler import sample_client_batches_with_keys
@@ -104,8 +102,8 @@ def hier_step(
     malicious, key) -> (state, metrics)``: data/client state sharded
     ``P(clients)``, ``malicious`` REPLICATED and UNPADDED
     (``(num_clients,)`` — the program pads it internally), key
-    replicated.  Metrics gain trace-time ``ici_bytes`` and
-    ``preagg_kept`` stamps; ``recorder`` holds the per-collective
+    replicated.  Metrics gain a trace-time ``preagg_kept`` stamp;
+    ``recorder`` holds the round's ``ici_bytes`` and the per-collective
     ``ici_events`` for reconciliation against the comm model.
     """
     _check_supported(fr, preagg, bucket_size)
@@ -243,9 +241,9 @@ def hier_step(
             participation=participation, straggled=straggled,
             stale=stale, loss_benign=~malicious,
         )
-        # Trace-time constants, the hbm_passes stamp pattern: counted on
-        # the recorder while this very trace was built.
-        metrics["ici_bytes"] = jnp.int32(rec.ici_bytes)
+        # Trace-time constant, the hbm_passes stamp pattern.  The wire
+        # total stays on the recorder (``rec.ici_bytes``, a Python int):
+        # at ResNet width it passes 2**31 bytes from ~150 clients on.
         metrics["preagg_kept"] = jnp.int32(kept)
         return new_state, metrics
 

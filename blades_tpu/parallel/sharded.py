@@ -22,19 +22,18 @@ ICI, once per round.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from blades_tpu.parallel.compat import shard_map
 
 from blades_tpu.core.round import FedRound, RoundState
 from blades_tpu.core.server import ServerState
 from blades_tpu.data.sampler import sample_client_batches
+from blades_tpu.ops.pallas_select import auto_partitioned
 from blades_tpu.parallel.mesh import (
     CLIENTS_AXIS,
     client_axis_sharding,
@@ -50,6 +49,19 @@ def _state_shardings(mesh: Mesh) -> RoundState:
     )
 
 
+def _gspmd_traced(fn: Callable) -> Callable:
+    """``fn`` traced under :func:`~blades_tpu.ops.pallas_select.
+    auto_partitioned`: GSPMD cannot partition a Mosaic custom call, so
+    the round's aggregators must trace their ``jnp`` paths here."""
+
+    @wraps(fn)
+    def traced(*args, **kwargs):
+        with auto_partitioned():
+            return fn(*args, **kwargs)
+
+    return traced
+
+
 def sharded_step(fr: FedRound, mesh: Mesh, donate: bool = True) -> Callable:
     """jit ``fr.step`` with GSPMD shardings over the client mesh axis.
 
@@ -60,7 +72,7 @@ def sharded_step(fr: FedRound, mesh: Mesh, donate: bool = True) -> Callable:
     rep = replicated_sharding(mesh)
     st = _state_shardings(mesh)
     return jax.jit(
-        fr.step,
+        _gspmd_traced(fr.step),
         in_shardings=(st, cs, cs, cs, cs, rep),
         out_shardings=(st, rep),
         donate_argnums=(0,) if donate else (),
@@ -76,7 +88,7 @@ def sharded_multi_step(
     rep = replicated_sharding(mesh)
     st = _state_shardings(mesh)
     return jax.jit(
-        partial(fr.multi_step, num_rounds=num_rounds),
+        _gspmd_traced(partial(fr.multi_step, num_rounds=num_rounds)),
         in_shardings=(st, cs, cs, cs, cs, rep),
         out_shardings=(st, rep),
         donate_argnums=(0,) if donate else (),

@@ -1,11 +1,11 @@
 """Batched device→host metric fetches for the training loop.
 
 The sequential sweep used to block on ``float(metric)`` for every round
-— one host↔device round trip per FL round, which through a remote
-accelerator relay costs more than the round itself.  The async loop
-instead carries *deferred rows*: result dicts whose scalar metrics are
-still device arrays under the ``_device_metrics`` key, accumulated and
-fetched in ONE ``jax.device_get`` per flush.
+— one host↔device round trip per FL round, each of which drains the
+dispatch pipeline.  The async loop instead carries *deferred rows*:
+result dicts whose scalar metrics are still device arrays under the
+``_device_metrics`` key, accumulated and fetched in ONE
+``jax.device_get`` per flush.
 
 Flush points are part of the durability contract, not an optimization
 detail: rows must be on disk before any checkpoint that covers them

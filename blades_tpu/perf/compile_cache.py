@@ -41,7 +41,9 @@ from __future__ import annotations
 
 import hashlib
 import json
+import os
 import threading
+from pathlib import Path
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -177,36 +179,23 @@ def cached_jit(
     return CachedFunction(fn, key=key, donate_argnums=donate_argnums)
 
 
-_persistent_dir: Optional[str] = None
+def enable_persistent_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory, so a repeat run's XLA work is a disk read.
 
-
-def enable_persistent_compilation_cache(
-    cache_dir: Optional[str] = None,
-) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (or
-    ``$BLADES_TPU_COMPILE_CACHE_DIR``), so a repeat sweep's XLA work is
-    a disk read.  Thresholds are dropped to zero — FL round programs on
-    CPU can compile in under the 1 s default and would otherwise never
-    be cached.  Returns the directory in effect, or ``None`` when no
-    directory is configured.  Idempotent; never raises (an old jax
-    without a knob just skips it)."""
-    global _persistent_dir
-    import os
-
-    cache_dir = cache_dir or os.environ.get("BLADES_TPU_COMPILE_CACHE_DIR")
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: when it
+    is set JAX has already read it and no directory is set in code.
+    Otherwise the cache lives at the FIXED ``<checkout>/.jax_cache``
+    next to this package — the directory is part of JAX's cache key, so
+    a path that moves between runs (temp dirs, pids, timestamps) never
+    hits.  Thresholds drop to zero either way: most of the round's
+    programs compile in under the 1 s default and would otherwise never
+    be cached.  Must run before the process's first compile — JAX
+    decides once whether the cache is in use.  Idempotent."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
-        return _persistent_dir
-    if _persistent_dir == cache_dir:
-        return _persistent_dir
-    os.makedirs(cache_dir, exist_ok=True)
-    for name, value in (
-        ("jax_compilation_cache_dir", cache_dir),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(name, value)
-        except Exception:  # knob absent in this jax — best-effort wiring
-            pass
-    _persistent_dir = cache_dir
-    return _persistent_dir
+        cache_dir = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir

@@ -48,8 +48,8 @@ ICI accounting: every collective is counted on the
 :class:`~blades_tpu.parallel.streamed_geometry.PassRecorder` and the
 totals reconcile event-by-event against
 :func:`blades_tpu.parallel.comm_model.gossip_round_volumes` in both
-directions; the per-round ``gossip_ici_bytes`` metric is stamped
-trace-time like ``ici_bytes`` on the hier path.
+directions; the driver stamps the recorder's total into every row as
+``gossip_ici_bytes``, like ``ici_bytes`` on the hier path.
 """
 
 from __future__ import annotations
@@ -61,14 +61,13 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from blades_tpu.core.round import FedRound, RoundState
 from blades_tpu.core.server import ServerState
 from blades_tpu.data.sampler import sample_client_batches_with_keys
 from blades_tpu.ops.aggregators import BREAKDOWN_MIN_ROWS
-from blades_tpu.parallel.compat import shard_map
 from blades_tpu.parallel.mesh import (
     CLIENTS_AXIS,
     D_AXIS,
@@ -158,9 +157,9 @@ def gossip_step(
     malicious, key) -> (state, metrics)``: the STACKED per-node server
     state (leading axis ``n_pad``) and client state shard ``P(clients)``
     (:func:`gossip_federation` builds the placement), ``malicious``
-    REPLICATED and UNPADDED, key replicated.  Metrics gain trace-time
-    ``gossip_ici_bytes`` plus the consensus/partition sensors;
-    ``recorder`` holds the per-collective ``ici_events`` for
+    REPLICATED and UNPADDED, key replicated.  Metrics gain the
+    consensus/partition sensors; ``recorder`` holds the round's
+    ``ici_bytes`` and the per-collective ``ici_events`` for
     reconciliation against the comm model.
     """
     _check_supported(fr, topo, mesh)
@@ -411,8 +410,6 @@ def gossip_step(
         if fr.health_check:
             metrics["num_unhealthy"] = (~healthy).sum()
             metrics["round_ok"] = jnp.isfinite(aggn[:n_real]).all()
-        # Trace-time constant, the hier ici_bytes stamp pattern.
-        metrics["gossip_ici_bytes"] = jnp.int32(rec.ici_bytes)
         new_state = RoundState(server=new_srv, client_opt=client_opt,
                                arrivals=getattr(state, "arrivals", None),
                                cohort=getattr(state, "cohort", None))

@@ -74,10 +74,6 @@ def main(argv=None) -> int:
                         "keeping one result row per round; 'auto' "
                         "(default) picks the largest safe window, 1 "
                         "disables")
-    common.add_argument("--compile-cache", default=None, metavar="DIR",
-                        help="enable JAX's persistent compilation cache in "
-                        "DIR so repeat sweeps skip XLA entirely (also via "
-                        "$BLADES_TPU_COMPILE_CACHE_DIR)")
     common.add_argument("--autotune", nargs="?", const="on", default=None,
                         choices=("on", "reassociating"),
                         help="execution autotuner (perf/autotune.py): "
@@ -287,7 +283,6 @@ def main(argv=None) -> int:
                 cost_analysis=not args.no_cost_analysis,
                 metrics_every=args.metrics_every,
                 scan_window=scan_window,
-                compile_cache_dir=args.compile_cache,
                 autotune=args.autotune,
                 plan_cache_dir=args.plan_cache_dir,
                 trace_dir=args.trace_dir,
@@ -351,7 +346,6 @@ def main(argv=None) -> int:
                 cost_analysis=not args.no_cost_analysis,
                 metrics_every=args.metrics_every,
                 scan_window=scan_window,
-                compile_cache_dir=args.compile_cache,
                 autotune=args.autotune,
                 plan_cache_dir=args.plan_cache_dir,
                 trace_dir=args.trace_dir,

@@ -715,7 +715,6 @@ def run_experiments(
     preempt_after: Optional[int] = None,
     scan_window="auto",
     metrics_every: int = 1,
-    compile_cache_dir: Optional[str] = None,
     autotune=None,
     plan_cache_dir: Optional[str] = None,
     trace_dir: Optional[str] = None,
@@ -773,12 +772,14 @@ def run_experiments(
       preemption hook fires, so the chaos layer's no-gap replay
       guarantee holds; rows pending at a crash are simply re-run from
       the restored checkpoint).
-    - ``compile_cache_dir`` (or ``$BLADES_TPU_COMPILE_CACHE_DIR``):
-      enable JAX's persistent compilation cache so repeat sweeps skip
-      XLA entirely.  Independent of the always-on in-process AOT
-      executable cache, whose per-trial hit/miss deltas land in each
-      summary under ``compile_cache`` (and per round in the metrics
-      stream as ``compile_cache_hits``/``compile_cache_misses``).
+    - JAX's persistent compilation cache is always on, so repeat sweeps
+      skip XLA entirely: at ``$JAX_COMPILATION_CACHE_DIR`` when set,
+      else the fixed ``<checkout>/.jax_cache``
+      (:func:`blades_tpu.perf.enable_persistent_compilation_cache`).
+      Independent of the in-process AOT executable cache, whose
+      per-trial hit/miss deltas land in each summary under
+      ``compile_cache`` (and per round in the metrics stream as
+      ``compile_cache_hits``/``compile_cache_misses``).
     - ``autotune`` (the CLI's ``--autotune``): enable the execution
       autotuner (:mod:`blades_tpu.perf.autotune`) on every trial that
       does not set its own ``autotune`` config — ``True``/``"on"`` for
@@ -874,7 +875,7 @@ def run_experiments(
                                  enable_persistent_compilation_cache,
                                  flush_rows)
 
-    enable_persistent_compilation_cache(compile_cache_dir)
+    enable_persistent_compilation_cache()
     wd_rules = _resolve_watchdog(watchdog)
     flightrec_rounds = int(flightrec_rounds or 0)
 
@@ -1220,8 +1221,7 @@ def run_experiments(
                         ef.write(f"attempt {failures}: {exc!r}\n")
                         ef.write(traceback.format_exc() + "\n")
                     if flightrec is not None:
-                        # The postmortem artifact a relay-box failure
-                        # used to leave nothing of: the last K rounds'
+                        # The postmortem artifact: the last K rounds'
                         # digests, durable before any retry/abort.
                         flightrec.dump({
                             "kind": ("preemption"

@@ -14,18 +14,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Before ANY jax import/backend use: jax < 0.5 lacks jax_num_cpu_devices
-# and its CPU client reads --xla_force_host_platform_device_count from
-# XLA_FLAGS exactly once, at first backend creation.
-_flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-          if "xla_force_host_platform_device_count" not in f]
-os.environ["XLA_FLAGS"] = " ".join(
-    _flags + ["--xla_force_host_platform_device_count=4"])
-
 import jax  # noqa: E402
 
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", 4)
+jax.config.update("jax_num_cpu_devices", 4)
 jax.config.update("jax_platforms", "cpu")
 
 from blades_tpu.parallel import init_distributed  # noqa: E402

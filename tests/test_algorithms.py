@@ -376,7 +376,7 @@ def test_dsharded_rounds_per_dispatch_through_config():
 
 def test_dense_matrix_hbm_limit_is_device_derived(monkeypatch):
     """'auto' execution's dense budget: env override > device
-    memory_stats > the 16 GB-chip fallback (VERDICT r3 item 7)."""
+    memory_stats > the 16 GB-chip default for devices without stats."""
     from blades_tpu.algorithms.fedavg import Fedavg
 
     class FakeDev:
@@ -394,9 +394,12 @@ def test_dense_matrix_hbm_limit_is_device_derived(monkeypatch):
         jax, "devices", lambda *a: [FakeDev({"bytes_limit": 95 * (1 << 30)})])
     assert Fedavg.dense_matrix_hbm_limit() == int(95 * (1 << 30) * 3 / 8)
 
-    # No stats (CPU / remote relay): the tuned 6 GB fallback.
+    assert Fedavg.dense_matrix_hbm_limit_source()[1] == "memory_stats"
+
+    # No stats (the CPU backend): the tuned 6 GB default.
     monkeypatch.setattr(jax, "devices", lambda *a: [FakeDev(None)])
-    assert Fedavg.dense_matrix_hbm_limit() == 6 * (1 << 30)
+    assert Fedavg.dense_matrix_hbm_limit_source() == (6 * (1 << 30),
+                                                      "default")
 
     # Env override wins over everything.
     monkeypatch.setenv("BLADES_TPU_DENSE_MATRIX_LIMIT_GB", "2.5")

@@ -95,9 +95,8 @@ def test_psum_pairwise_matches_dense():
 
     from functools import partial
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from blades_tpu.parallel.compat import shard_map
 
     shard = L.ShardInfo(axis="clients", num_shards=8, global_d=64, width=8)
 

@@ -193,7 +193,6 @@ def test_ici_reconciles_with_comm_model_both_ways():
                           for _, kind, payload, k in rec.ici_events)
         assert recorded == model, (mesh_shape, recorded, model)
         assert rec.ici_bytes == hier_wire_bytes(vols)
-        assert int(m["ici_bytes"]) == rec.ici_bytes
         assert int(m["preagg_kept"]) == N_CLIENTS
     # The 2-D torus gathers column-sliced representatives in two phases;
     # the flat ring ships full rows once — the 2-D wire total is strictly
@@ -326,7 +325,11 @@ def test_hier_kill_and_resume_bit_identical(tmp_path):
     a = _tiny_driver(16, faults={"dropout_rate": 0.25, "seed": 11},
                      num_malicious=4)
     try:
-        a.train()
+        # The wire total is a host int off the recorder: at ResNet width
+        # it passes int32 from ~150 clients on, which an in-program
+        # int32 stamp could not trace.
+        a._hier_recorder.ici_bytes += 1 << 32
+        assert a.train()["ici_bytes"] > 1 << 32
         path = a.save_checkpoint(str(tmp_path))
         r2a = a.train()
         r3a = a.train()

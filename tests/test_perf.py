@@ -10,6 +10,7 @@
 """
 
 import json
+import os
 from pathlib import Path
 
 import jax
@@ -421,11 +422,26 @@ def test_streamed_chained_dispatch_matches_streamed_sequential():
 # ---------------------------------------------------------------------------
 
 
-def test_persistent_cache_wiring(tmp_path, monkeypatch):
+def test_persistent_cache_placement(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` places the cache and then no
+    directory is set in code; unset, the cache is the fixed
+    ``<checkout>/.jax_cache``."""
     from blades_tpu.perf import enable_persistent_compilation_cache
 
-    target = tmp_path / "xla_cache"
-    assert enable_persistent_compilation_cache(str(target)) == str(target)
-    assert target.is_dir()
-    # Idempotent, and the env fallback resolves when no arg is given.
-    assert enable_persistent_compilation_cache(str(target)) == str(target)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_persistent_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        assert enable_persistent_compilation_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        # Idempotent.
+        assert enable_persistent_compilation_cache() == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

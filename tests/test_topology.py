@@ -280,7 +280,6 @@ def test_gossip_ici_reconciles_with_comm_model_both_ways():
                       for _, kind, payload, k in rec.ici_events)
     assert recorded == model, (recorded, model)
     assert rec.ici_bytes == gossip_wire_bytes(vols)
-    assert int(m["gossip_ici_bytes"]) == rec.ici_bytes
     # Fault-armed round: the partitioned-count psum joins the inventory.
     fr, data = _tiny_round("Median", "SignFlip",
                            faults=FaultInjector(seed=5, dropout_rate=0.3))
@@ -292,7 +291,6 @@ def test_gossip_ici_reconciles_with_comm_model_both_ways():
                         for _, kind, payload, k in rec_f.ici_events)
     assert recorded_f == model_f, (recorded_f, model_f)
     assert rec_f.ici_bytes == gossip_wire_bytes(vols_f)
-    assert int(m_f["gossip_ici_bytes"]) == rec_f.ici_bytes
     # The exchange volume does not depend on graph density (replica
     # gathers ship the full stack; the topology selects locally).
     assert rec.ici_bytes == gossip_wire_bytes(
@@ -309,7 +307,6 @@ def _assert_cell_healthy(agg, attack, graph, f=N_BYZ, **topo_kw):
     assert all(np.isfinite(v) for v in losses), (graph, agg, losses)
     for leaf in jax.tree.leaves(params):
         assert np.isfinite(leaf[:N_CLIENTS]).all()
-    assert int(m["gossip_ici_bytes"]) > 0
     assert float(m["consensus_dist"]) >= 0.0
     assert int(m["num_partitioned_nodes"]) == 0  # no faults armed
 
