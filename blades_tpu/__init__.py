@@ -24,7 +24,7 @@ Package layout (mirrors the reference's layer map, SURVEY.md §1):
 - :mod:`blades_tpu.algorithms`   FedAvg / FedAvg-DP drivers + config system
                                  (ref: fllib/algorithms, blades/algorithms)
 - :mod:`blades_tpu.tune`         YAML experiment sweeps (ref: blades/train.py)
-- :mod:`blades_tpu.utils`        tree/metric/checkpoint/timing utilities
+- :mod:`blades_tpu.utils`        pytree utilities
 """
 
 __version__ = "0.1.0"

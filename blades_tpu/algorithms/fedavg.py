@@ -46,20 +46,47 @@ class Fedavg:
 
     def __init__(self, config):
         self.config = config
-        self._setup()
+        self.timers = Timers()
+        with self.timers.span("blades/setup"):
+            self._setup()
 
     # -- setup (ref: fedavg.py:127-201) -------------------------------------
 
     def _setup(self) -> None:
+        """The build, under ``blades/setup``, in three named parts that
+        the rows' ``timers`` carry from the first row on: ``.../data``
+        (dataset, device stacks, and — nested inside ``.../round``,
+        where the streamed branch does it — the stack's cast to the
+        compute dtype), ``.../model`` (model and state init),
+        ``.../round`` (``get_fed_round``, autotune, the round
+        callable).  Host time: a device copy or cast is timed as far as
+        its enqueue."""
         cfg = self.config
-        self.dataset = DatasetCatalog.get_dataset(
-            cfg.dataset, num_clients=cfg.num_clients, iid=cfg.iid,
-            alpha=cfg.dirichlet_alpha, seed=cfg.seed,
-        )
-        self.fed_round: FedRound = cfg.resolve_augment_for_data(
-            cfg.get_fed_round(), self.dataset)
-        if getattr(self.fed_round.server.aggregator, "expects_trusted_row", False):
-            self.fed_round = self._attach_root_data(self.fed_round)
+        with self.timers.span("blades/setup/data"):
+            self.dataset = DatasetCatalog.get_dataset(
+                cfg.dataset, num_clients=cfg.num_clients, iid=cfg.iid,
+                alpha=cfg.dirichlet_alpha, seed=cfg.seed,
+            )
+        with self.timers.span("blades/setup/round"):
+            self.fed_round: FedRound = cfg.resolve_augment_for_data(
+                cfg.get_fed_round(), self.dataset)
+            if getattr(self.fed_round.server.aggregator,
+                       "expects_trusted_row", False):
+                self.fed_round = self._attach_root_data(self.fed_round)
+        # Out-of-core async: the event cohort's opt rows live behind a
+        # host/disk store (blades_tpu/state).
+        self._ooc_async = (cfg.execution == "async"
+                           and cfg.state_store != "resident")
+        with self.timers.span("blades/setup/model"):
+            self._setup_state()
+        with self.timers.span("blades/setup/data"):
+            self._setup_data()
+        with self.timers.span("blades/setup/round"):
+            self._setup_round()
+        self._setup_bookkeeping()
+
+    def _setup_state(self) -> None:
+        cfg = self.config
         self.malicious = make_malicious_mask(cfg.num_clients,
                                              cfg.num_malicious_clients)
         self._key = jax.random.PRNGKey(cfg.seed)
@@ -72,13 +99,11 @@ class Fedavg:
         # dense broadcast would OOM before the store could help.
         sw = getattr(cfg, "state_window", None)
         self._windowed = sw is not None and sw >= 1
-        ooc_async = (cfg.execution == "async"
-                     and cfg.state_store != "resident")
         self._state_store = None   # ClientStateStore handle (None = off)
         self._state_pf = None      # StatePrefetcher (sync windowed only)
         self._window_prev = None   # (cohort ids, device rows) of round r-1
         self._row_template = None  # one client's persistent-state row
-        if self._windowed or ooc_async:
+        if self._windowed or self._ooc_async:
             server_rows = (int(sw) if self._windowed
                            else cfg.get_async_spec().agg_every)
             self.state, self._row_template = self.fed_round.init_windowed(
@@ -86,12 +111,14 @@ class Fedavg:
         else:
             self.state = self.fed_round.init(init_key, cfg.num_clients)
 
+    def _setup_data(self) -> None:
+        cfg = self.config
         # The windowed/out-of-core paths keep the training shards
         # HOST-resident (cohort rows are gathered per round); every
         # other path stages the full stacks onto the device as before.
         self._host_train = (self.dataset.train.x, self.dataset.train.y,
                             self.dataset.train.lengths)
-        if self._windowed or ooc_async:
+        if self._windowed or self._ooc_async:
             self._train_arrays = None
         else:
             self._train_arrays = tuple(jnp.asarray(a)
@@ -107,7 +134,7 @@ class Fedavg:
         self._data_pf = None     # DataPrefetcher staging adapter
         self._eval_chunk_fn = None  # jitted streaming-eval chunk program
         self._eval_chunks = 0    # chunks walked by the last streaming eval
-        if self._windowed or ooc_async:
+        if self._windowed or self._ooc_async:
             from blades_tpu.data.store import make_data_store
             from blades_tpu.data.stream import DataPrefetcher
 
@@ -166,6 +193,8 @@ class Fedavg:
 
             self._eval_chunk_fn = make_chunk_evaluator(self.fed_round.task)
 
+    def _setup_round(self) -> None:
+        cfg = self.config
         # Execution autotuner (perf/autotune.py): resolve the measured
         # plan — or the checkpoint/operator pin, or the cached winner —
         # and materialise it into the config knobs BEFORE the pipeline
@@ -203,7 +232,7 @@ class Fedavg:
             # version they actually pulled.
             from blades_tpu.arrivals import AsyncEngine
 
-            if ooc_async:
+            if self._ooc_async:
                 # The event cohort's opt rows come from the window
                 # store (gathered per cycle, scattered back after);
                 # the version vector is already keyed by registered id.
@@ -369,8 +398,9 @@ class Fedavg:
             # the giant bf16 update matrix needs back.
             cd = self.fed_round.task.spec.compute_dtype
             if cd is not None:
-                x, y, ln = self._train_arrays
-                self._train_arrays = (x.astype(jnp.dtype(cd)), y, ln)
+                with self.timers.span("blades/setup/data"):
+                    x, y, ln = self._train_arrays
+                    self._train_arrays = (x.astype(jnp.dtype(cd)), y, ln)
             streamed_kw = dict(
                 client_block=self._streamed_block(),
                 d_chunk=cfg.d_chunk,
@@ -393,6 +423,8 @@ class Fedavg:
         else:
             self._setup_dense_pipeline()
 
+    def _setup_bookkeeping(self) -> None:
+        cfg = self.config
         # Client-lifetime ledger (obs/ledger.py): one longitudinal
         # record per REGISTERED client, folded host-side in
         # _fill_round_metrics from the already-fetched row and the
@@ -444,7 +476,6 @@ class Fedavg:
                                       and self.mesh is None),
                 )
 
-        self.timers = Timers()
         self._iteration = 0
         self._rounds_since_eval = 0
         self._last_eval: Dict = {}
@@ -1109,11 +1140,12 @@ class Fedavg:
     def adopt_tracer(self, tracer) -> None:
         """Observability layer (obs/trace.py): replace this instance's
         phase timers with the caller's span tracer, so the
-        ``training_step`` / ``evaluate`` phases nest inside the
+        ``blades/round`` phases and ``evaluate`` nest inside the
         caller's trial/round spans (ONE tree per trial in the
-        ``--trace-dir`` export).  The tracer's ``summary()`` shape is a
-        superset of the old ``Timers`` one, so the per-row ``timers``
-        field keeps its contract."""
+        ``--trace-dir`` export).  What this instance has timed so far
+        (its build) moves into the adopted tracer's aggregates, so the
+        per-row ``timers`` field keeps it."""
+        tracer.absorb(self.timers)
         self.timers = tracer
 
     @property
@@ -1231,8 +1263,20 @@ class Fedavg:
 
     def train(self) -> Dict:
         """One training dispatch (= ``rounds_per_dispatch`` FL rounds, 1 by
-        default) + periodic eval, returns the last round's result dict."""
-        return self.finalize_row(self._train_raw(fetch=True))
+        default) + periodic eval, returns the last round's result dict.
+
+        The call is the ``blades/round`` span; inside it
+        ``training_step`` (dispatch + fetch) holds the round body's
+        ``blades/prepare|block|finish`` (parallel/streamed.py) and
+        ``blades/fetch`` (the host's wait for the device), and
+        ``blades/row`` the row work after them (``evaluate`` nests in
+        it on an evaluation round).  The row's ``timers`` is taken
+        after the round's spans close, so a row's ``timers`` minus the
+        previous row's is that round's phase times."""
+        with self.timers.span("blades/round", step=self._iteration):
+            row = self._train_raw(fetch=True, finalize=True)
+        row["timers"] = self.timers.summary()
+        return row
 
     def train_raw(self) -> Dict:
         """One training dispatch WITHOUT the host sync on round-scalar
@@ -1240,10 +1284,13 @@ class Fedavg:
         ``perf.async_metrics.DEVICE_METRICS_KEY`` and must be passed
         through :meth:`finalize_row` (or ``perf.flush_rows``, which
         batches the ``device_get`` across rows) before it is consumed.
-        The async sweep loop (``metrics_every > 1``) drives this."""
-        return self._train_raw(fetch=False)
+        The async sweep loop (``metrics_every > 1``) drives this.  Such
+        a row's ``timers`` is the snapshot at dispatch: its
+        ``blades/round`` and ``blades/row`` lag by that round's."""
+        with self.timers.span("blades/round", step=self._iteration):
+            return self._train_raw(fetch=False)
 
-    def _train_raw(self, fetch: bool) -> Dict:
+    def _train_raw(self, fetch: bool, finalize: bool = False) -> Dict:
         cycle_t0 = now() if self._async is not None else None
         with self.timers.time("training_step"):
             if self._async is not None:
@@ -1285,9 +1332,20 @@ class Fedavg:
             if fetch:
                 # The fetch sits inside the timer: the dispatch is
                 # asynchronous, so the span otherwise times the enqueue.
-                raw_metrics = jax.device_get(raw_metrics)
+                with self.timers.span("blades/fetch"):
+                    raw_metrics = jax.device_get(raw_metrics)
         self._iteration += self._chunk
         self._rounds_since_eval += self._chunk
+        with self.timers.span("blades/row"):
+            row = self._new_row(raw_metrics, cycle_t0)
+            if finalize:
+                self._fill_round_metrics(
+                    row, row.pop(DEVICE_METRICS_KEY), idx=None)
+        return row
+
+    def _new_row(self, raw_metrics, cycle_t0) -> Dict:
+        """The row as ``_train_raw`` stamps it at dispatch time: host
+        counters, and the evaluation where its cadence fires."""
         row = {
             "training_iteration": self._iteration,
             DEVICE_METRICS_KEY: raw_metrics,
@@ -1384,8 +1442,10 @@ class Fedavg:
         raw = row.pop(DEVICE_METRICS_KEY, None)
         if raw is None:
             return row
-        raw = jax.device_get(raw)
-        self._fill_round_metrics(row, raw, idx=None)
+        with self.timers.span("blades/fetch"):
+            raw = jax.device_get(raw)
+        with self.timers.span("blades/row"):
+            self._fill_round_metrics(row, raw, idx=None)
         return row
 
     def _fill_round_metrics(self, row: Dict, raw: Dict, idx) -> None:

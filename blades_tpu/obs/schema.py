@@ -244,7 +244,7 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     "reputation_p90": (_NUM, False),
     "ledger_clients_seen": ((int,), False),
     "ledger_top_suspects": ((list,), False),
-    # host-side timings (utils/timers.py)
+    # host-side phase timings (obs/trace.py)
     "timers": ((dict,), False),
 }
 

@@ -1,26 +1,26 @@
 """Span tracing: the host-side timing source of truth.
 
-One layer replaces the two PR-1 timing modules (``utils/timers.py``'s
-phase accumulators, ``utils/profiling.py``'s jax-profiler wrappers —
-both kept as back-compat shims over this module): a :class:`Tracer`
-records a TREE of named spans (sweep -> trial -> round -> phase:
-sample / encode / step / aggregate / eval / checkpoint), aggregates
-per-name phase statistics in the exact shape the old ``Timers`` emitted
-(``{name: {mean_s, total_s, count}}`` — the ``timers`` field of every
-metrics row), and exports the tree as Chrome/Perfetto trace JSON per
-trial (``--trace-dir``).
+A :class:`Tracer` times named spans (sweep -> trial -> round -> phase),
+aggregates per-name phase statistics (``{name: {mean_s, total_s,
+count}}`` — the ``timers`` field of every metrics row) and, armed
+(``record=True``), keeps the span TREE and exports it as Chrome/Perfetto
+trace JSON per trial (``--trace-dir``).
 
-Device correlation: when a tracer is **armed** (``record=True``) every
-span also enters a ``jax.profiler.TraceAnnotation`` (or
-``StepTraceAnnotation`` when the span carries a ``step`` number), so a
-run that ALSO captures a jax profiler trace (``--trace``) shows device
-work nested inside the right host span — the autotuner / fusion / codec
-decisions stamped on the round spans (``plan_id``, ``hbm_passes``,
-``agg_domain``, ``comm_bytes_up``) then sit inline with the time they
-explain.  An un-armed tracer (the default everywhere) records NO tree,
-enters NO annotations and writes NO files — it is exactly the old
-phase-accumulator, so the tracing-off path is bit-identical to pre-span
-builds (regression-tested per execution path in tests/test_trace.py).
+The profiler's clock: EVERY span, armed or not, enters a
+``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` when the span
+carries a ``step`` number).  Outside a profiler session that is an inert
+``TraceMe``; inside one (``--trace``, or a harness's
+``jax.profiler.start_trace``) the span lands on the calling thread's
+line of the profile, beside the runtime's own host events and on the
+clock of the device trace — so each device idle gap can be put down to
+the phase the host was in.  ``record`` governs retention only: an
+un-armed tracer keeps NO span, writes NO file, and its rows differ from
+an armed run's in ``timers`` alone (tests/test_trace.py).
+
+:func:`span` is the same call for code that holds no tracer (the round
+bodies under ``parallel/``): it aggregates into — and, armed, nests
+under — the tracer whose span is open on the calling thread, and is
+annotation-only where none is.
 
 Clock discipline: :func:`now` is THE duration clock.  Raw
 ``time.time()``/``time.perf_counter()`` calls anywhere else under
@@ -32,13 +32,14 @@ one place.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
-    "Span", "Tracer", "Timers", "now", "trace", "annotate",
-    "xla_dump_flags", "validate_chrome_trace",
+    "Span", "Tracer", "Timers", "now", "span", "trace",
+    "validate_chrome_trace",
 ]
 
 
@@ -67,6 +68,8 @@ class Span:
     step: Optional[int] = None
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     children: List["Span"] = dataclasses.field(default_factory=list)
+    # The entered profiler annotation, exited by Tracer.finish.
+    _ann: Any = dataclasses.field(default=None, repr=False, compare=False)
 
     @property
     def duration(self) -> float:
@@ -74,30 +77,61 @@ class Span:
             - self.start_s
 
 
-def _profiler_annotation(name: str, step: Optional[int]):
-    """The jax profiler annotation for an armed span (None when jax or
-    its profiler is unavailable — the span layer must work in a
-    stripped-down host process)."""
-    try:
-        import jax.profiler as jp
-    except Exception:
+_profiler = None  # jax.profiler once resolved; False where unavailable
+
+
+def _enter_annotation(name: str, step: Optional[int]):
+    """Enter the span's jax profiler annotation and return it (``None``
+    when jax or its profiler is unavailable — the span layer must work
+    in a stripped-down host process).  Inert outside a profiler
+    session."""
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax.profiler as jp
+        except Exception:
+            jp = False
+        _profiler = jp
+    if not _profiler:
         return None
     try:
-        if step is not None:
-            return jp.StepTraceAnnotation(name, step_num=int(step))
-        return jp.TraceAnnotation(name)
+        ann = (_profiler.TraceAnnotation(name) if step is None else
+               _profiler.StepTraceAnnotation(name, step_num=int(step)))
+        ann.__enter__()
     except Exception:
         return None
+    return ann
+
+
+def _exit_annotation(ann) -> None:
+    if ann is not None:
+        try:
+            ann.__exit__(None, None, None)
+        except Exception:
+            pass
+
+
+# Per thread, the tracers that have a span open on it, innermost last:
+# what the module-level span() aggregates into.
+_thread = threading.local()
+
+
+def _open_tracers() -> List["Tracer"]:
+    try:
+        return _thread.tracers
+    except AttributeError:
+        _thread.tracers = []
+        return _thread.tracers
 
 
 class Tracer:
-    """Span recorder + phase aggregator.
+    """Span timer + phase aggregator, every span on the profiler's
+    clock (module docstring).
 
-    ``record=False`` (default): aggregation only — the old ``Timers``
-    semantics, near-zero overhead, nothing retained per span.
-    ``record=True`` (armed): additionally keeps the span TREE for
-    Chrome-trace export and enters jax profiler annotations so device
-    work correlates.  ``clock`` is injectable for deterministic tests.
+    ``record=False`` (default): aggregation only, nothing retained per
+    span.  ``record=True`` (armed): additionally keeps the span TREE
+    for Chrome-trace export.  ``clock`` is injectable for deterministic
+    tests.
     """
 
     def __init__(self, record: bool = False, clock=now):
@@ -115,9 +149,9 @@ class Tracer:
 
     def start(self, name: str, step: Optional[int] = None,
               **attrs) -> Span:
-        """Open a span (pair with :meth:`finish`).  Works un-armed too:
-        the returned :class:`Span` always carries real start/end times,
-        so ``finish(span); span.duration`` is the sanctioned way to
+        """Open a span (pair with :meth:`finish`).  The returned
+        :class:`Span` always carries real start/end times, so
+        ``finish(span); span.duration`` is the sanctioned way to
         measure a block the ``with`` form cannot wrap cleanly."""
         span = Span(name=name, start_s=self._clock(), step=step,
                     attrs=dict(attrs))
@@ -127,30 +161,25 @@ class Tracer:
                 (self._stack[-1].children if self._stack
                  else self._roots).append(span)
                 self._stack.append(span)
-                ann = _profiler_annotation(name, step)
-                if ann is not None:
-                    span.attrs.setdefault("_ann", None)
-                    try:
-                        ann.__enter__()
-                        span.attrs["_ann"] = ann
-                    except Exception:
-                        span.attrs.pop("_ann", None)
             else:
                 self._dropped += 1
+        _open_tracers().append(self)
+        span._ann = _enter_annotation(name, step)
         return span
 
     def finish(self, span: Span) -> Span:
+        _exit_annotation(span._ann)
+        span._ann = None
+        opened = _open_tracers()
+        for i in range(len(opened) - 1, -1, -1):
+            if opened[i] is self:
+                del opened[i]
+                break
         span.end_s = self._clock()
         self._totals[span.name] = self._totals.get(span.name, 0.0) \
             + span.duration
         self._counts[span.name] = self._counts.get(span.name, 0) + 1
         if self.record:
-            ann = span.attrs.pop("_ann", None)
-            if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
             if self._stack and self._stack[-1] is span:
                 self._stack.pop()
             elif span in self._stack:
@@ -215,6 +244,14 @@ class Tracer:
             for k in self._totals
         }
 
+    def absorb(self, other: "Tracer") -> None:
+        """Add ``other``'s phase aggregates to this tracer's: what a
+        driver timed before it adopted this tracer (its build) stays in
+        the rows' ``timers``."""
+        for k, total in other._totals.items():
+            self._totals[k] = self._totals.get(k, 0.0) + total
+            self._counts[k] = self._counts.get(k, 0) + other._counts[k]
+
     # -- export --------------------------------------------------------------
 
     def to_chrome_trace(self) -> Dict[str, Any]:
@@ -233,8 +270,7 @@ class Tracer:
             # contributes no event of its own, but its FINISHED children
             # must still be walked — they are the tree being salvaged.
             if span.end_s is not None:
-                args = {k: v for k, v in span.attrs.items()
-                        if not k.startswith("_")}
+                args = dict(span.attrs)
                 if span.step is not None:
                     args["step"] = span.step
                 events.append({
@@ -265,19 +301,39 @@ class Tracer:
 
 
 class Timers(Tracer):
-    """PR-1 back-compat name (``utils/timers.py`` re-exports this): a
-    plain un-armed tracer IS the old phase-timer object."""
+    """The PR-1 name of the phase-timer object: a plain un-armed
+    tracer."""
+
+
+def span(name: str, step: Optional[int] = None):
+    """A span for code that holds no tracer, as a context manager: a
+    span of the tracer whose span is open on the calling thread
+    (innermost), or the profiler annotation alone where none is (it
+    then yields ``None``)."""
+    opened = _open_tracers()
+    if opened:
+        return opened[-1].span(name, step=step)
+    return _annotation_only(name, step)
+
+
+@contextmanager
+def _annotation_only(name: str, step: Optional[int]) -> Iterator[None]:
+    ann = _enter_annotation(name, step)
+    try:
+        yield None
+    finally:
+        _exit_annotation(ann)
 
 
 # ---------------------------------------------------------------------------
-# jax profiler wrappers (formerly utils/profiling.py; shims remain there)
+# jax profiler capture
 # ---------------------------------------------------------------------------
 
 
 @contextmanager
 def trace(log_dir: str) -> Iterator[None]:
     """Capture a jax profiler trace (device + host) into ``log_dir``.
-    Armed tracers' span annotations land inside this capture, so the
+    Every span's annotation lands inside this capture, so the
     ``--trace`` profiler hook and ``--trace-dir`` span export compose."""
     import jax.profiler
 
@@ -286,20 +342,6 @@ def trace(log_dir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named sub-region, visible in the profiler trace viewer."""
-    import jax.profiler
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def xla_dump_flags(dump_dir: str) -> str:
-    """XLA_FLAGS value that dumps optimised HLO text to ``dump_dir``."""
-    return f"--xla_dump_to={dump_dir} --xla_dump_hlo_as_text"
 
 
 # ---------------------------------------------------------------------------

@@ -85,6 +85,7 @@ from blades_tpu.adversaries.update_attacks import (
 )
 from blades_tpu.core.round import FedRound, RoundState
 from blades_tpu.data.sampler import sample_client_batches_with_keys
+from blades_tpu.obs.trace import span
 from blades_tpu.ops.aggregators import Mean, Median, Trimmedmean
 
 _COORDWISE_FORGERS = (ALIEAdversary, IPMAdversary, NoiseAdversary,
@@ -280,36 +281,44 @@ def streamed_step(
                      malicious, sample_keys, train_keys, row0, buf_row0):
         """``row0`` indexes the CLIENT arrays; ``buf_row0`` the update
         matrix row — they differ only on the benign-compacted path,
-        where the matrix stores no malicious-prefix rows."""
+        where the matrix stores no malicious-prefix rows.
+
+        Device scopes (trace-time metadata, the dense body's names):
+        ``blades/sample``, ``blades/step``, and ``blades/store`` for the
+        norms and the writes into the matrix and ``client_opt``."""
         def sl(a):
             return lax.dynamic_slice_in_dim(a, row0, client_block, axis=0)
 
         opt_b = jax.tree.map(sl, client_opt)
-        bx, by = sample_client_batches_with_keys(
-            sl(sample_keys), sl(x), sl(y), sl(lengths), fr.batch_size,
-            fr.num_batches_per_round,
-        )
+        with jax.named_scope("blades/sample"):
+            bx, by = sample_client_batches_with_keys(
+                sl(sample_keys), sl(x), sl(y), sl(lengths), fr.batch_size,
+                fr.num_batches_per_round,
+            )
 
         # Non-DP rounds cast per leaf inside the block (same bf16 bits,
         # half the assembly traffic); DP needs the f32 row norms BEFORE
         # storage rounding, so there the cast stays at the buffer write.
-        upd, opt2, loss = fr.task.local_round_batched(
-            params, opt_b, bx, by, sl(train_keys), sl(malicious), *hooks,
-            out_dtype=None if dp else update_dtype,
-        )
-        # Full-row L2 norms, taken on the f32 updates BEFORE storage-dtype
-        # rounding — what chunked DP clipping needs and cannot recover
-        # from the matrix later.  Gated: the O(n*d) reduction is pure
-        # waste on non-DP rounds.
-        norms = (jnp.linalg.norm(upd, axis=1) if dp
-                 else jnp.zeros((upd.shape[0],), jnp.float32))
-        updates_buf = lax.dynamic_update_slice(
-            updates_buf, upd.astype(update_dtype), (buf_row0, 0)
-        )
-        client_opt = jax.tree.map(
-            lambda full, blk: lax.dynamic_update_slice_in_dim(full, blk, row0, 0),
-            client_opt, opt2,
-        )
+        with jax.named_scope("blades/step"):
+            upd, opt2, loss = fr.task.local_round_batched(
+                params, opt_b, bx, by, sl(train_keys), sl(malicious), *hooks,
+                out_dtype=None if dp else update_dtype,
+            )
+        with jax.named_scope("blades/store"):
+            # Full-row L2 norms, taken on the f32 updates BEFORE
+            # storage-dtype rounding — what chunked DP clipping needs and
+            # cannot recover from the matrix later.  Gated: the O(n*d)
+            # reduction is pure waste on non-DP rounds.
+            norms = (jnp.linalg.norm(upd, axis=1) if dp
+                     else jnp.zeros((upd.shape[0],), jnp.float32))
+            updates_buf = lax.dynamic_update_slice(
+                updates_buf, upd.astype(update_dtype), (buf_row0, 0)
+            )
+            client_opt = jax.tree.map(
+                lambda full, blk: lax.dynamic_update_slice_in_dim(
+                    full, blk, row0, 0),
+                client_opt, opt2,
+            )
         return updates_buf, client_opt, loss, norms
 
     @jax.jit
@@ -350,12 +359,14 @@ def streamed_step(
                 # single (n, d) draw (both are valid iid streams).
                 chunk = _dp_chunk(chunk, row_norms, k_dp, i)
             if forges:
-                chunk = fr.adversary.on_updates_ready(
-                    chunk, malicious, jax.random.fold_in(k_adv, i),
-                    aggregator=agg, global_params=None,
-                )
-            a, _ = agg(chunk, ())
-            agg_vec = lax.dynamic_update_slice(agg_vec, a, (start,))
+                with jax.named_scope("blades/forge"):
+                    chunk = fr.adversary.on_updates_ready(
+                        chunk, malicious, jax.random.fold_in(k_adv, i),
+                        aggregator=agg, global_params=None,
+                    )
+            with jax.named_scope("blades/aggregate"):
+                a, _ = agg(chunk, ())
+                agg_vec = lax.dynamic_update_slice(agg_vec, a, (start,))
             # Row-norm accumulation over not-yet-covered coordinates only.
             new = (start + jnp.arange(c)) >= i * c
             sq_acc = sq_acc + jnp.where(new[None, :], chunk**2, 0.0).sum(axis=1)
@@ -373,8 +384,12 @@ def streamed_step(
     def _serve_aggregate(server_state, agg_vec, malicious, losses, sq_norms,
                          bad_rows, agg_state=None):
         """Shared finish tail: server step + round metrics + health guard
-        (identical for the chunked, fused, and row-geometry finishes)."""
-        server = fr.server.apply_aggregate(server_state, agg_vec, agg_state)
+        (identical for the chunked, fused, and row-geometry finishes).
+        The server step wears ``blades/aggregate``, as in the dense body
+        (core/round.py)."""
+        with jax.named_scope("blades/aggregate"):
+            server = fr.server.apply_aggregate(server_state, agg_vec,
+                                               agg_state)
         benign = (~malicious).astype(jnp.float32)
         train_loss = (losses * benign).sum() / jnp.maximum(benign.sum(), 1.0)
         metrics = {
@@ -406,10 +421,11 @@ def streamed_step(
         d = sum(p.size for p in jax.tree.leaves(server_state.params))
         noise = None
         if spec[0] is not None and spec[0][0] == "adaptive":
-            noise = jax.random.uniform(k_adv, (d,), jnp.float32)
-            d_alloc = updates_buf.shape[1]
-            if d_alloc != d:
-                noise = jnp.pad(noise, (0, d_alloc - d))
+            with jax.named_scope("blades/forge"):
+                noise = jax.random.uniform(k_adv, (d,), jnp.float32)
+                d_alloc = updates_buf.shape[1]
+                if d_alloc != d:
+                    noise = jnp.pad(noise, (0, d_alloc - d))
         return d, noise
 
     @jax.jit
@@ -421,11 +437,15 @@ def streamed_step(
         # materialize a second near-full copy of the giant matrix).
         d, noise = _model_d_and_noise(server_state, updates_buf, k_adv)
         forge, aspec = spec
-        agg_vec, sq_norms, bad_rows = fused_finish(
-            updates_buf, malicious, noise, forge=forge, agg=aspec,
-            sanitize=fr.health_check,
-        )
-        agg_vec = agg_vec[:d]  # drop stripe-alignment padding columns
+        # Forge and aggregate are ONE Mosaic call: it sits under
+        # blades/aggregate, and blades/forge holds only the adaptive
+        # forge's uniforms.
+        with jax.named_scope("blades/aggregate"):
+            agg_vec, sq_norms, bad_rows = fused_finish(
+                updates_buf, malicious, noise, forge=forge, agg=aspec,
+                sanitize=fr.health_check,
+            )
+            agg_vec = agg_vec[:d]  # drop stripe-alignment padding columns
         return _serve_aggregate(server_state, agg_vec, malicious, losses,
                                 sq_norms, bad_rows)
 
@@ -437,17 +457,20 @@ def streamed_step(
         (ops/pallas_round.fused_finish_compact) — per-row kernel work and
         matrix HBM both shrink by the byzantine fraction.  ``nb_real`` is
         the benign row count; rows past it are the caller's +inf sublane
-        padding."""
+        padding.  Forge and aggregate are ONE Mosaic call here: it sits
+        under ``blades/aggregate``, and ``blades/forge`` holds only the
+        adaptive forge's uniforms."""
         from blades_tpu.ops.pallas_round import fused_finish_compact
 
         d, noise = _model_d_and_noise(server_state, updates_buf, k_adv)
         forge, aspec = spec
-        agg_vec, sq_b, bad_b, forged = fused_finish_compact(
-            updates_buf, noise, forged_mult=malicious_prefix, forge=forge,
-            agg=aspec, sanitize=fr.health_check, num_real=nb_real,
-            mxu_finish=mxu_finish,
-        )
-        agg_vec, forged = agg_vec[:d], forged[:d]
+        with jax.named_scope("blades/aggregate"):
+            agg_vec, sq_b, bad_b, forged = fused_finish_compact(
+                updates_buf, noise, forged_mult=malicious_prefix,
+                forge=forge, agg=aspec, sanitize=fr.health_check,
+                num_real=nb_real, mxu_finish=mxu_finish,
+            )
+            agg_vec, forged = agg_vec[:d], forged[:d]
         fsq = forged @ forged
         sq = jnp.concatenate(
             [jnp.full((malicious_prefix,), fsq, jnp.float32), sq_b])
@@ -496,10 +519,13 @@ def streamed_step(
         if dp:
             chunk = _dp_chunk(chunk, row_norms, k_dp, i)
         if forges:
-            chunk = fr.adversary.on_updates_ready(
-                chunk, malicious, k_adv, aggregator=agg, global_params=None,
-                shard=ChunkInfo(global_d=d, width=c, start=start, index=i),
-            )
+            with jax.named_scope("blades/forge"):
+                chunk = fr.adversary.on_updates_ready(
+                    chunk, malicious, k_adv, aggregator=agg,
+                    global_params=None,
+                    shard=ChunkInfo(global_d=d, width=c, start=start,
+                                    index=i),
+                )
         new = new_cols(start, i, c)
         sq_acc = sq_acc + jnp.where(new[None, :], chunk**2, 0.0).sum(axis=1)
         # Write back ONLY this chunk's not-yet-covered columns: the tail
@@ -526,11 +552,12 @@ def streamed_step(
         trusted = fr.compute_trusted_update(
             server_state.params, jax.random.fold_in(k_agg, 1)
         )
-        agg_vec, agg_state, sq = aggregate_streamed(
-            agg, updates_buf, sq, server_state.agg_state, key=k_agg,
-            trusted=trusted, d_chunk=d_chunk, d=d_model,
-            recorder=_pass_recorder, fuse=fuse_rowgeom,
-        )
+        with jax.named_scope("blades/aggregate"):
+            agg_vec, agg_state, sq = aggregate_streamed(
+                agg, updates_buf, sq, server_state.agg_state, key=k_agg,
+                trusted=trusted, d_chunk=d_chunk, d=d_model,
+                recorder=_pass_recorder, fuse=fuse_rowgeom,
+            )
         return _serve_aggregate(server_state, agg_vec, malicious, losses,
                                 sq, bad_rows, agg_state=agg_state)
 
@@ -547,10 +574,12 @@ def streamed_step(
 
         planner = PassPlanner(updates_buf, d_chunk, d=d_model,
                               recorder=_pass_recorder, fuse=fuse_rowgeom)
-        forged, sq = forge_streamed(
-            fr.adversary, updates_buf, malicious, sq, k_adv, agg, planner,
-        )
-        sq = jnp.where(malicious, forged @ forged, sq)
+        with jax.named_scope("blades/forge"):
+            forged, sq = forge_streamed(
+                fr.adversary, updates_buf, malicious, sq, k_adv, agg,
+                planner,
+            )
+            sq = jnp.where(malicious, forged @ forged, sq)
         return forged, sq
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -560,11 +589,12 @@ def streamed_step(
         padding columns past d_model are never touched)."""
         n = updates_buf.shape[0]
         c = min(d_chunk, d_model)
-        fs = lax.dynamic_slice(forged, (start,), (c,))
-        chunk = lax.dynamic_slice(updates_buf, (0, start), (n, c))
-        chunk = jnp.where(malicious[:, None],
-                          fs[None, :].astype(chunk.dtype), chunk)
-        return lax.dynamic_update_slice(updates_buf, chunk, (0, start))
+        with jax.named_scope("blades/forge"):
+            fs = lax.dynamic_slice(forged, (start,), (c,))
+            chunk = lax.dynamic_slice(updates_buf, (0, start), (n, c))
+            chunk = jnp.where(malicious[:, None],
+                              fs[None, :].astype(chunk.dtype), chunk)
+            return lax.dynamic_update_slice(updates_buf, chunk, (0, start))
 
     @jax.jit
     def _coordwise_after_forge(server_state, updates_buf, malicious, losses,
@@ -574,10 +604,11 @@ def streamed_step(
         Trimmedmean)."""
         from blades_tpu.parallel.streamed_geometry import aggregate_coordwise
 
-        agg_vec = aggregate_coordwise(
-            agg, updates_buf, min(d_chunk, d_model), d=d_model,
-            recorder=_pass_recorder,
-        )
+        with jax.named_scope("blades/aggregate"):
+            agg_vec = aggregate_coordwise(
+                agg, updates_buf, min(d_chunk, d_model), d=d_model,
+                recorder=_pass_recorder,
+            )
         return _serve_aggregate(server_state, agg_vec, malicious, losses,
                                 sq, bad_rows)
 
@@ -604,202 +635,219 @@ def streamed_step(
         return jnp.broadcast_to(col[:, None], (rows, d))
 
     def step(state: RoundState, data_x, data_y, lengths, malicious, key):
+        """One streamed round.  Three host spans (obs/trace.py) bound its
+        phases on the calling thread: ``blades/prepare`` (key splits,
+        mask check, geometry, allocation of the update matrix: all that
+        precedes the first block), one ``blades/block`` per trained
+        block and ``blades/finish`` (whichever finish runs, its chunk
+        loops included).  ``blades/block`` and ``blades/finish`` time the
+        ENQUEUE of their programs, not their device time: dispatch is
+        asynchronous, and the host runs ahead of the device."""
         nonlocal d_model
-        n = data_x.shape[0]
-        if n % client_block:
-            raise ValueError(f"{n} clients not divisible by block {client_block}")
-        if row_geom or row_forges:
-            # Checked BEFORE training: the round below donates the
-            # caller's opt state and burns a full training pass.
-            if fr.num_clients is not None and fr.num_clients != n:
+        with span("blades/prepare"):
+            n = data_x.shape[0]
+            if n % client_block:
                 raise ValueError(
-                    f"the streamed row-geometry finish needs num_clients "
-                    f"({fr.num_clients}) == data rows ({n}): ghost lanes "
-                    "would enter the row geometry — pick a client_block "
-                    "that divides num_clients"
-                )
-            from blades_tpu.parallel.streamed_geometry import check_applicable
-
-            check_applicable(agg, n)
-        if d_model is None:
-            d_model = sum(p.size for p in jax.tree.leaves(state.server.params))
-        from blades_tpu.ops.pallas_round import should_use
-
-        # Per-call (n can differ between calls): ghost (padding) lanes
-        # force the chunked path — slicing them off before a pallas_call
-        # would materialize a second copy of the giant matrix, and the
-        # kernel has no lane-validity input.
-        no_ghosts = fr.num_clients is None or fr.num_clients == n
-        use_fused = (spec is not None and no_ghosts
-                     and should_use(n, d_model))
-        # Same RNG stream as FedRound.step.
-        k_sample, k_train, k_adv, k_agg, k_dp = jax.random.split(key, 5)
-        sample_keys = jax.random.split(k_sample, n)
-        train_keys = jax.random.split(k_train, n)
-        # Malicious-lane training elision (see malicious_prefix above):
-        # blocks fully inside the forged prefix never train — their rows
-        # stay zero (finite, benign-invisible) and the forge overwrites
-        # them before any aggregator reads them.  A block straddling the
-        # prefix boundary trains its malicious lanes harmlessly.
-        skip_blocks = 0
-        if (malicious_prefix is not None and malicious_prefix > 0
-                and (coord_forges or row_forges)):
-            skip_blocks = malicious_prefix // client_block
-            if skip_blocks and _checked_mask[0] is not malicious:
-                # Validate the caller's promise ONCE per mask object — a
-                # wrong mask would silently aggregate zero rows for
-                # benign clients.  Per-round checking would cost a
-                # host<->device fetch that drains the dispatch pipeline,
-                # so the check is cached by array identity.
-                import numpy as np
-
-                mal_np = np.asarray(malicious)  # blades-lint: disable=host-sync — once per mask object, by design (see comment above)
-                if not (bool(mal_np[:skip_blocks * client_block].all())
-                        and not bool(mal_np[malicious_prefix:].any())):
+                    f"{n} clients not divisible by block {client_block}")
+            if row_geom or row_forges:
+                # Checked BEFORE training: the round below donates the
+                # caller's opt state and burns a full training pass.
+                if fr.num_clients is not None and fr.num_clients != n:
                     raise ValueError(
-                        f"malicious_prefix={malicious_prefix} promised "
-                        "exactly the first lanes malicious, but the "
-                        "malicious mask disagrees — elision would zero "
-                        "benign updates (or treat trained malicious lanes "
-                        "as benign on the compacted path)"
+                        f"the streamed row-geometry finish needs num_clients "
+                        f"({fr.num_clients}) == data rows ({n}): ghost lanes "
+                        "would enter the row geometry — pick a client_block "
+                        "that divides num_clients"
                     )
-                _checked_mask[0] = malicious
-        # Benign-compacted fused finish: when the whole malicious prefix
-        # is elided block-aligned, the matrix stores ONLY the benign rows
-        # and the forged row enters the order statistics as a virtual row
-        # of multiplicity `malicious_prefix` (fused_finish_compact) —
-        # matrix HBM and per-row kernel work shrink by the byzantine
-        # fraction.
-        from blades_tpu.ops.pallas_select import kernel_applicable
+                from blades_tpu.parallel.streamed_geometry import (
+                    check_applicable,
+                )
 
-        nb = n - (malicious_prefix or 0)
-        # No nb % 8 gate: the buffer is allocated pre-padded to a sublane
-        # multiple with +inf rows the kernel excludes via num_real.
-        compact = (spec is not None and no_ghosts and coord_forges
-                   and skip_blocks > 0
-                   and malicious_prefix % client_block == 0
-                   and kernel_applicable(nb, d_model))
-        use_fused = use_fused or compact
-        # The fused pallas finishes want stripe-aligned columns; padding
-        # at allocation (zero columns, sliced off the aggregate) avoids a
-        # whole-matrix pad copy inside the kernel call.  The row-geometry
-        # path pads for the same reason whenever the fused row-stats
-        # kernel can serve its planner bundles (chunk traversals are
-        # bounded to d_model either way, so padding is inert on the
-        # fallback path).
-        pad_cols = use_fused
-        if row_geom or row_forges:
-            from blades_tpu.ops.pallas_rowstats import (
-                kernel_applicable as _rowstats_ok,
-            )
+                check_applicable(agg, n)
+            if d_model is None:
+                d_model = sum(
+                    p.size for p in jax.tree.leaves(state.server.params))
+            from blades_tpu.ops.pallas_round import should_use
 
-            pad_cols = pad_cols or _rowstats_ok(n, d_model)
-        if pad_cols:
-            from blades_tpu.ops.pallas_select import _BLOCK_D
+            # Per-call (n can differ between calls): ghost (padding) lanes
+            # force the chunked path — slicing them off before a pallas_call
+            # would materialize a second copy of the giant matrix, and the
+            # kernel has no lane-validity input.
+            no_ghosts = fr.num_clients is None or fr.num_clients == n
+            use_fused = (spec is not None and no_ghosts
+                         and should_use(n, d_model))
+            # Same RNG stream as FedRound.step.
+            k_sample, k_train, k_adv, k_agg, k_dp = jax.random.split(key, 5)
+            sample_keys = jax.random.split(k_sample, n)
+            train_keys = jax.random.split(k_train, n)
+            # Malicious-lane training elision (see malicious_prefix above):
+            # blocks fully inside the forged prefix never train — their rows
+            # stay zero (finite, benign-invisible) and the forge overwrites
+            # them before any aggregator reads them.  A block straddling the
+            # prefix boundary trains its malicious lanes harmlessly.
+            skip_blocks = 0
+            if (malicious_prefix is not None and malicious_prefix > 0
+                    and (coord_forges or row_forges)):
+                skip_blocks = malicious_prefix // client_block
+                if skip_blocks and _checked_mask[0] is not malicious:
+                    # Validate the caller's promise ONCE per mask object — a
+                    # wrong mask would silently aggregate zero rows for
+                    # benign clients.  Per-round checking would cost a
+                    # host<->device fetch that drains the dispatch pipeline,
+                    # so the check is cached by array identity.
+                    import numpy as np
 
-            d_alloc = -(-d_model // _BLOCK_D) * _BLOCK_D
-        else:
-            d_alloc = d_model
-        rows = -(-nb // 8) * 8 if compact else n
-        row_shift = malicious_prefix if compact else 0
-        if compact and rows != nb:
-            updates_buf = _alloc_row_padded(rows, nb, d_alloc)
-        else:
-            updates_buf = jnp.zeros((rows, d_alloc), update_dtype)
-        client_opt = state.client_opt
-        if not donate:
-            client_opt = jax.tree.map(jnp.copy, client_opt)
-        losses, norms = [], []
-        for b in range(n // client_block):
-            if b < skip_blocks:
-                losses.append(jnp.zeros((client_block,), jnp.float32))
-                norms.append(jnp.zeros((client_block,), jnp.float32))
-                continue
-            updates_buf, client_opt, loss, blk_norms = _train_block(
-                updates_buf, client_opt, state.server.params, data_x, data_y,
-                lengths, malicious, sample_keys, train_keys,
-                jnp.int32(b * client_block),
-                jnp.int32(b * client_block - row_shift),
-            )
+                    mal_np = np.asarray(malicious)  # blades-lint: disable=host-sync — once per mask object, by design (see comment above)
+                    if not (bool(mal_np[:skip_blocks * client_block].all())
+                            and not bool(mal_np[malicious_prefix:].any())):
+                        raise ValueError(
+                            f"malicious_prefix={malicious_prefix} promised "
+                            "exactly the first lanes malicious, but the "
+                            "malicious mask disagrees — elision would zero "
+                            "benign updates (or treat trained malicious lanes "
+                            "as benign on the compacted path)"
+                        )
+                    _checked_mask[0] = malicious
+            # Benign-compacted fused finish: when the whole malicious prefix
+            # is elided block-aligned, the matrix stores ONLY the benign rows
+            # and the forged row enters the order statistics as a virtual row
+            # of multiplicity `malicious_prefix` (fused_finish_compact) —
+            # matrix HBM and per-row kernel work shrink by the byzantine
+            # fraction.
+            from blades_tpu.ops.pallas_select import kernel_applicable
+
+            nb = n - (malicious_prefix or 0)
+            # No nb % 8 gate: the buffer is allocated pre-padded to a sublane
+            # multiple with +inf rows the kernel excludes via num_real.
+            compact = (spec is not None and no_ghosts and coord_forges
+                       and skip_blocks > 0
+                       and malicious_prefix % client_block == 0
+                       and kernel_applicable(nb, d_model))
+            use_fused = use_fused or compact
+            # The fused pallas finishes want stripe-aligned columns; padding
+            # at allocation (zero columns, sliced off the aggregate) avoids a
+            # whole-matrix pad copy inside the kernel call.  The row-geometry
+            # path pads for the same reason whenever the fused row-stats
+            # kernel can serve its planner bundles (chunk traversals are
+            # bounded to d_model either way, so padding is inert on the
+            # fallback path).
+            pad_cols = use_fused
+            if row_geom or row_forges:
+                from blades_tpu.ops.pallas_rowstats import (
+                    kernel_applicable as _rowstats_ok,
+                )
+
+                pad_cols = pad_cols or _rowstats_ok(n, d_model)
+            if pad_cols:
+                from blades_tpu.ops.pallas_select import _BLOCK_D
+
+                d_alloc = -(-d_model // _BLOCK_D) * _BLOCK_D
+            else:
+                d_alloc = d_model
+            rows = -(-nb // 8) * 8 if compact else n
+            row_shift = malicious_prefix if compact else 0
+            if compact and rows != nb:
+                updates_buf = _alloc_row_padded(rows, nb, d_alloc)
+            else:
+                updates_buf = jnp.zeros((rows, d_alloc), update_dtype)
+            client_opt = state.client_opt
+            if not donate:
+                client_opt = jax.tree.map(jnp.copy, client_opt)
+            # Elided blocks: no program runs, their losses and norms read 0.
+            losses = [jnp.zeros((client_block,), jnp.float32)
+                      for _ in range(skip_blocks)]
+            norms = [jnp.zeros((client_block,), jnp.float32)
+                     for _ in range(skip_blocks)]
+        for b in range(skip_blocks, n // client_block):
+            with span("blades/block"):
+                updates_buf, client_opt, loss, blk_norms = _train_block(
+                    updates_buf, client_opt, state.server.params, data_x,
+                    data_y, lengths, malicious, sample_keys, train_keys,
+                    jnp.int32(b * client_block),
+                    jnp.int32(b * client_block - row_shift),
+                )
             losses.append(loss)
             norms.append(blk_norms)
-        if row_geom or row_forges:
-            from blades_tpu.parallel.streamed_geometry import chunk_grid
+        with span("blades/finish"):
+            if row_geom or row_forges:
+                from blades_tpu.parallel.streamed_geometry import chunk_grid
 
-            c, k_chunks, _ = chunk_grid(d_model, d_chunk)
-            if _rowgeom_rewrites:
-                sq = jnp.zeros((n,), jnp.float32)
-                bad = jnp.zeros((n,), bool)
-                cat_norms = jnp.concatenate(norms)
-                for i in range(k_chunks):
-                    updates_buf, sq, bad = _rowgeom_mat_chunk(
-                        updates_buf, sq, bad, malicious, cat_norms,
-                        k_adv, k_dp, jnp.int32(i),
-                        jnp.int32(min(i * c, d_model - c)),
+                c, k_chunks, _ = chunk_grid(d_model, d_chunk)
+                if _rowgeom_rewrites:
+                    sq = jnp.zeros((n,), jnp.float32)
+                    bad = jnp.zeros((n,), bool)
+                    cat_norms = jnp.concatenate(norms)
+                    for i in range(k_chunks):
+                        updates_buf, sq, bad = _rowgeom_mat_chunk(
+                            updates_buf, sq, bad, malicious, cat_norms,
+                            k_adv, k_dp, jnp.int32(i),
+                            jnp.int32(min(i * c, d_model - c)),
+                        )
+                else:
+                    # Read-only buffer: no dedicated row-norm traversal — the
+                    # sq request fuses into the forge's/aggregator's first
+                    # statistics bundle (sq=None threads through).
+                    sq = None
+                    bad = jnp.zeros((n,), bool)
+                if row_forges:
+                    # Stats passes -> forged (d,) row, then scatter it into
+                    # the malicious lanes chunk by chunk (donated buffer).
+                    forged, sq = _forge_row(updates_buf, malicious, sq, k_adv)
+                    for i in range(k_chunks):
+                        updates_buf = _scatter_chunk(
+                            updates_buf, forged, malicious,
+                            jnp.int32(min(i * c, d_model - c)),
+                        )
+                if row_geom:
+                    server, metrics = _rowgeom_aggregate(
+                        state.server, updates_buf, malicious,
+                        jnp.concatenate(losses), sq, bad, k_agg,
                     )
-            else:
-                # Read-only buffer: no dedicated row-norm traversal — the
-                # sq request fuses into the forge's/aggregator's first
-                # statistics bundle (sq=None threads through).
-                sq = None
-                bad = jnp.zeros((n,), bool)
-            if row_forges:
-                # Stats passes -> forged (d,) row, then scatter it into
-                # the malicious lanes chunk by chunk (donated buffer).
-                forged, sq = _forge_row(updates_buf, malicious, sq, k_adv)
-                for i in range(k_chunks):
-                    updates_buf = _scatter_chunk(
-                        updates_buf, forged, malicious,
-                        jnp.int32(min(i * c, d_model - c)),
+                else:
+                    server, metrics = _coordwise_after_forge(
+                        state.server, updates_buf, malicious,
+                        jnp.concatenate(losses), sq, bad,
                     )
-            if row_geom:
-                server, metrics = _rowgeom_aggregate(
+            elif compact:
+                server, metrics = _finish_fused_compact(
                     state.server, updates_buf, malicious,
-                    jnp.concatenate(losses), sq, bad, k_agg,
+                    jnp.concatenate(losses), k_adv, nb_real=nb,
+                )
+            elif use_fused:
+                server, metrics = _finish_fused(
+                    state.server, updates_buf, malicious,
+                    jnp.concatenate(losses), k_adv,
                 )
             else:
-                server, metrics = _coordwise_after_forge(
+                server, metrics = _finish(
                     state.server, updates_buf, malicious,
-                    jnp.concatenate(losses), sq, bad,
+                    jnp.concatenate(losses), jnp.concatenate(norms),
+                    k_adv, k_dp,
                 )
-        elif compact:
-            server, metrics = _finish_fused_compact(
-                state.server, updates_buf, malicious, jnp.concatenate(losses),
-                k_adv, nb_real=nb,
-            )
-        elif use_fused:
-            server, metrics = _finish_fused(
-                state.server, updates_buf, malicious, jnp.concatenate(losses),
-                k_adv,
-            )
-        else:
-            server, metrics = _finish(
-                state.server, updates_buf, malicious, jnp.concatenate(losses),
-                jnp.concatenate(norms), k_adv, k_dp,
-            )
-        if row_geom or row_forges:
-            # Pass-fusion telemetry (schema-registered, stamped host-side
-            # like elided_lanes): planned full-matrix HBM traversals this
-            # round — the fused plan vs the one-traversal-per-statistic
-            # baseline.  Planner counts fill at first trace; the fixed
-            # components are the materialization rewrite and the forged-
-            # row scatter, each one traversal.  Data-dependent Weiszfeld
-            # loops count maxiter iterations (a planned upper bound).
-            fixed_passes = ((1 if _rowgeom_rewrites else 0)
-                            + (1 if row_forges else 0))
-            metrics["hbm_passes"] = jnp.int32(
-                _pass_recorder.executed + fixed_passes)
-            metrics["hbm_passes_unfused"] = jnp.int32(
-                _pass_recorder.unfused + fixed_passes)
-            _pass_recorder.finalize()
-        if skip_blocks:
-            # Elision telemetry (schema-registered): lanes whose training
-            # blocks were skipped this round — the lanes num_unhealthy can
-            # never count (an elided lane never trains, so it cannot trip
-            # the health detectors; see parallel/dsharded.py's elision
-            # caveats for the shared contract).  Only added when elision
-            # engages, so non-elided rounds' metrics are unchanged.
-            metrics["elided_lanes"] = jnp.int32(skip_blocks * client_block)
+            if row_geom or row_forges:
+                # Pass-fusion telemetry (schema-registered, stamped host-side
+                # like elided_lanes): planned full-matrix HBM traversals this
+                # round — the fused plan vs the one-traversal-per-statistic
+                # baseline.  Planner counts fill at first trace; the fixed
+                # components are the materialization rewrite and the forged-
+                # row scatter, each one traversal.  Data-dependent Weiszfeld
+                # loops count maxiter iterations (a planned upper bound).
+                fixed_passes = ((1 if _rowgeom_rewrites else 0)
+                                + (1 if row_forges else 0))
+                metrics["hbm_passes"] = jnp.int32(
+                    _pass_recorder.executed + fixed_passes)
+                metrics["hbm_passes_unfused"] = jnp.int32(
+                    _pass_recorder.unfused + fixed_passes)
+                _pass_recorder.finalize()
+            if skip_blocks:
+                # Elision telemetry (schema-registered): lanes whose training
+                # blocks were skipped this round — the lanes num_unhealthy can
+                # never count (an elided lane never trains, so it cannot trip
+                # the health detectors; see parallel/dsharded.py's elision
+                # caveats for the shared contract).  Only added when elision
+                # engages, so non-elided rounds' metrics are unchanged.
+                metrics["elided_lanes"] = jnp.int32(
+                    skip_blocks * client_block)
         return RoundState(server=server, client_opt=client_opt), metrics
 
     # Expose the jitted phases for profiling / inspection.  A round runs

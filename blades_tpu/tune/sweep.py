@@ -802,7 +802,7 @@ def run_experiments(
     training/eval metrics, defense-forensics scalars (``forensics=True``
     trials), health counts, and per-phase timings.  Each summary gains
     ``timers`` (sweep-level compile / round / eval / checkpoint phases,
-    ``utils/timers.py``; evaluation runs inside ``algo.train()``, so the
+    ``obs/trace.py``; evaluation runs inside ``algo.train()``, so the
     ``eval`` phase OVERLAPS compile/round rather than adding to them —
     subtract it for pure-training estimates) and
     ``cost`` (XLA's compiled FLOPs/bytes for one

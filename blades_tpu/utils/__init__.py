@@ -1,4 +1,3 @@
-from blades_tpu.utils.timers import Timers  # noqa: F401
 from blades_tpu.utils.tree import (  # noqa: F401
     ravel_fn,
     tree_size,

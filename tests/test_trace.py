@@ -19,6 +19,7 @@ Four layers of coverage:
    identically; the preemption itself leaves a flight-recorder dump.
 """
 
+import dataclasses
 import json
 import math
 from pathlib import Path
@@ -34,6 +35,7 @@ from blades_tpu.obs.flightrec import (  # noqa: E402
     FlightRecorder,
     validate_flightrec,
 )
+import blades_tpu.obs.trace as trace_mod  # noqa: E402
 from blades_tpu.obs.trace import (  # noqa: E402
     Timers,
     Tracer,
@@ -115,14 +117,78 @@ def test_chrome_validator_tolerates_torn_file(tmp_path):
     assert "unreadable" in errors[0]
 
 
-def test_timers_shims_still_import():
-    """The consolidation satellite keeps both PR-1 modules importable."""
-    from blades_tpu.utils.profiling import annotate, trace, xla_dump_flags
-    from blades_tpu.utils.timers import Timers as ShimTimers
+def test_shims_and_unused_wrappers_are_gone():
+    """The PR-1 shim modules and the two wrappers nothing called went
+    with them; the span layer keeps the names that have callers."""
+    import importlib
 
-    assert ShimTimers is Timers
-    assert callable(trace) and callable(annotate)
-    assert "--xla_dump_to=/x" in xla_dump_flags("/x")
+    import blades_tpu.obs.trace as T
+    import blades_tpu.utils as U
+
+    for name in ("blades_tpu.utils.timers", "blades_tpu.utils.profiling"):
+        with pytest.raises(ModuleNotFoundError):
+            importlib.import_module(name)
+    assert not hasattr(U, "Timers")
+    assert not hasattr(T, "annotate") and not hasattr(T, "xla_dump_flags")
+    assert callable(T.trace) and callable(T.span) and T.Timers is Timers
+
+
+def test_unarmed_tracer_retains_nothing_and_still_aggregates():
+    t = Tracer()
+    for i in range(1000):
+        with t.span("round", step=i, plan_id="p") as sp:
+            with trace_mod.span("phase"):
+                pass
+    assert t._roots == [] and t._stack == [] and t._latest == {}
+    assert t._recorded == 0 and sp._ann is None and "_ann" not in sp.attrs
+    s = t.summary()
+    assert s["round"]["count"] == 1000 and s["phase"]["count"] == 1000
+    assert s["round"]["total_s"] >= s["phase"]["total_s"] > 0
+    assert trace_mod._open_tracers() == []
+
+
+def test_module_span_nests_under_the_open_tracer_and_is_inert_alone():
+    # No tracer open on this thread: the profiler annotation alone.
+    with trace_mod.span("blades/prepare") as sp:
+        assert sp is None
+    outer, inner = Tracer(record=True), Tracer(record=True)
+    with outer.span("trial"):
+        with trace_mod.span("blades/a"):
+            pass
+        with inner.span("blades/round", step=3):
+            # Innermost open tracer wins; an armed one nests the span.
+            with trace_mod.span("blades/block") as blk:
+                assert blk.name == "blades/block"
+        with trace_mod.span("blades/b"):
+            pass
+    assert set(outer.summary()) == {"trial", "blades/a", "blades/b"}
+    assert set(inner.summary()) == {"blades/round", "blades/block"}
+    assert [c.name for c in inner._roots[0].children] == ["blades/block"]
+    assert [c.name for c in outer._roots[0].children] == ["blades/a",
+                                                          "blades/b"]
+    # A span of another thread's tracer is not this thread's.
+    import threading
+
+    seen = []
+    with outer.span("trial"):
+        th = threading.Thread(
+            target=lambda: seen.append(list(trace_mod._open_tracers())))
+        th.start()
+        th.join(timeout=10)
+    assert seen == [[]]
+    assert trace_mod._open_tracers() == []
+
+
+def test_adopted_tracer_keeps_what_the_instance_timed_before():
+    mine, theirs = Tracer(), Tracer(record=True)
+    with mine.span("blades/setup"):
+        pass
+    with theirs.span("trial"):
+        pass
+    theirs.absorb(mine)
+    s = theirs.summary()
+    assert s["blades/setup"] == mine.summary()["blades/setup"]
+    assert s["trial"]["count"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -432,6 +498,191 @@ def _assert_identity(tmp_path, path_name):
     on_cmp = [{k: v for k, v in r.items() if k != "trial"}
               for r in _strip(on)]
     assert off_cmp == on_cmp, f"{path_name}: rows diverged"
+
+
+# ---------------------------------------------------------------------------
+# the streamed round's own phases (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+_STREAMED_CFG = {
+    "dataset_config": {"type": "mnist", "num_clients": 8, "train_bs": 4},
+    "global_model": "mlp", "evaluation_interval": 0,
+    "num_malicious_clients": 2, "adversary_config": {"type": "ALIE"},
+    "execution": "streamed", "client_block": 2,
+    "server_config": {"lr": 1.0, "aggregator": {"type": "Median"}},
+}
+_ROUND_PHASES = ("blades/prepare", "blades/block", "blades/finish",
+                 "blades/fetch", "blades/row")
+
+
+def _streamed_algo():
+    from blades_tpu.algorithms import get_algorithm_class
+
+    _, config = get_algorithm_class("FEDAVG", return_config=True)
+    config.update_from_dict(json.loads(json.dumps(_STREAMED_CFG)))
+    return config.build()
+
+
+@pytest.fixture(scope="module")
+def streamed_two_rounds(tmp_path_factory):
+    """Two streamed rounds (8 clients, blocks of 2, the first block
+    elided), twice: un-armed under a jax.profiler capture, and with an
+    armed tracer adopted."""
+    import jax
+
+    plain = _streamed_algo()
+    trace_dir = tmp_path_factory.mktemp("profile")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        plain_rows = [plain.train() for _ in range(2)]
+    finally:
+        jax.profiler.stop_trace()
+    armed = _streamed_algo()
+    tracer = Tracer(record=True)
+    armed.adopt_tracer(tracer)
+    armed_rows = [armed.train() for _ in range(2)]
+    return {"plain": plain, "plain_rows": plain_rows, "tracer": tracer,
+            "armed_rows": armed_rows, "trace_dir": trace_dir}
+
+
+def test_streamed_round_counts_its_phases_on_every_row(streamed_two_rounds):
+    rows = streamed_two_rounds["plain_rows"]
+    assert [r["elided_lanes"] for r in rows] == [2, 2]
+    want = {"blades/prepare": 1, "blades/block": 8 // 2 - 1,
+            "blades/finish": 1, "blades/fetch": 1, "blades/row": 1,
+            "blades/round": 1, "training_step": 1}
+    for i, row in enumerate(rows, start=1):
+        timers = row["timers"]
+        # Taken after the round's spans close: row i holds i whole rounds.
+        assert {k: timers[k]["count"] for k in want} == \
+            {k: i * c for k, c in want.items()}
+        assert timers["blades/setup"]["count"] == 1
+        assert sum(timers[k]["total_s"] for k in _ROUND_PHASES) \
+            <= timers["blades/round"]["total_s"]
+        assert timers["blades/prepare"]["total_s"] \
+            + timers["blades/block"]["total_s"] \
+            + timers["blades/finish"]["total_s"] \
+            + timers["blades/fetch"]["total_s"] \
+            <= timers["training_step"]["total_s"]
+    # A row's timers minus the previous row's is that round's phase times.
+    for k in _ROUND_PHASES + ("blades/round",):
+        assert rows[1]["timers"][k]["total_s"] > rows[0]["timers"][k]["total_s"]
+    setup = rows[0]["timers"]
+    assert {"blades/setup/data", "blades/setup/model",
+            "blades/setup/round"} <= set(setup)
+    assert max(setup[k]["total_s"] for k in setup
+               if k.startswith("blades/setup/")) \
+        <= setup["blades/setup"]["total_s"]
+    # Un-armed: nothing but the aggregates was kept.
+    t = streamed_two_rounds["plain"].timers
+    assert t._roots == [] and t._latest == {} and t._recorded == 0
+
+
+def test_streamed_rows_equal_armed_and_unarmed_but_for_timers(
+        streamed_two_rounds):
+    plain, armed = (streamed_two_rounds[k]
+                    for k in ("plain_rows", "armed_rows"))
+    assert _strip(plain) == _strip(armed)
+    assert all(math.isfinite(r["train_loss"]) for r in plain)
+    # Armed, the same spans are a tree: each phase inside its round.
+    roots = streamed_two_rounds["tracer"]._roots
+    rounds = [r for r in roots if r.name == "blades/round"]
+    assert [r.step for r in rounds] == [0, 1]
+    for r in rounds:
+        (step,) = [c for c in r.children if c.name == "training_step"]
+        assert [c.name for c in step.children] == \
+            ["blades/prepare"] + ["blades/block"] * 3 + \
+            ["blades/finish", "blades/fetch"]
+        assert [c.name for c in r.children] == ["training_step",
+                                                "blades/row"]
+    # What the instance timed before it adopted the tracer came along.
+    assert armed[0]["timers"]["blades/setup"]["count"] == 1
+
+
+def test_profiler_capture_holds_the_phases_on_the_driving_thread(
+        streamed_two_rounds):
+    """The un-armed run's spans reached the profiler's trace: on one
+    thread line, each inside its ``blades/round``."""
+    import glob
+
+    import jax
+
+    (pb,) = glob.glob(str(streamed_two_rounds["trace_dir"]
+                          / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(pb)
+    lines = []
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events
+                   if e.name.startswith("blades/")
+                   or e.name == "training_step"]
+            if evs:
+                lines.append(evs)
+    (evs,) = lines                      # one thread line holds them all
+    rounds = [e for e in evs if e[0] == "blades/round"]
+    assert len(rounds) == 2
+    counts = {}
+    for name, s, e in evs:
+        if name == "blades/round":
+            continue
+        counts[name] = counts.get(name, 0) + 1
+        assert sum(r[1] <= s and e <= r[2] for r in rounds) == 1, name
+    assert counts == {"training_step": 2, "blades/prepare": 2,
+                      "blades/block": 6, "blades/finish": 2,
+                      "blades/fetch": 2, "blades/row": 2}
+
+
+def _scopes(lowered) -> set:
+    import re
+
+    return set(re.findall(r"blades/[a-z]+", lowered.as_text(debug_info=True)))
+
+
+def test_streamed_programs_carry_the_device_scopes(streamed_two_rounds,
+                                                   monkeypatch):
+    """Trace-time metadata: the block wears sample/step/store, the
+    chunked finish forge/aggregate, and the compact finish, whose forge
+    and aggregate are one Mosaic call, aggregate (plus forge for the
+    adaptive forge's uniforms)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from blades_tpu.adversaries import get_adversary
+    from blades_tpu.ops import pallas_round
+    from blades_tpu.parallel.streamed import streamed_step
+
+    algo = streamed_two_rounds["plain"]
+    step, st, n, d = algo._step, algo.state, 8, algo._num_params
+    x, y, ln = algo._train_arrays
+    key = jax.random.PRNGKey(0)
+    keys = jax.random.split(key, n)
+    buf = jnp.zeros((n, d), jnp.bfloat16)
+    zeros = jnp.zeros((n,), jnp.float32)
+    assert _scopes(step.train_block.lower(
+        buf, st.client_opt, st.server.params, x, y, ln, algo.malicious,
+        keys, keys, jnp.int32(2), jnp.int32(2))) == \
+        {"blades/sample", "blades/step", "blades/store"}
+    assert _scopes(step.finish.lower(
+        st.server, buf, algo.malicious, zeros, zeros, key, key)) == \
+        {"blades/forge", "blades/aggregate"}
+
+    monkeypatch.setattr(
+        pallas_round, "fused_finish_compact",
+        functools.partial(pallas_round.fused_finish_compact, interpret=True))
+    for adv, want in (("ALIE", {"blades/aggregate"}),
+                      ("Adaptive", {"blades/forge", "blades/aggregate"})):
+        fr = dataclasses.replace(
+            algo.fed_round,
+            adversary=get_adversary(adv, num_clients=n, num_byzantine=2))
+        compact = streamed_step(fr, client_block=2, malicious_prefix=2)
+        assert _scopes(compact.finish_fused_compact.lower(
+            st.server, jnp.zeros((8, 128 * -(-d // 128)), jnp.bfloat16),
+            algo.malicious, zeros, key, nb_real=6)) == want
 
 
 def test_chaos_nan_dump_replays_bit_identically(tmp_path):

@@ -1,7 +1,6 @@
 """trace-discipline: every measured second flows through the span layer.
 
-ISSUE 12 consolidated ``utils/timers.py`` + ``utils/profiling.py`` into
-:mod:`blades_tpu.obs.trace` as the SINGLE timing source of truth: phase
+:mod:`blades_tpu.obs.trace` is the SINGLE timing source of truth: phase
 durations are spans (they aggregate, nest, export to Chrome traces, and
 correlate with the jax profiler), and the sanctioned raw clock is
 ``obs.trace.now()``.  A raw ``time.time()`` / ``time.perf_counter()`` /
@@ -10,8 +9,8 @@ duration nobody can see in a trace — the drift this pass freezes out,
 exactly like host-sync froze out stray ``device_get``\\ s.
 
 Scope: ``blades_tpu/`` only (bench.py and tools/ are measurement
-harnesses outside the traced driver).  The trace/timer modules
-themselves are the allowed homes.  Detection covers the module-attribute
+harnesses outside the traced driver).  The trace module itself is the
+allowed home.  Detection covers the module-attribute
 form (``time.perf_counter()``), ``from time import perf_counter``
 aliases, and the ``_ns`` variants; ``time.sleep`` is not a measurement
 and stays legal, as does passing ``time.perf_counter`` itself as an
@@ -28,12 +27,9 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set
 from tools.lint import astutil
 from tools.lint.core import Finding, LintContext, LintPass
 
-#: Where raw clock reads are legal: the span layer itself and its
-#: back-compat shims.
+#: Where raw clock reads are legal: the span layer itself.
 TIMER_MODULES = (
     "blades_tpu/obs/trace.py",
-    "blades_tpu/utils/timers.py",
-    "blades_tpu/utils/profiling.py",
 )
 
 #: ``time`` module attributes whose CALL is a duration/wall-clock read.
