@@ -155,8 +155,7 @@ def bench_workload(model: str, num_clients: int, client_block: int,
     # the stored size.
     from blades_tpu.ops.pallas_select import kernel_applicable
 
-    compacted = (kernel_applicable(num_clients - num_byzantine, d)
-                 and num_byzantine % client_block == 0)
+    compacted = kernel_applicable(num_clients - num_byzantine, d)
     if not compacted:
         raise RuntimeError(
             "benign-compacted streamed path not engaged (non-TPU backend "

@@ -111,7 +111,10 @@ class FedavgConfig:
         # the server fires a staleness-weighted robust aggregation every
         # K buffered arrivals (configure via .arrivals()).
         self.execution: str = "auto"
-        self.client_block: int = 50        # clients per streamed dispatch
+        # Clients per streamed dispatch: an upper bound; the round takes
+        # the largest whole number of storage tiles under it, and pads
+        # the last block (parallel/streamed.py::block_plan).
+        self.client_block: int = 50
         self.d_chunk: int = 1 << 17        # coords per streamed agg chunk
         self.update_dtype: str = "bfloat16"  # streamed matrix storage
         # MXU finish variant for the streamed pallas finish

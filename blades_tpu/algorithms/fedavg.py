@@ -402,7 +402,7 @@ class Fedavg:
                     x, y, ln = self._train_arrays
                     self._train_arrays = (x.astype(jnp.dtype(cd)), y, ln)
             streamed_kw = dict(
-                client_block=self._streamed_block(),
+                client_block=cfg.client_block,
                 d_chunk=cfg.d_chunk,
                 mxu_finish=getattr(cfg, "mxu_finish", None),
                 update_dtype=getattr(jnp, str(cfg.update_dtype)),
@@ -1089,29 +1089,6 @@ class Fedavg:
             }
         self.fed_round = fr
 
-    def _streamed_block(self) -> int:
-        """Largest divisor of num_clients that is <= the configured
-        client_block (the streamed path needs an exact tiling).  A client
-        count with no usable divisor (e.g. prime) silently degrading to
-        1-client dispatches would be a ~50x slowdown — warn loudly."""
-        n, want = self.config.num_clients, max(1, self.config.client_block)
-        block = 1
-        for b in range(min(want, n), 0, -1):
-            if n % b == 0:
-                block = b
-                break
-        if block < max(2, want // 4) and n > want:
-            import warnings
-
-            warnings.warn(
-                f"num_clients={n} has no divisor near client_block={want}; "
-                f"the streamed round degrades to {block}-client dispatches "
-                f"({n // block} per round). Pick a client count divisible "
-                "by the block (or a block dividing the count).",
-                stacklevel=2,
-            )
-        return block
-
     def _attach_root_data(self, fed_round: FedRound) -> FedRound:
         """Carve a clean server root dataset for FLTrust (Cao et al.): a few
         rows from every client's training shard, round-robin, up to
@@ -1557,6 +1534,14 @@ class Fedavg:
             # elided lane never trains, so it can never trip the health
             # counters (see parallel/dsharded.py caveats).
             row["elided_lanes"] = int(metrics["elided_lanes"])
+        if "store_blocks" in metrics:
+            # Streamed path (parallel/streamed.py::block_plan): the
+            # round's matrix stores, how many were whole storage tiles at
+            # a tile-aligned row, and the lanes its padded last block
+            # trained again and dropped.
+            for name in ("store_blocks", "store_blocks_aligned",
+                         "surplus_lanes"):
+                row[name] = int(metrics[name])
         if self.config.fault_config:  # chaos layer (blades_tpu/faults)
             # Participation is per round; the dispatch summary reports the
             # LAST round (consistent with the scalar metrics above) plus

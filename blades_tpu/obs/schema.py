@@ -160,6 +160,15 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     # num_unhealthy basis — elided lanes can never trip health counters —
     # is visible in telemetry.
     "elided_lanes": ((int,), False),
+    # Streamed path (parallel/streamed.py::block_plan): training blocks
+    # whose rows were stored into the update matrix this round, how many
+    # of those stores were a whole number of storage tiles at a
+    # tile-aligned row (a plain copy on the chip; the rest take the
+    # general read-modify-write), and the lanes the padded last block
+    # trained a second time and dropped.
+    "store_blocks": ((int,), False),
+    "store_blocks_aligned": ((int,), False),
+    "surplus_lanes": ((int,), False),
     # Row-geometry pass fusion (parallel/streamed_geometry.py): planned
     # full-matrix HBM traversals the streamed row-geometry finish runs
     # this round under the fused pass plan, vs what the

@@ -13,8 +13,10 @@ answers:
   exponent preserves ordering; VERDICT r1 explicitly flags the f32->bf16
   update matrix as headroom) and (b) never holding a second copy or a
   giant fused program: the round is a SEQUENCE of small dispatches —
-  per-client-block training programs that write rows into a DONATED
-  ``(n, d)`` buffer, then one donated finish program that forges and
+  per-client-block training programs (ONE compiled shape: tile-sized
+  blocks whose last block is padded, :func:`block_plan`) that write rows
+  into a DONATED ``(n, d)`` buffer, then one donated finish program that
+  forges and
   aggregates in d-chunks under ``lax.scan`` (sort workspace lives
   per-chunk).  A previous single-program formulation planned ~2x the
   matrix in HLO temps from allocator fragmentation and OOM'd at the
@@ -54,7 +56,7 @@ finish (sanitize + forge + aggregate + row norms) runs as ONE fused
 pallas kernel in a single HBM pass over the stored matrix
 (:mod:`blades_tpu.ops.pallas_round`), with a 16-step radix select in
 bf16 key space when storage is bf16 — ~3.5x the chunked finish at
-n=1000 x d=4.9M.  When the malicious prefix is elided block-aligned
+n=1000 x d=4.9M.  When the malicious prefix is elided
 (``malicious_prefix``), the matrix is further COMPACTED to the benign
 rows only and the forged row enters the order statistics as a virtual
 row of multiplicity f (``fused_finish_compact``) — per-row kernel work
@@ -70,10 +72,11 @@ does NOT fit one chip — that is what the mesh is for.
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from blades_tpu.adversaries.base import Adversary
@@ -99,6 +102,66 @@ _COORDWISE_AGGREGATORS = (Mean, Median, Trimmedmean)
 # design, so it repeats the literal) all pin the same 1 << 17; the
 # autotuner's chunk tests assert the agreement.
 DEFAULT_D_CHUNK = 1 << 17
+
+
+class BlockPlan(NamedTuple):
+    """How one round's trained lanes ``[first_lane, n)`` split into
+    ``blocks`` dispatches of ``_train_block``, each of ``block`` lanes:
+    ONE compiled shape.  Block ``i`` trains client lanes from
+    ``first_lane + i * block`` and stores its rows from matrix row
+    ``first_row + i * block``.  Where the trained lanes are no whole
+    number of blocks, the last block starts ``surplus`` lanes early (at
+    lane ``n - block``): its first ``surplus`` lanes are clients that the
+    block before it already trained (or elided ones).  They train again
+    and all they produce is dropped."""
+
+    first_lane: int
+    first_row: int
+    block: int
+    blocks: int
+    surplus: int
+    tile: int
+    # Every store is a whole number of storage tiles at a whole-block
+    # row of a matrix a whole number of blocks high: the tile copy's
+    # geometry (ops/pallas_store.py), whatever the backend.
+    whole_tiles: bool
+
+    @property
+    def aligned_stores(self) -> int:
+        """Dispatches whose store is a whole number of storage tiles at
+        a tile-aligned matrix row.  All or none: one compiled block
+        stores all of a round's blocks the same way."""
+        return self.blocks if self.whole_tiles else 0
+
+
+def block_plan(n: int, prefix: int, client_block: int, dtype, *,
+               compact: bool) -> BlockPlan:
+    """Block geometry from the storage type.  TPU HBM holds a matrix in
+    tiles of 8 sublanes of 32-bit words, so a storage tile is 8 rows of
+    a 4-byte ``dtype`` and 16 of a 2-byte one; a block stores as a plain
+    copy (ops/pallas_store.py) only where it is a whole number of tiles
+    at a tile boundary.
+    The dispatch size is the largest multiple of the tile's rows under
+    ``client_block`` (and under the lanes there are), or that bound
+    itself where it is under one tile.  Every dispatch has that size:
+    the last one is padded with ``surplus`` lanes, not cut short.
+
+    ``prefix`` lanes are elidable (0: train everyone).  The compact
+    matrix stores benign rows only, padded with ``+inf`` rows to a whole
+    number of blocks, so training starts at ``prefix`` and row 0;
+    otherwise rows are lanes, and training starts at the block boundary
+    under ``prefix``: the malicious lanes in between train harmlessly."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    cap = max(1, min(client_block, n - prefix if compact else n))
+    block = cap // tile * tile or cap
+    if compact:
+        first_lane, first_row = prefix, 0
+    else:
+        first_lane = first_row = prefix // block * block
+    blocks = -(-(n - first_lane) // block)
+    surplus = blocks * block - (n - first_lane)
+    return BlockPlan(first_lane, first_row, block, blocks, surplus, tile,
+                     block % tile == 0 and (compact or not surplus))
 
 
 def _fused_spec(fr: FedRound):
@@ -162,8 +225,10 @@ def streamed_step(
     equally valid) forged rows; see :mod:`blades_tpu.ops.pallas_round`.
 
     Args:
-        client_block: clients trained per dispatch (bounds activation
-            memory; must divide ``num_clients``).
+        client_block: upper bound on the clients trained per dispatch
+            (bounds activation memory).  The round takes the largest
+            whole number of storage tiles under it, and pads the last
+            block (:func:`block_plan`); any ``num_clients`` runs.
         d_chunk: coordinates forged+aggregated per ``lax.scan`` iteration
             (bounds the f32 chunk + sort workspace).
         update_dtype: storage dtype of the ``(n, d)`` update matrix.
@@ -183,9 +248,11 @@ def streamed_step(
             coordinate-wise and row-geometry update attack), the forged
             rows are computed purely from benign statistics and replace
             whatever the malicious clients trained — their local training
-            is dead computation, and training blocks that lie entirely
-            inside the prefix are skipped (~25% of the round at the
-            1/4-byzantine benchmark scale).  Exact: the post-forge
+            is dead computation, and the prefix's lanes are skipped: all
+            of them where the matrix is compacted to the benign rows,
+            otherwise down to the block boundary under the prefix
+            (:func:`block_plan`; ~25% of the round at the 1/4-byzantine
+            benchmark scale).  Exact: the post-forge
             matrix, aggregate, server state and all benign-side metrics
             are unchanged (train_loss already averages benign lanes
             only).  Observable differences: skipped lanes keep their
@@ -276,18 +343,57 @@ def streamed_step(
             )
         return chunk
 
-    @partial(jax.jit, donate_argnums=(0, 1))
+    @partial(jax.jit, donate_argnums=(0, 1), static_argnames=("plan",))
     def _train_block(updates_buf, client_opt, params, x, y, lengths,
-                     malicious, sample_keys, train_keys, row0, buf_row0):
-        """``row0`` indexes the CLIENT arrays; ``buf_row0`` the update
-        matrix row — they differ only on the benign-compacted path,
-        where the matrix stores no malicious-prefix rows.
+                     malicious, sample_keys, train_keys, index, *, plan):
+        """Train block ``index`` of ``plan`` and store its rows: one
+        compiled program for all of a round's blocks.  The client offset
+        and the matrix row are computed here, from an UNSIGNED index
+        times the static block size: no eager scalar program on the
+        host, and no ``select`` for a negative index in front of every
+        slice.  The two offsets differ only on the benign-compacted
+        path, where the matrix stores no malicious-prefix rows.
+
+        In a round with a short last block (``plan.surplus``) the slices
+        start at ``min(row0, n - block)``, and the ``head`` lanes by
+        which the last block starts early are surplus: their
+        ``client_opt`` lanes are written back as read and their rows are
+        not stored (the host drops their losses and norms).
+
+        Blocks of whole storage tiles are stored by the aliased tile
+        copy (ops/pallas_store.py) on a TPU; blocks under a tile, a full
+        matrix with a short last block and other backends take
+        ``lax.dynamic_update_slice``, whose TPU emitter read-modify-writes
+        partial tiles at any runtime offset, aligned or not.
 
         Device scopes (trace-time metadata, the dense body's names):
         ``blades/sample``, ``blades/step``, and ``blades/store`` for the
         norms and the writes into the matrix and ``client_opt``."""
+        from blades_tpu.ops.pallas_store import (
+            store_applicable,
+            store_row_block,
+        )
+
+        block = plan.block
+        index = index.astype(jnp.uint32)
+        row0 = index * jnp.uint32(block) + jnp.uint32(plan.first_lane)
+        if plan.surplus:
+            # What lax.dynamic_slice would clamp to anyway, made explicit.
+            lane0 = jnp.minimum(row0, jnp.uint32(x.shape[0] - block))
+            head = row0 - lane0
+            kept = lax.iota(jnp.uint32, block) >= head
+        else:
+            lane0 = row0
+
         def sl(a):
-            return lax.dynamic_slice_in_dim(a, row0, client_block, axis=0)
+            return lax.dynamic_slice_in_dim(a, lane0, block, axis=0)
+
+        def keep(new, old):
+            """``new``, but ``old`` in the surplus lanes."""
+            if not plan.surplus:
+                return new
+            return jnp.where(
+                kept.reshape((block,) + (1,) * (new.ndim - 1)), new, old)
 
         opt_b = jax.tree.map(sl, client_opt)
         with jax.named_scope("blades/sample"):
@@ -311,13 +417,28 @@ def streamed_step(
             # reduction is pure waste on non-DP rounds.
             norms = (jnp.linalg.norm(upd, axis=1) if dp
                      else jnp.zeros((upd.shape[0],), jnp.float32))
-            updates_buf = lax.dynamic_update_slice(
-                updates_buf, upd.astype(update_dtype), (buf_row0, 0)
-            )
+            upd = upd.astype(update_dtype)
+            if store_applicable(*updates_buf.shape, block, plan.first_row,
+                                plan.tile):
+                # Whole tiles at a tile boundary: a plain aliased copy of
+                # block `index`, the padded last block's included (its
+                # surplus rows dropped inside the copy).
+                updates_buf = store_row_block(
+                    updates_buf, upd,
+                    index + jnp.uint32(plan.first_row // block),
+                    head if plan.surplus else None, surplus=plan.surplus)
+            else:
+                buf_row0 = lane0 - jnp.uint32(
+                    plan.first_lane - plan.first_row)
+                at = (buf_row0, jnp.uint32(0))
+                if plan.surplus:
+                    upd = keep(upd, lax.dynamic_slice(
+                        updates_buf, at, upd.shape))
+                updates_buf = lax.dynamic_update_slice(updates_buf, upd, at)
             client_opt = jax.tree.map(
-                lambda full, blk: lax.dynamic_update_slice_in_dim(
-                    full, blk, row0, 0),
-                client_opt, opt2,
+                lambda full, new, old: lax.dynamic_update_slice_in_dim(
+                    full, keep(new, old), lane0, 0),
+                client_opt, opt2, opt_b,
             )
         return updates_buf, client_opt, loss, norms
 
@@ -646,9 +767,6 @@ def streamed_step(
         nonlocal d_model
         with span("blades/prepare"):
             n = data_x.shape[0]
-            if n % client_block:
-                raise ValueError(
-                    f"{n} clients not divisible by block {client_block}")
             if row_geom or row_forges:
                 # Checked BEFORE training: the round below donates the
                 # caller's opt state and burns a full training pass.
@@ -656,8 +774,7 @@ def streamed_step(
                     raise ValueError(
                         f"the streamed row-geometry finish needs num_clients "
                         f"({fr.num_clients}) == data rows ({n}): ghost lanes "
-                        "would enter the row geometry — pick a client_block "
-                        "that divides num_clients"
+                        "would enter the row geometry"
                     )
                 from blades_tpu.parallel.streamed_geometry import (
                     check_applicable,
@@ -681,49 +798,45 @@ def streamed_step(
             sample_keys = jax.random.split(k_sample, n)
             train_keys = jax.random.split(k_train, n)
             # Malicious-lane training elision (see malicious_prefix above):
-            # blocks fully inside the forged prefix never train — their rows
-            # stay zero (finite, benign-invisible) and the forge overwrites
-            # them before any aggregator reads them.  A block straddling the
-            # prefix boundary trains its malicious lanes harmlessly.
-            skip_blocks = 0
-            if (malicious_prefix is not None and malicious_prefix > 0
-                    and (coord_forges or row_forges)):
-                skip_blocks = malicious_prefix // client_block
-                if skip_blocks and _checked_mask[0] is not malicious:
-                    # Validate the caller's promise ONCE per mask object — a
-                    # wrong mask would silently aggregate zero rows for
-                    # benign clients.  Per-round checking would cost a
-                    # host<->device fetch that drains the dispatch pipeline,
-                    # so the check is cached by array identity.
-                    import numpy as np
-
-                    mal_np = np.asarray(malicious)  # blades-lint: disable=host-sync — once per mask object, by design (see comment above)
-                    if not (bool(mal_np[:skip_blocks * client_block].all())
-                            and not bool(mal_np[malicious_prefix:].any())):
-                        raise ValueError(
-                            f"malicious_prefix={malicious_prefix} promised "
-                            "exactly the first lanes malicious, but the "
-                            "malicious mask disagrees — elision would zero "
-                            "benign updates (or treat trained malicious lanes "
-                            "as benign on the compacted path)"
-                        )
-                    _checked_mask[0] = malicious
-            # Benign-compacted fused finish: when the whole malicious prefix
-            # is elided block-aligned, the matrix stores ONLY the benign rows
-            # and the forged row enters the order statistics as a virtual row
-            # of multiplicity `malicious_prefix` (fused_finish_compact) —
-            # matrix HBM and per-row kernel work shrink by the byzantine
-            # fraction.
+            # lanes of the forged prefix never train — their rows stay zero
+            # (finite, benign-invisible) and the forge overwrites them
+            # before any aggregator reads them.
+            elide = (malicious_prefix
+                     if malicious_prefix and (coord_forges or row_forges)
+                     else 0)
+            # Benign-compacted fused finish: with the prefix elided, the
+            # matrix stores ONLY the benign rows and the forged row enters
+            # the order statistics as a virtual row of multiplicity
+            # `malicious_prefix` (fused_finish_compact) — matrix HBM and
+            # per-row kernel work shrink by the byzantine fraction.
             from blades_tpu.ops.pallas_select import kernel_applicable
 
             nb = n - (malicious_prefix or 0)
-            # No nb % 8 gate: the buffer is allocated pre-padded to a sublane
-            # multiple with +inf rows the kernel excludes via num_real.
             compact = (spec is not None and no_ghosts and coord_forges
-                       and skip_blocks > 0
-                       and malicious_prefix % client_block == 0
-                       and kernel_applicable(nb, d_model))
+                       and elide > 0 and kernel_applicable(nb, d_model))
             use_fused = use_fused or compact
+            # Off the compact path rows are lanes, and training starts at
+            # the block boundary under the prefix (block_plan): the
+            # malicious lanes from there on train harmlessly.
+            plan = block_plan(n, elide, client_block, update_dtype,
+                              compact=compact)
+            if plan.first_lane and _checked_mask[0] is not malicious:
+                # Validate the caller's promise ONCE per mask object — a
+                # wrong mask would silently aggregate zero rows for
+                # benign clients.  Per-round checking would cost a
+                # host<->device fetch that drains the dispatch pipeline,
+                # so the check is cached by array identity.
+                mal_np = np.asarray(malicious)  # blades-lint: disable=host-sync — once per mask object, by design (see comment above)
+                if not (bool(mal_np[:plan.first_lane].all())
+                        and not bool(mal_np[malicious_prefix:].any())):
+                    raise ValueError(
+                        f"malicious_prefix={malicious_prefix} promised "
+                        "exactly the first lanes malicious, but the "
+                        "malicious mask disagrees — elision would zero "
+                        "benign updates (or treat trained malicious lanes "
+                        "as benign on the compacted path)"
+                    )
+                _checked_mask[0] = malicious
             # The fused pallas finishes want stripe-aligned columns; padding
             # at allocation (zero columns, sliced off the aggregate) avoids a
             # whole-matrix pad copy inside the kernel call.  The row-geometry
@@ -744,8 +857,10 @@ def streamed_step(
                 d_alloc = -(-d_model // _BLOCK_D) * _BLOCK_D
             else:
                 d_alloc = d_model
-            rows = -(-nb // 8) * 8 if compact else n
-            row_shift = malicious_prefix if compact else 0
+            # The compact matrix is a whole number of blocks (and of
+            # sublanes) high: its +inf padding rows, which the kernel
+            # excludes via num_real, take a padded last block's surplus.
+            rows = -(-(plan.blocks * plan.block) // 8) * 8 if compact else n
             if compact and rows != nb:
                 updates_buf = _alloc_row_padded(rows, nb, d_alloc)
             else:
@@ -753,21 +868,29 @@ def streamed_step(
             client_opt = state.client_opt
             if not donate:
                 client_opt = jax.tree.map(jnp.copy, client_opt)
-            # Elided blocks: no program runs, their losses and norms read 0.
-            losses = [jnp.zeros((client_block,), jnp.float32)
-                      for _ in range(skip_blocks)]
-            norms = [jnp.zeros((client_block,), jnp.float32)
-                     for _ in range(skip_blocks)]
-        for b in range(skip_blocks, n // client_block):
+            # Elided lanes: no program runs, their losses and norms read 0.
+            elided = ([jnp.zeros((plan.first_lane,), jnp.float32)]
+                      if plan.first_lane else [])
+            losses, norms = list(elided), list(elided)
+        for b in range(plan.blocks):
             with span("blades/block"):
+                # The index goes in as a NumPy scalar: an argument of the
+                # block's own launch, not an eager convert program.
                 updates_buf, client_opt, loss, blk_norms = _train_block(
                     updates_buf, client_opt, state.server.params, data_x,
                     data_y, lengths, malicious, sample_keys, train_keys,
-                    jnp.int32(b * client_block),
-                    jnp.int32(b * client_block - row_shift),
+                    np.uint32(b), plan=plan,
                 )
             losses.append(loss)
             norms.append(blk_norms)
+
+        def per_lane(parts):
+            """The blocks' per-lane vectors as one ``(n,)`` vector: the
+            padded last block's surplus lanes stop here."""
+            if plan.surplus:
+                parts = parts[:-1] + [parts[-1][plan.surplus:]]
+            return jnp.concatenate(parts)
+
         with span("blades/finish"):
             if row_geom or row_forges:
                 from blades_tpu.parallel.streamed_geometry import chunk_grid
@@ -776,7 +899,7 @@ def streamed_step(
                 if _rowgeom_rewrites:
                     sq = jnp.zeros((n,), jnp.float32)
                     bad = jnp.zeros((n,), bool)
-                    cat_norms = jnp.concatenate(norms)
+                    cat_norms = per_lane(norms)
                     for i in range(k_chunks):
                         updates_buf, sq, bad = _rowgeom_mat_chunk(
                             updates_buf, sq, bad, malicious, cat_norms,
@@ -801,27 +924,27 @@ def streamed_step(
                 if row_geom:
                     server, metrics = _rowgeom_aggregate(
                         state.server, updates_buf, malicious,
-                        jnp.concatenate(losses), sq, bad, k_agg,
+                        per_lane(losses), sq, bad, k_agg,
                     )
                 else:
                     server, metrics = _coordwise_after_forge(
                         state.server, updates_buf, malicious,
-                        jnp.concatenate(losses), sq, bad,
+                        per_lane(losses), sq, bad,
                     )
             elif compact:
                 server, metrics = _finish_fused_compact(
                     state.server, updates_buf, malicious,
-                    jnp.concatenate(losses), k_adv, nb_real=nb,
+                    per_lane(losses), k_adv, nb_real=nb,
                 )
             elif use_fused:
                 server, metrics = _finish_fused(
                     state.server, updates_buf, malicious,
-                    jnp.concatenate(losses), k_adv,
+                    per_lane(losses), k_adv,
                 )
             else:
                 server, metrics = _finish(
                     state.server, updates_buf, malicious,
-                    jnp.concatenate(losses), jnp.concatenate(norms),
+                    per_lane(losses), per_lane(norms),
                     k_adv, k_dp,
                 )
             if row_geom or row_forges:
@@ -839,20 +962,27 @@ def streamed_step(
                 metrics["hbm_passes_unfused"] = jnp.int32(
                     _pass_recorder.unfused + fixed_passes)
                 _pass_recorder.finalize()
-            if skip_blocks:
+            if plan.first_lane:
                 # Elision telemetry (schema-registered): lanes whose training
-                # blocks were skipped this round — the lanes num_unhealthy can
+                # was skipped this round — the lanes num_unhealthy can
                 # never count (an elided lane never trains, so it cannot trip
                 # the health detectors; see parallel/dsharded.py's elision
                 # caveats for the shared contract).  Only added when elision
                 # engages, so non-elided rounds' metrics are unchanged.
-                metrics["elided_lanes"] = jnp.int32(
-                    skip_blocks * client_block)
+                metrics["elided_lanes"] = np.int32(plan.first_lane)
+            # Store telemetry (schema-registered, host-side like
+            # elided_lanes): the blocks whose rows were written into the
+            # matrix, how many of those writes were whole storage tiles at
+            # a tile-aligned row (a plain copy on the chip), and the lanes
+            # the padded last block trained again and dropped.
+            metrics["store_blocks"] = np.int32(plan.blocks)
+            metrics["store_blocks_aligned"] = np.int32(plan.aligned_stores)
+            metrics["surplus_lanes"] = np.int32(plan.surplus)
         return RoundState(server=server, client_opt=client_opt), metrics
 
     # Expose the jitted phases for profiling / inspection.  A round runs
     # train_block xN then exactly one of the finishes — finish_fused_compact
-    # when the malicious prefix is elided block-aligned and the kernel
+    # when the malicious prefix is elided and the kernel
     # applies (the headline benchmark configuration), finish_fused for
     # full-matrix kernel rounds, finish otherwise.  The fused handles
     # exist only for configs the kernel covers.

@@ -116,3 +116,24 @@ def test_cli_end_to_end(tmp_path):
         capture_output=True, text=True, cwd=str(REPO))
     assert r.returncode == 1
     assert "exceeds" in r.stderr
+
+
+def test_aot_train_block_reads_the_store_out_of_an_hlo_line():
+    """tools/aot_train_block.py (compile-only, no device) imports, and
+    its reader finds the matrix store's known bits and aliasing."""
+    import aot_train_block
+
+    line = ('  %dynamic_update_slice.3 = bf16[752,4903424]{1,0:T(8,128)(2,1)} '
+            'dynamic-update-slice(%buf, %upd, %mul.761, %constant.397), '
+            'metadata={op_name="jit(_train_block)/blades/store/x"}, '
+            'backend_config={"indices_config":{"index_known_bits":['
+            '{"zeroes":"15","ones":"0","bitwidth":"32"},'
+            '{"zeroes":"4294967295","ones":"0","bitwidth":"32"}],'
+            '"is_index_aligned":[false,false]},'
+            '"aliasing_operands":{"lists":[{"indices":["0","4"]}]}}')
+    other = line.replace("752,", "16,")
+    assert aot_train_block.store_ops(line + "\n" + other, 752) == [{
+        "name": "dynamic_update_slice.3", "shape": [752, 4903424],
+        "index_known_zero_bits": [15, 4294967295],
+        "is_index_aligned": [False, False],
+        "aliasing_operands": [{"indices": ["0", "4"]}]}]

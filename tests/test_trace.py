@@ -651,10 +651,11 @@ def test_streamed_programs_carry_the_device_scopes(streamed_two_rounds,
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from blades_tpu.adversaries import get_adversary
     from blades_tpu.ops import pallas_round
-    from blades_tpu.parallel.streamed import streamed_step
+    from blades_tpu.parallel.streamed import block_plan, streamed_step
 
     algo = streamed_two_rounds["plain"]
     step, st, n, d = algo._step, algo.state, 8, algo._num_params
@@ -663,9 +664,10 @@ def test_streamed_programs_carry_the_device_scopes(streamed_two_rounds,
     keys = jax.random.split(key, n)
     buf = jnp.zeros((n, d), jnp.bfloat16)
     zeros = jnp.zeros((n,), jnp.float32)
+    plan = block_plan(n, 2, 2, jnp.bfloat16, compact=False)
     assert _scopes(step.train_block.lower(
         buf, st.client_opt, st.server.params, x, y, ln, algo.malicious,
-        keys, keys, jnp.int32(2), jnp.int32(2))) == \
+        keys, keys, np.uint32(0), plan=plan)) == \
         {"blades/sample", "blades/step", "blades/store"}
     assert _scopes(step.finish.lower(
         st.server, buf, algo.malicious, zeros, zeros, key, key)) == \

@@ -3,7 +3,8 @@
 compare it with a float64 reference there.
 
 The gates (``ops/pallas_select.kernel_applicable``,
-``ops/pallas_round.should_use``, ``ops/pallas_rowstats.kernel_applicable``)
+``ops/pallas_round.should_use``, ``ops/pallas_rowstats.kernel_applicable``,
+``ops/pallas_store.store_applicable``)
 promise that a kernel applies; only libtpu's Mosaic compile on a real
 chip can say whether that promise holds (scoped-VMEM budget, vector
 layouts).  ``CASES`` is the table of (kernel, edge shape) pairs the gates
@@ -40,7 +41,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from blades_tpu.ops import pallas_round, pallas_rowstats, pallas_select  # noqa: E402
+from blades_tpu.ops import (  # noqa: E402
+    pallas_round,
+    pallas_rowstats,
+    pallas_select,
+    pallas_store,
+)
 
 STRIPE = pallas_select._BLOCK_D
 D = 4 * STRIPE  # four grid steps: accumulators cross stripe boundaries
@@ -231,6 +237,40 @@ def _rowstats_case(n: int, dtype, gram: bool) -> Case:
     return Case(name, run, inputs, reference, 2e-2)
 
 
+def _store_case(lanes: int, dtype, surplus: int = 0) -> Case:
+    """``store_row_block``: the middle one of three row blocks, an update
+    two column blocks and 74 columns wide (the last block masked) into a
+    matrix padded to the next stripe, whose +inf rows must stay.  With
+    ``surplus`` it is a padded last block: rows from ``surplus`` on, then
+    ``surplus`` rows of +inf."""
+    name = f"store_{jnp.dtype(dtype).name}_b{lanes}_s{surplus}"
+    d = 2 * pallas_store._block_cols(lanes, dtype) + 74
+    width = -(-d // STRIPE) * STRIPE
+
+    def inputs():
+        mat = np.zeros((3 * lanes, width), np.float32)
+        mat[-1] = np.inf
+        upd = _rng(name).normal(size=(lanes, d)).astype(np.float32)
+        return _store(mat, dtype), _store(upd, dtype)
+
+    def run(mat, upd):
+        out = pallas_store.store_row_block(
+            mat, upd, jnp.uint32(1), jnp.uint32(surplus), surplus=surplus)
+        # +inf rows as a flag, the rest finite for the comparison.
+        inf = jnp.isposinf(out.astype(jnp.float32))
+        return {"inf": inf, "rows": jnp.where(inf, 0, out)}
+
+    def reference(mat, upd):
+        want = _f64(mat)
+        want[lanes:2 * lanes] = 0.0
+        want[lanes:2 * lanes - surplus, :d] = _f64(upd)[surplus:]
+        want[2 * lanes - surplus:2 * lanes] = np.inf
+        inf = np.isposinf(want)
+        return {"inf": inf, "rows": np.where(inf, 0.0, want)}
+
+    return Case(name, run, inputs, reference, 0.0)  # a copy: exact
+
+
 def _cases() -> Tuple[Case, ...]:
     bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
     gram_n = pallas_rowstats._GRAM_MAX_N
@@ -265,6 +305,13 @@ def _cases() -> Tuple[Case, ...]:
         _rowstats_case(gram_n, i8, gram=True),
         _rowstats_case(2048, f32, gram=False),
         _rowstats_case(2048, i8, gram=False),
+        # The streamed block's tile store: the cells' 16 bf16 lanes plain
+        # and as r10_median's padded last block, an f32 tile with an odd
+        # surplus, and three tiles a block (client_block 50 -> 48).
+        _store_case(16, bf16),
+        _store_case(16, bf16, surplus=2),
+        _store_case(8, f32, surplus=5),
+        _store_case(48, bf16, surplus=20),
     )
 
 
