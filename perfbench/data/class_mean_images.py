@@ -1,14 +1,28 @@
-"""The one general traffic generator: a federation's data, from the seed.
+"""The data kind ``class_mean_images``: labelled images, from the seed.
 
-A traffic mix is a data file (``perfbench/traffic/<name>.json``): which of
-the program's experiment files and which arm of its grid the cell runs, the
-overrides laid over that arm, and the parameters of the clients' data.  This
-module reads those parameters and makes the data; it knows no cell by name.
+A data kind is the traffic's, not the model's: the ``data`` block of a
+traffic file (``perfbench/traffic/<name>.json``) names it (``kind``) and
+holds its parameters, and ``pb/manifest.py::data_kind`` finds this file by
+that name.  What every data kind provides:
+
+- ``make(spec, num_clients, cfg, seed)``: the federation's data.  ``train``
+  and ``test`` are ``(ids (n, cap), lengths (n,))``, one row a client, into a
+  pool of samples that only the kind reads; ``dataset`` holds, as plain
+  values, what the program's dataset object is told of the data beside the
+  arrays (``pb/sut.py::build`` passes them on by keyword).
+- ``gather(data, part)``: a part laid out as the ``(n, cap, ...)`` arrays a
+  vmapped federation reads: what the program is handed.
+- ``batches(data, ids)``: the samples ``ids`` ``(G, steps, batch)`` as the
+  reference's ``loss_fn`` reads them, ``(x (G, steps, batch, ...), y)``.
 
 Every seed gives arrays of the same shapes (``shard_cap`` rows a client, true
 sizes in ``lengths``), so that a new seed is new data for the same compiled
 programs and the same work: only which samples a client holds, and how
 skewed its labels are, changes.
+
+This kind: one image a label, a fixed random mean per class plus Gaussian
+noise, of the shape and class count the configuration's file states
+(``input_shape``, ``num_classes``); label-skewed (Dirichlet) or iid shards.
 """
 
 from __future__ import annotations
@@ -92,23 +106,20 @@ def pad_shards(shards, cap: int):
     return ids, np.array([len(s) for s in shards], np.int32)
 
 
-def make_data(spec: dict, num_clients: int, input_shape, num_classes: int,
-              seed: int) -> dict:
+def make(spec: dict, num_clients: int, cfg: dict, seed: int) -> dict:
     """The federation's data from the traffic file's ``data`` block.
 
     ``pool_x`` ``(N, H, W, C)`` on the device and ``pool_y`` ``(N,)`` on the
     host are the samples; ``train`` and ``test`` are ``(ids, lengths)`` into
-    them, one row a client.  ``gather(part)`` lays a part out as the
-    ``(n, cap, ...)`` arrays a vmapped federation reads."""
-    if spec["kind"] != "class_mean_images":
-        raise ValueError(f"unknown data kind {spec['kind']!r}")
+    them, one row a client."""
+    input_shape, num_classes = tuple(cfg["input_shape"]), cfg["num_classes"]
     rng = np.random.default_rng([seed, 0xDA7A])
     n_train = num_clients * spec["train_per_client"]
     n_test = num_clients * spec["test_per_client"]
     pool_y = rng.integers(0, num_classes, size=n_train + n_test).astype(
         np.int32)
     pool_x = class_mean_images(
-        jax.random.PRNGKey(seed), jnp.asarray(pool_y), tuple(input_shape),
+        jax.random.PRNGKey(seed), jnp.asarray(pool_y), input_shape,
         num_classes, float(spec["noise"]), spec["image_dtype"])
     part = spec["partition"]
     if part["kind"] == "dirichlet":
@@ -123,10 +134,20 @@ def make_data(spec: dict, num_clients: int, input_shape, num_classes: int,
                                  num_clients)
     return {"pool_x": pool_x, "pool_y": pool_y,
             "train": pad_shards(shards, spec["shard_cap"]),
-            "test": pad_shards(test_shards, spec["test_per_client"])}
+            "test": pad_shards(test_shards, spec["test_per_client"]),
+            "dataset": {"name": spec["stands_in_for"],
+                        "input_shape": input_shape,
+                        "num_classes": num_classes}}
 
 
 def gather(data: dict, part: str):
     """``(x (n, cap, H, W, C) on the device, y (n, cap), lengths)``."""
     ids, lengths = data[part]
     return data["pool_x"][jnp.asarray(ids)], data["pool_y"][ids], lengths
+
+
+def batches(data: dict, ids):
+    """``(x (G, steps, batch, H, W, C) float32 on the device, y (G, steps,
+    batch))`` of the samples ``ids``."""
+    return (data["pool_x"][jnp.asarray(ids)].astype(jnp.float32),
+            jnp.asarray(data["pool_y"][ids]))

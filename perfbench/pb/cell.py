@@ -12,14 +12,10 @@ import sys
 import time
 
 from . import compare, tracered, window
-from .manifest import Manifest, reader
+from .manifest import Manifest, data_kind, family, reader
 
 TRACE_SECONDS = 8.0   # the profiler covers the window's first rounds only
 TRACE_MIN_ROUNDS = 3
-# The rehearsal's federation: full model width, a handful of clients.
-REHEARSAL_OVERRIDES = {"num_clients": 8, "num_malicious_clients": 2,
-                       "client_block": 2, "dataset_config": {"train_bs": 4}}
-REHEARSAL_REFERENCE_BLOCK = 2
 
 
 def say(**facts) -> None:
@@ -56,8 +52,8 @@ class Cell:
     and the window both go through ``round()``."""
 
     def __init__(self, manifest: Manifest, workload: str, seed: int,
-                 overrides=None, spoil=None):
-        from . import reference, sut, traffic
+                 rehearse: bool = False, spoil=None):
+        from . import sut
 
         self.manifest, self.workload, self.seed = manifest, workload, seed
         files = manifest.cell(workload)
@@ -67,26 +63,35 @@ class Cell:
         # the cell's file says so to keep the reference under the window.
         self.compared_rounds = files["limits"].get(
             "reference_rounds", self.cfg["reference"]["rounds"])
-        if overrides:      # the rehearsal's tiny sizes; never a measurement
+        self.reference_block = self.cfg["reference"]["client_block"]
+        if rehearse:
+            # The traffic file's tiny sizes (PERFBENCH_REHEARSE: other
+            # overrides, as JSON); never a measurement.
+            tiny = self.traffic["rehearsal"]
             self.traffic = json.loads(json.dumps(self.traffic))
-            self.traffic["overrides"].update(overrides)
+            self.traffic["overrides"].update(
+                json.loads(os.environ.get("PERFBENCH_REHEARSE", "null"))
+                or tiny["overrides"])
+            self.cfg = dict(self.cfg, **tiny.get("config", {}))
+            self.reference_block = tiny["reference_client_block"]
+        self.family = family(self.cfg["family"], manifest.root)
+        self.kind = data_kind(self.traffic["data"]["kind"], manifest.root)
         t = time.perf_counter()
         found = sut.trial_dict(manifest.checkout, self.traffic)
         config = sut.build_config(found, seed)
         self.fed = sut.federation(config)
-        if not overrides:
+        if not rehearse:
             for k in ("num_clients", "num_malicious_clients"):
                 if self.fed[k] != self.cfg[k]:
                     raise ValueError(
                         f"{k}: the configuration's file says {self.cfg[k]}, "
                         f"the traffic builds {self.fed[k]}")
-        self.data = traffic.make_data(
-            self.traffic["data"], self.fed["num_clients"],
-            self.cfg["input_shape"], self.cfg["num_classes"], seed)
+        self.data = self.kind.make(self.traffic["data"],
+                                   self.fed["num_clients"], self.cfg, seed)
         self.t_data = time.perf_counter() - t
         t = time.perf_counter()
-        self.algo = sut.build(config, self.data, self.cfg)
-        self.params0 = reference.init_params(self.cfg, seed)
+        self.algo = sut.build(config, self.kind, self.data)
+        self.params0 = self.family.init_params(self.cfg, seed)
         sut.place_weights(self.algo, self.params0)
         self.params0 = sut.server_params(self.algo)
         if spoil is not None:   # the fault tests break the timed path here
@@ -94,6 +99,15 @@ class Cell:
         self.t_build = time.perf_counter() - t
         self.describe = sut.describe(self.algo)
         self.rows = []
+
+    def follow(self, **kw) -> dict:
+        """The plain reference over this cell's first rounds (``quant``,
+        ``fault``: the control and the planted faults)."""
+        from . import reference
+
+        return reference.run_rounds(
+            self.family, self.kind, self.cfg, self.fed, self.data, self.seed,
+            self.compared_rounds, self.reference_block, **kw)
 
     def round(self) -> dict:
         import jax
@@ -190,7 +204,7 @@ def run_cell(checkout: str, workload: str, seed: int, seconds: float,
              trace: bool, t_process: float, rehearse: bool = False,
              spoil=None, reference_cache=None) -> tuple:
     """``(exit code, result dict or None)``."""
-    from . import reference, sut
+    from . import sut
 
     t_import = time.perf_counter() - t_process
     manifest = Manifest(checkout)
@@ -209,11 +223,7 @@ def run_cell(checkout: str, workload: str, seed: int, seconds: float,
     cache_dir = None if rehearse else sut.place_compile_cache(checkout)
     log = sut.CompileLog()
     mark0 = log.mark()
-    overrides = None
-    if rehearse:
-        overrides = json.loads(os.environ.get("PERFBENCH_REHEARSE", "null")) \
-            or REHEARSAL_OVERRIDES
-    cell = Cell(manifest, workload, seed, overrides, spoil)
+    cell = Cell(manifest, workload, seed, rehearse, spoil)
     t_dev = time.perf_counter()
     rounds = cell.compared_rounds
     prog = warm_up(cell, cell.traffic["warmup_rounds"], rounds)
@@ -271,10 +281,7 @@ def run_cell(checkout: str, workload: str, seed: int, seconds: float,
     if reference_cache is not None and key in reference_cache:
         ref = reference_cache[key]
     else:
-        ref = reference.run_rounds(
-            cell.cfg, cell.fed, cell.data, seed, rounds,
-            REHEARSAL_REFERENCE_BLOCK if rehearse
-            else cell.cfg["reference"]["client_block"])
+        ref = cell.follow()
         if reference_cache is not None:
             reference_cache[key] = ref
     t_ref = time.perf_counter() - t
@@ -296,7 +303,8 @@ def run_cell(checkout: str, workload: str, seed: int, seconds: float,
         ctx = {
             "traced_rounds": win["traced"],
             "traced_round_s": round_s[:win["traced"]], "round_s": round_s,
-            "config": cell.cfg, "federation": cell.fed, "peaks": peaks,
+            "config": cell.cfg, "family": cell.family,
+            "federation": cell.fed, "peaks": peaks,
             "chips": want_chips, "rows": rows, "memory_peak_bytes": peak,
             "eval_ms": eval_ms, "notes": {},
             "compile": {"setup": log.between(mark0, mark1),
@@ -339,7 +347,7 @@ def per_layer(manifest: Manifest, workload: str, trace_dir: str,
     metrics = {}
     for m in manifest.metrics_of(workload, "per_layer"):
         spec = manifest.metric_file(m["name"])
-        value = reader(spec["reader"])(ctx, spec)
+        value = reader(spec["reader"], manifest.root)(ctx, spec)
         if value is not None and math.isfinite(value):
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     if reduced is None or not reduced["devices"]:

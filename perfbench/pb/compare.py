@@ -28,7 +28,14 @@ Numbers, each with a limit of its own (``cells/<workload>.json``):
   such an error, and the whole vector's norm has no all-but-zero leaf to
   blow up on.
 
-A state the step left unchanged reads 1 on the leaf numbers and the diffs.
+- ``change_energy``: ``change_diff`` squared, the share of the step's energy
+  that is error.  An error spread like noise adds to a floor both sides
+  share in quadrature, not in norm: in the deeper cell the fp8 control reads
+  1.8 times the sound runs' ``change_diff`` and 3.4 times their energy, and
+  the sound runs' own readings lie within 4% of each other from seed to seed.
+
+A state the step left unchanged reads 1 on the leaf numbers, the diffs and
+the energy.
 """
 
 from __future__ import annotations
@@ -37,16 +44,27 @@ import numpy as np
 
 
 def _leaves(tree, path=()):
+    """``(name, leaf)`` in sorted order, the leaf as the tree holds it."""
     if isinstance(tree, dict):
         for k in sorted(tree):
             yield from _leaves(tree[k], path + (k,))
     else:
-        yield "/".join(path), np.asarray(tree, np.float64)
+        yield "/".join(path), tree
+
+
+def _together(*trees):
+    """``(name, leaf of each tree as float64)``, one leaf at a time: no
+    tree-sized float64 copy is ever held."""
+    for named in zip(*map(_leaves, trees), strict=True):
+        names = {k for k, _ in named}
+        if len(names) != 1:
+            raise ValueError(f"the trees differ: {sorted(names)}")
+        yield named[0][0], [np.asarray(v, np.float64) for _, v in named]
 
 
 def leaf_norms(after, before) -> dict:
-    b = dict(_leaves(before))
-    return {k: float(np.linalg.norm(a - b[k])) for k, a in _leaves(after)}
+    return {k: float(np.linalg.norm(a - b))
+            for k, (a, b) in _together(after, before)}
 
 
 def worst_leaf_gap(prog: dict, ref: dict, skip=()) -> tuple:
@@ -65,12 +83,11 @@ def worst_leaf_gap(prog: dict, ref: dict, skip=()) -> tuple:
 
 def diff_share(prog_after, ref_after, before_prog, before_ref) -> float:
     """``|step_prog - step_ref| / |step_ref|`` over all parameters."""
-    p0, r0 = dict(_leaves(before_prog)), dict(_leaves(before_ref))
-    p1 = dict(_leaves(prog_after))
     num = den = 0.0
-    for k, r1 in _leaves(ref_after):
-        step = r1 - r0[k]
-        num += float(np.sum(np.square((p1[k] - p0[k]) - step)))
+    for _, (p1, r1, p0, r0) in _together(prog_after, ref_after, before_prog,
+                                         before_ref):
+        step = r1 - r0
+        num += float(np.sum(np.square((p1 - p0) - step)))
         den += float(np.sum(np.square(step)))
     gap = np.sqrt(num / max(den, 1e-300))
     return float(gap) if np.isfinite(gap) else float("inf")
@@ -97,6 +114,7 @@ def numbers(prog: dict, ref: dict) -> dict:
                                   prog["params0"], ref["params0"])
     out["change_diff"] = diff_share(prog["params"][-1], ref["params"][-1],
                                     prog["params0"], ref["params0"])
+    out["change_energy"] = out["change_diff"] ** 2
     out["_where"] = {"agg1_worst_leaf": where_a,
                      "change_worst_leaf": where_c, "left_out": still}
     return out

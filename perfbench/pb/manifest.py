@@ -3,17 +3,27 @@
 Whatever belongs to one configuration, one traffic mix, one cell or one
 per-layer metric sits in a file of its own, found here by its name:
 
-- ``configs/<config>.json``  (the path is the entry's ``file``)
-- ``traffic/<traffic>.json``
+- ``configs/<config>.json``  (the path is the entry's ``file``); its
+  ``family`` names the model family
+- ``families/<family>.py``    the model: weights, reference loss, required
+  work (what a family provides: ``families/cifar_resnet.py``)
+- ``traffic/<traffic>.json``  the program's experiment arm and overrides,
+  the ``data`` block (its ``kind`` names the data kind), the rehearsal's sizes
+- ``data/<kind>.py``          the clients' data from the seed (what a data
+  kind provides: ``data/class_mean_images.py``)
 - ``cells/<workload>.json``   the cell's limits for ``correct``
 - ``metrics/<metric>.json``   the metric's reader and its parameters
 - ``readers/<reader>.py``     a reader, shared by the metrics that name it
 - ``peaks.json``              the chips' peaks, keyed by ``device_kind``
+
+A PR that brings a new model family, data kind, configuration, traffic mix,
+cell or metric adds files and entries, and edits none
+(``tests/perfbench/test_pb_family_seam.py`` holds the harness to that).
 """
 
 from __future__ import annotations
 
-import importlib
+import importlib.util
 import json
 import os
 import re
@@ -72,8 +82,37 @@ class Manifest:
         return table[device_kind]
 
 
-def reader(name: str):
-    """``readers/<name>.py``'s ``read(ctx, spec)``."""
+_MODULES = {}
+
+
+def _find(root: str, package: str, name: str):
+    """The module ``<root>/<package>/<name>.py``, loaded from that file (not
+    from ``sys.path``: a manifest of another checkout finds its own) and
+    once a process, so that what it jits compiles once."""
     if not NAME.match(name):
-        raise ValueError(f"bad reader name {name!r}")
-    return importlib.import_module(f"readers.{name}").read
+        raise ValueError(f"bad {package} name {name!r}")
+    path = os.path.realpath(os.path.join(root, package, name + ".py"))
+    if path not in _MODULES:
+        if not os.path.isfile(path):
+            raise KeyError(f"no {package}/{name}.py under {root}")
+        spec = importlib.util.spec_from_file_location(
+            f"{package}.{name}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _MODULES[path] = module
+    return _MODULES[path]
+
+
+def reader(name: str, root: str = HERE):
+    """``readers/<name>.py``'s ``read(ctx, spec)``."""
+    return _find(root, "readers", name).read
+
+
+def family(name: str, root: str = HERE):
+    """``families/<name>.py``: the model family a configuration names."""
+    return _find(root, "families", name)
+
+
+def data_kind(name: str, root: str = HERE):
+    """``data/<name>.py``: the data kind a traffic mix names."""
+    return _find(root, "data", name)

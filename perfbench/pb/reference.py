@@ -1,17 +1,16 @@
-"""The plain reference of a robust federated round, and its weights.
+"""The plain reference of a robust federated round, for any model family.
 
 Imports ``jax`` and ``numpy`` only: nothing of the program under test, and
 nothing the program has made.  It is the yardstick ``correct`` is decided
 against (see ``compare.py``), so it follows the published description and
-nothing cleverer:
+nothing cleverer.  The model is the family's (``families/<family>.py``:
+``init_params``, ``num_params``, ``loss_fn``) and the samples are the data
+kind's (``data/<kind>.py``: ``batches``); the round is here, once for all
+of them:
 
-- CIFAR ResNet with BasicBlocks (He et al. 2016; 3x3 stem, no max-pool),
-  batch normalisation by the current batch's statistics (no running
-  averages), global average pool, one dense head.  NHWC, float32,
-  ``precision=HIGHEST`` on every contraction.
-- A client's local round: ``local_steps`` plain SGD steps of the mean
-  softmax cross-entropy on batches it draws with replacement from its own
-  shard; its update is ``params_end - params_start``.
+- A client's local round: ``local_steps`` plain SGD steps of the family's
+  loss on batches it draws with replacement from its own shard; its update
+  is ``params_end - params_start``.
 - Update rows are stored in the configuration's ``update_dtype``.
 - ALIE (Baruch et al. 2019): every malicious row is ``mean + z_max * std``
   of the benign rows (unbiased std), ``z_max`` the inverse normal CDF at
@@ -29,10 +28,9 @@ initialisation, then one split per round; a round key splits five ways
 a client's key splits per local batch, and a batch is
 ``randint(key, (batch,), 0, shard_length)``.
 
-``quant`` selects the arithmetic.  ``None`` is the reference.  ``"fp8"`` is
-the control: the same round with every conv/dense operand rounded to
-float8_e4m3 and every cotangent to float8_e5m2, each with a per-tensor
-scale to its own largest magnitude (the usual fp8 training recipe), and the
+``quant`` selects the arithmetic (``arith.py``).  ``None`` is the reference.
+``"fp8"`` is the control: the same round with every contraction's operands
+and cotangents rounded to fp8 inside the family's ``loss_fn``, and the
 stored rows rounded to float8_e4m3 with a per-row scale.
 """
 
@@ -46,162 +44,20 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-HIGHEST = lax.Precision.HIGHEST
-_DN = ("NHWC", "HWIO", "NHWC")
-
-
-# -- the layer list, from the configuration's file --------------------------
-
-
-def layer_shapes(cfg: dict) -> dict:
-    """``{module: {leaf: shape}}`` of the model the configuration's file
-    describes, under flax-linen's automatic module names (the one
-    convention the reference shares with the program, stated under
-    ``assumed`` in the file)."""
-    if cfg["block"] != "basic":
-        raise ValueError(f"block {cfg['block']!r}: only 'basic' is described")
-    cin = cfg["input_shape"][-1]
-    stem = cfg["stem_width"]
-    tree = {"Conv_0": {"kernel": (3, 3, cin, stem)},
-            "BatchStatsNorm_0": {"scale": (stem,), "bias": (stem,)}}
-    prev, idx = stem, 0
-    for width, blocks, stride in zip(cfg["stage_widths"], cfg["stage_blocks"],
-                                     cfg["stage_strides"]):
-        for j in range(blocks):
-            s = stride if j == 0 else 1
-            blk = {"Conv_0": {"kernel": (3, 3, prev, width)},
-                   "BatchStatsNorm_0": {"scale": (width,), "bias": (width,)},
-                   "Conv_1": {"kernel": (3, 3, width, width)},
-                   "BatchStatsNorm_1": {"scale": (width,), "bias": (width,)}}
-            if s != 1 or prev != width:
-                blk["Conv_2"] = {"kernel": (1, 1, prev, width)}
-                blk["BatchStatsNorm_2"] = {"scale": (width,),
-                                           "bias": (width,)}
-            tree[f"BasicBlock_{idx}"] = blk
-            prev, idx = width, idx + 1
-    tree["Dense_0"] = {"kernel": (prev, cfg["num_classes"]),
-                       "bias": (cfg["num_classes"],)}
-    return tree
-
-
-def block_strides(cfg: dict) -> list:
-    return [stride if j == 0 else 1
-            for blocks, stride in zip(cfg["stage_blocks"],
-                                      cfg["stage_strides"])
-            for j in range(blocks)]
-
-
-def num_params(cfg: dict) -> int:
-    return sum(int(np.prod(s)) for s in jax.tree.leaves(
-        layer_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-
-
-def init_params(cfg: dict, seed: int):
-    """The weights every side starts from, made on the device in one jitted
-    call from the seed: He-normal kernels (std sqrt(2 / fan_in)) for the
-    convs, std sqrt(1 / fan_in) for the head, unit scales, zero biases."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        layer_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-
-    @jax.jit
-    def make(key):
-        out = []
-        for i, (path, shape) in enumerate(flat):
-            leaf = path[-1].key
-            if leaf == "scale":
-                out.append(jnp.ones(shape, jnp.float32))
-            elif leaf == "bias":
-                out.append(jnp.zeros(shape, jnp.float32))
-            else:
-                fan_in = int(np.prod(shape[:-1]))
-                gain = 2.0 if len(shape) == 4 else 1.0
-                out.append(jax.random.normal(jax.random.fold_in(key, i),
-                                             shape, jnp.float32)
-                           * np.float32(np.sqrt(gain / fan_in)))
-        return out
-
-    return jax.tree.unflatten(treedef, make(jax.random.PRNGKey(seed)))
-
-
-# -- arithmetic: exact, or rounded to fp8 for the control --------------------
-
-
-def _round_fp8(x, dtype, axis=None):
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    scale = jnp.where(amax > 0, float(jnp.finfo(dtype).max) / amax, 1.0)
-    return (x * scale).astype(dtype).astype(jnp.float32) / scale
-
-
-@jax.custom_vjp
-def _fp8_operand(x):
-    return _round_fp8(x, jnp.float8_e4m3fn)
-
-
-_fp8_operand.defvjp(lambda x: (_round_fp8(x, jnp.float8_e4m3fn), None),
-                    lambda _, g: (_round_fp8(g, jnp.float8_e5m2),))
-
-
-def _operand(quant):
-    if quant is None:
-        return lambda x: x
-    if quant == "fp8":
-        return _fp8_operand
-    raise ValueError(f"unknown arithmetic {quant!r}")
+from .arith import HIGHEST, round_fp8
 
 
 def store_rows(rows, cfg: dict, quant):
     """Rows as the update matrix keeps them."""
     dtype = jnp.dtype(cfg["update_dtype"])
     if quant == "fp8":
-        rows = _round_fp8(rows, jnp.float8_e4m3fn, axis=1)
+        rows = round_fp8(rows, jnp.float8_e4m3fn, axis=1)
     return rows.astype(dtype)
 
 
-# -- the model ----------------------------------------------------------------
-
-
-def _norm(x, p, eps):
-    mean = jnp.mean(x, axis=(0, 1, 2))
-    var = jnp.mean(jnp.square(x - mean), axis=(0, 1, 2))
-    return (x - mean) * lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def forward(cfg: dict, params, x, quant=None):
-    q = _operand(quant)
-    eps = cfg["norm_eps"]
-
-    def conv(x, w, stride, pad):
-        return lax.conv_general_dilated(
-            q(x), q(w), (stride, stride), [(pad, pad), (pad, pad)],
-            dimension_numbers=_DN, precision=HIGHEST)
-
-    x = conv(x, params["Conv_0"]["kernel"], 1, 1)
-    x = jax.nn.relu(_norm(x, params["BatchStatsNorm_0"], eps))
-    for i, stride in enumerate(block_strides(cfg)):
-        p = params[f"BasicBlock_{i}"]
-        y = conv(x, p["Conv_0"]["kernel"], stride, 1)
-        y = jax.nn.relu(_norm(y, p["BatchStatsNorm_0"], eps))
-        y = conv(y, p["Conv_1"]["kernel"], 1, 1)
-        y = _norm(y, p["BatchStatsNorm_1"], eps)
-        if "Conv_2" in p:
-            x = _norm(conv(x, p["Conv_2"]["kernel"], stride, 0),
-                      p["BatchStatsNorm_2"], eps)
-        x = jax.nn.relu(y + x)
-    x = jnp.mean(x, axis=(1, 2))
-    head = params["Dense_0"]
-    return jnp.dot(q(x), q(head["kernel"]), precision=HIGHEST) + head["bias"]
-
-
-def loss_fn(cfg: dict, params, x, y, quant=None):
-    logits = forward(cfg, params, x, quant)
-    logp = jax.nn.log_softmax(logits)
-    ce = -jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0].mean()
-    return jnp.clip(ce, 0.0, 1e6)
-
-
-def local_round(cfg: dict, fed: dict, params, xs, ys, quant=None):
-    """One client: ``xs`` ``(steps, batch, H, W, C)``.  Returns its update
-    tree and its mean loss."""
+def local_round(loss_fn, cfg: dict, fed: dict, params, xs, ys, quant=None):
+    """One client: ``xs`` ``(steps, batch, ...)`` as the family's ``loss_fn``
+    reads a batch.  Returns its update tree and its mean loss."""
     p = params
     losses = []
     for s in range(xs.shape[0]):
@@ -397,14 +253,14 @@ def _geomed(mat, forged, f, spec, chunk, info=None):
 # -- rounds ------------------------------------------------------------------------
 
 
-def make_block_fn(cfg: dict, fed: dict, quant=None):
+def make_block_fn(loss_fn, cfg: dict, fed: dict, quant=None):
     """Jitted ``(params, xs, ys) -> (rows (G, d) as stored, losses (G,))``
-    for a block of clients, ``xs`` ``(G, steps, batch, H, W, C)``."""
+    for a block of clients, ``xs`` ``(G, steps, batch, ...)``."""
 
     @jax.jit
     def block(params, xs, ys):
-        upd, losses = jax.vmap(
-            lambda x, y: local_round(cfg, fed, params, x, y, quant))(xs, ys)
+        upd, losses = jax.vmap(lambda x, y: local_round(
+            loss_fn, cfg, fed, params, x, y, quant))(xs, ys)
         return store_rows(flatten_rows(upd), cfg, quant), losses
 
     return block
@@ -421,14 +277,16 @@ def _server_step(params, agg, lr):
                         unflatten_vec(agg, params))
 
 
-def run_rounds(cfg: dict, fed: dict, data, seed: int, rounds: int,
-               client_block: int, quant=None, fault=None):
+def run_rounds(family, kind, cfg: dict, fed: dict, data, seed: int,
+               rounds: int, client_block: int, quant=None, fault=None):
     """Follow the federation for ``rounds`` rounds from the seed's weights.
 
-    ``data`` is the traffic generator's: ``pool_x`` ``(N, H, W, C)`` on the
-    device, ``pool_y`` ``(N,)`` and ``train = (ids (n, cap), lengths (n,))``
-    on the host.  Returns ``{"losses": [...], "params": [params after round
-    1, ..., after round R] as host trees, "params0": host tree}``.
+    ``family`` is the configuration's model family and ``kind`` the
+    traffic's data kind, both as ``manifest`` finds them.  ``data`` is
+    ``kind.make``'s: ``train = (ids (n, cap), lengths (n,))`` on the host,
+    sample ids that ``kind.batches`` turns into batches.  Returns
+    ``{"losses": [...], "params": [params after round 1, ..., after round
+    R] as host trees, "params0": host tree}``.
 
     ``fault`` plants one of the harness's known faults in the reference,
     for reading how far each moves the compared numbers: ``"half_batch"``
@@ -441,15 +299,14 @@ def run_rounds(cfg: dict, fed: dict, data, seed: int, rounds: int,
                 and fed["adversary"].get("type") != "ALIE")):
         raise ValueError("the reference follows plain SGD on both sides and "
                          f"the ALIE forge only, not {fed}")
-    pool_x, pool_y = data["pool_x"], data["pool_y"]
     ids_of, lengths = data["train"]
     n, f = fed["num_clients"], fed["num_malicious_clients"]
     nb = n - f
     steps, batch = fed["local_steps"], fed["batch_size"]
-    params = init_params(cfg, seed)
+    params = family.init_params(cfg, seed)
     params0 = jax.device_get(params)
-    d = num_params(cfg)
-    block = make_block_fn(cfg, fed, quant)
+    d = family.num_params(cfg)
+    block = make_block_fn(family.loss_fn, cfg, fed, quant)
     dtype = jnp.dtype(cfg["update_dtype"])
     out = {"losses": [], "params": [], "params0": params0, "info": {}}
     g = client_block
@@ -466,9 +323,7 @@ def run_rounds(cfg: dict, fed: dict, data, seed: int, rounds: int,
             sid = ids_of[ids[:, None, None], idx[ids]]      # (G, steps, B)
             if fault == "half_batch":
                 sid = sid[:, :, : batch // 2]
-            rows, ls = block(params,
-                             pool_x[jnp.asarray(sid)].astype(jnp.float32),
-                             jnp.asarray(pool_y[sid]))
+            rows, ls = block(params, *kind.batches(data, sid))
             keep = min(g, trained - b0)
             mat = _write_rows(mat, rows[:keep], jnp.int32(b0))
             if fault == "half_clients":   # the untrained half repeats it

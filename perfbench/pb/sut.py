@@ -130,21 +130,20 @@ def federation(config) -> dict:
     }
 
 
-def build(config, data: dict, cfg: dict):
-    """The algorithm object on the harness's data."""
+def build(config, kind, data: dict):
+    """The algorithm object on the harness's data: each part as the data
+    kind lays it out, and what the kind says of the data beside the arrays
+    (``data["dataset"]``) passed on by keyword."""
     from blades_tpu.data.datasets import FLDataset
     from blades_tpu.data.partition import Partition
 
-    from . import traffic as T
-
     parts = {}
     for name in ("train", "test"):
-        x, y, lengths = T.gather(data, name)
+        x, y, lengths = kind.gather(data, name)
         parts[name] = Partition(x=x, y=y, lengths=lengths)
     config.data(dataset=FLDataset(
-        name="cifar10", train=parts["train"], test_x=None, test_y=None,
-        test=parts["test"], num_classes=cfg["num_classes"],
-        input_shape=tuple(cfg["input_shape"]), synthetic=True))
+        train=parts["train"], test_x=None, test_y=None, test=parts["test"],
+        synthetic=True, **data["dataset"]))
     return config.build()
 
 

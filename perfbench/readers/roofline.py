@@ -1,7 +1,8 @@
 """A layer's share of its roofline, in %: the least time the chip could take
 for the work the layer is required to do (the larger of operations over peak
-operations a second and bytes over peak bytes a second, both from
-``pb/costs.py``) over the device time of the launches that match."""
+operations a second and bytes over peak bytes a second; the metric's file
+names the work, ``pb/costs.py::work`` finds it in the family or among the
+shared ones) over the device time of the launches that match."""
 from pb import costs, tracered
 
 
@@ -12,11 +13,9 @@ def read(ctx, spec):
     seconds, launches = tracered.time_by_pattern(evs, spec["patterns"])
     if not launches or seconds <= 0:
         return None
-    cfg, fed, peaks = ctx["config"], ctx["federation"], ctx["peaks"]
-    flops = {"train": costs.round_flops, "finish": lambda *_: 0}[
-        spec["work"]](cfg, fed)
-    nbytes = {"train": costs.train_bytes, "finish": costs.finish_bytes}[
-        spec["work"]](cfg, fed)
+    peaks = ctx["peaks"]
+    flops, nbytes = costs.work(ctx["family"], spec["work"])(
+        ctx["config"], ctx["federation"])
     t_flops = flops / peaks["bf16_flops_per_s"]
     t_bytes = nbytes / peaks["hbm_bytes_per_s"]
     ctx["notes"][spec["name"] + ".bound_by"] = (

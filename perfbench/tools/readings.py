@@ -41,7 +41,7 @@ def main() -> int:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from pb import cell as C
-    from pb import compare, reference, sut
+    from pb import compare, sut
     from pb.manifest import Manifest
 
     device = C.device_facts()
@@ -51,13 +51,10 @@ def main() -> int:
     if not args.rehearse:
         sut.place_compile_cache(CHECKOUT)
     manifest = Manifest(CHECKOUT)
-    overrides = C.REHEARSAL_OVERRIDES if args.rehearse else None
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     for seed in args.seeds:
-        cell = C.Cell(manifest, args.workload, seed, overrides)
+        cell = C.Cell(manifest, args.workload, seed, args.rehearse)
         rounds = cell.compared_rounds
-        block = (C.REHEARSAL_REFERENCE_BLOCK if args.rehearse
-                 else cell.cfg["reference"]["client_block"])
         t = time.perf_counter()
         prog = C.warm_up(cell, rounds, rounds)
         t_prog = time.perf_counter() - t
@@ -65,8 +62,7 @@ def main() -> int:
 
         def follow(**kw):
             t = time.perf_counter()
-            out = reference.run_rounds(cell.cfg, cell.fed, cell.data, seed,
-                                       rounds, block, **kw)
+            out = cell.follow(**kw)
             return out, time.perf_counter() - t
 
         ref, t_ref = follow()

@@ -5,7 +5,9 @@ import os
 
 import pytest
 
-from pb.manifest import CHECKOUT, NAME, UNIT, Manifest, reader
+from pb import costs
+from pb.manifest import (CHECKOUT, NAME, UNIT, Manifest, data_kind, family,
+                         reader)
 
 M = Manifest()
 DOC = M.doc
@@ -89,8 +91,18 @@ def test_every_cells_files_are_found_by_name(w):
     assert os.path.isfile(os.path.join(CHECKOUT,
                                        traffic["experiment_file"]))
     assert set(files["limits"]["limits"]) >= {"loss_r1", "agg1_worst_leaf"}
-    assert {"stage_widths", "stage_blocks", "num_params",
-            "reference"} <= set(cfg)
+    assert {"family", "num_params", "reference"} <= set(cfg)
+    assert {"overrides", "reference_client_block"} <= set(
+        traffic["rehearsal"])
+    fam = family(cfg["family"])
+    for need in ("layer_shapes", "num_params", "init_params", "loss_fn",
+                 "train_flops_per_sample",
+                 "train_activation_bytes_per_sample"):
+        assert callable(getattr(fam, need)), need
+    assert isinstance(fam.WORKS, dict)
+    kind = data_kind(traffic["data"]["kind"])
+    for need in ("make", "gather", "batches"):
+        assert callable(getattr(kind, need)), need
     for kind in ("end_to_end", "per_layer"):
         assert M.metrics_of(w["name"], kind)
     for m in M.metrics_of(w["name"], "per_layer"):
@@ -98,6 +110,31 @@ def test_every_cells_files_are_found_by_name(w):
         assert spec["unit"] == m["unit"] and spec["moves"] == m["moves"]
         assert spec["layer"] == m["layer"]
         assert callable(reader(spec["reader"]))
+        if "work" in spec:
+            flops, nbytes = costs.work(fam, spec["work"])(
+                cfg, {"num_clients": cfg["num_clients"], "elided_lanes": 0,
+                      "stored_rows": cfg["num_clients"], "batch_size": 32,
+                      "local_steps": 1})
+            assert flops >= 0 and nbytes > 0
+
+
+@pytest.mark.parametrize("find", [reader, family, data_kind],
+                         ids=lambda f: f.__name__)
+@pytest.mark.parametrize("name", ["../pb/cell", "a/b", "", "x" * 65, "a b"])
+def test_a_name_that_is_no_name_finds_no_file(find, name):
+    assert not NAME.match(name)
+    with pytest.raises(ValueError):
+        find(name)
+
+
+@pytest.mark.parametrize("find", [reader, family, data_kind],
+                         ids=lambda f: f.__name__)
+def test_a_name_with_no_file_is_an_error_and_a_file_loads_once(find):
+    with pytest.raises(KeyError):
+        find("there_is_no_such_file")
+    name = {"reader": "roofline", "family": "cifar_resnet",
+            "data_kind": "class_mean_images"}[find.__name__]
+    assert find(name) is find(name)
 
 
 @pytest.mark.parametrize("c", DOC["configs"], ids=lambda c: c["name"])
@@ -109,9 +146,7 @@ def test_config_files_lie_under_paths_and_cut_no_width(c):
         assert not (k.endswith("_dim") or k.endswith("_rank")
                     or "width" in k or "hidden" in k), k
     cfg = json.load(open(os.path.join(CHECKOUT, c["file"])))
-    from pb import reference
-
-    assert reference.num_params(cfg) == cfg["num_params"]
+    assert family(cfg["family"]).num_params(cfg) == cfg["num_params"]
 
 
 def test_peaks_table_is_keyed_by_device_kind_and_has_no_default():

@@ -57,3 +57,107 @@ def test_geomed_is_weiszfeld_from_the_mean(benign):
 def test_an_unknown_defense_has_no_reference(benign):
     with pytest.raises(ValueError):
         reference.aggregate(benign, _federation("Multikrum"))
+
+
+# -- the comparison holds one leaf at a time, and reads what it read --------
+
+
+def _old_leaves(tree, path=()):
+    if isinstance(tree, dict):
+        for k in sorted(tree):
+            yield from _old_leaves(tree[k], path + (k,))
+    else:
+        yield "/".join(path), np.asarray(tree, np.float64)
+
+
+def _old_leaf_norms(after, before):
+    b = dict(_old_leaves(before))
+    return {k: float(np.linalg.norm(a - b[k]))
+            for k, a in _old_leaves(after)}
+
+
+def _old_diff_share(prog_after, ref_after, before_prog, before_ref):
+    p0, r0 = dict(_old_leaves(before_prog)), dict(_old_leaves(before_ref))
+    p1 = dict(_old_leaves(prog_after))
+    num = den = 0.0
+    for k, r1 in _old_leaves(ref_after):
+        step = r1 - r0[k]
+        num += float(np.sum(np.square((p1[k] - p0[k]) - step)))
+        den += float(np.sum(np.square(step)))
+    gap = np.sqrt(num / max(den, 1e-300))
+    return float(gap) if np.isfinite(gap) else float("inf")
+
+
+def _old_numbers(prog, ref):
+    """``compare.numbers`` as it stood at PR 27: every tree turned into a
+    dict of float64 leaves first."""
+    from pb import compare
+
+    out = {}
+    for k in range(len(ref["losses"])):
+        p, r = float(prog["losses"][k]), float(ref["losses"][k])
+        gap = abs(p - r) / abs(r)
+        out[f"loss_r{k + 1}"] = gap if np.isfinite(gap) else float("inf")
+    a_prog = _old_leaf_norms(prog["params"][0], prog["params0"])
+    a_ref = _old_leaf_norms(ref["params"][0], ref["params0"])
+    out["agg1_worst_leaf"], where_a = compare.worst_leaf_gap(a_prog, a_ref)
+    med = float(np.median(list(a_ref.values())))
+    still = [k for k, v in a_ref.items() if v < 1e-3 * med]
+    c_prog = _old_leaf_norms(prog["params"][-1], prog["params0"])
+    c_ref = _old_leaf_norms(ref["params"][-1], ref["params0"])
+    out["change_worst_leaf"], where_c = compare.worst_leaf_gap(
+        c_prog, c_ref, still)
+    out["agg1_diff"] = _old_diff_share(prog["params"][0], ref["params"][0],
+                                       prog["params0"], ref["params0"])
+    out["change_diff"] = _old_diff_share(prog["params"][-1], ref["params"][-1],
+                                         prog["params0"], ref["params0"])
+    out["_where"] = {"agg1_worst_leaf": where_a,
+                     "change_worst_leaf": where_c, "left_out": still}
+    return out
+
+
+def _side(rng, shapes, start, noise):
+    def tree(scale):
+        return {m: {k: (start[m][k] + scale * rng.normal(size=s)).astype(
+            np.float32) for k, s in leaves.items()}
+            for m, leaves in shapes.items()}
+
+    return {"losses": list(2.3 - 0.1 * rng.random(3) * noise),
+            "params0": start, "params": [tree(0.01 * noise),
+                                         tree(0.03 * noise)]}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_streamed_comparison_reads_the_whole_tree_one_to_the_last_bit(
+        seed):
+    from pb import compare
+
+    rng = np.random.default_rng(seed)
+    shapes = {"Conv_0": {"kernel": (3, 3, 3, 16)},
+              "Block_1": {"scale": (16,), "bias": (16,)},
+              "Dense_0": {"kernel": (16, 10), "bias": (10,)}}
+    start = {m: {k: rng.normal(size=s).astype(np.float32)
+                 for k, s in leaves.items()} for m, leaves in shapes.items()}
+    ref = _side(rng, shapes, start, 1.0)
+    prog = _side(rng, shapes, start, 1.02)
+    # A leaf the reference all but leaves alone is left out of the change.
+    for side in (ref, prog):
+        for after in side["params"]:
+            after["Block_1"]["bias"] = start["Block_1"]["bias"] + np.float32(
+                1e-9)
+    got, want = compare.numbers(prog, ref), _old_numbers(prog, ref)
+    # PR 28 added one number, the square of another; the rest read as before.
+    assert got.pop("change_energy") == got["change_diff"] ** 2
+    assert got == want                      # floats compared bit for bit
+    assert list(got) == list(want)
+    assert got["_where"]["left_out"] == ["Block_1/bias"]
+
+
+def test_trees_that_differ_are_not_compared():
+    from pb import compare
+
+    with pytest.raises(ValueError):
+        compare.leaf_norms({"a": np.ones(3), "b": np.ones(3)},
+                           {"a": np.ones(3)})
+    with pytest.raises(ValueError):
+        compare.leaf_norms({"a": np.ones(3)}, {"b": np.ones(3)})
