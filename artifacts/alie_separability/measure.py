@@ -68,7 +68,7 @@ def _build_round(n, f, model, input_shape, num_classes):
         bx, by = sample_client_batches(k_sample, x, y, ln, fr.batch_size,
                                        fr.num_batches_per_round)
         hooks = fr._hooks()
-        updates, client_opt, _ = fr.task.local_round_batched(
+        updates, client_opt, *_ = fr.task.local_round_batched(
             state.server.params, state.client_opt, bx, by,
             jax.random.split(k_train, n), mal, *hooks)
         forged = fr.adversary.on_updates_ready(

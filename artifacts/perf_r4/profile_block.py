@@ -48,7 +48,7 @@ def build_run(variant: str):
 
     def body(c, _):
         bxp = bx + c * 1e-30
-        upd, _o, loss = task.local_round_batched(params, opt, bxp, by, keys,
+        upd, _o, loss, _ = task.local_round_batched(params, opt, bxp, by, keys,
                                                  mal)
         return loss.sum() + upd.sum() * 1e-30, None
 

@@ -64,7 +64,7 @@ def make_timed(task: Task, params, opt, bx, by, keys, mal):
 
     def body(c, _):
         bxp = bx + c * 1e-30
-        upd, _opt2, loss = task.local_round_batched(
+        upd, _opt2, loss, _ = task.local_round_batched(
             params, opt, bxp, by, keys, mal
         )
         return loss.sum() + upd.sum() * 1e-30, None

@@ -180,7 +180,7 @@ VARIANTS = {
 def make_timed(task, params, opt, bx, by, keys, mal):
     def body(c, _):
         bxp = bx + c * 1e-30
-        upd, _o, loss = task.local_round_batched(params, opt, bxp, by, keys,
+        upd, _o, loss, _ = task.local_round_batched(params, opt, bxp, by, keys,
                                                  mal)
         return loss.sum() + upd.sum() * 1e-30, None
 

@@ -17,6 +17,7 @@ SURVEY.md §5).
 
 from __future__ import annotations
 
+import math
 import pickle
 import warnings
 from dataclasses import replace as _dc_replace
@@ -397,7 +398,9 @@ class Fedavg:
             # footprint (2.4 GB -> 1.2 GB at 1000 CIFAR clients), which
             # the giant bf16 update matrix needs back.
             cd = self.fed_round.task.spec.compute_dtype
-            if cd is not None:
+            if cd is not None and jnp.issubdtype(
+                    self._train_arrays[0].dtype, jnp.floating):
+                # (Token ids are integers and stay as they are.)
                 with self.timers.span("blades/setup/data"):
                     x, y, ln = self._train_arrays
                     self._train_arrays = (x.astype(jnp.dtype(cd)), y, ln)
@@ -1438,13 +1441,19 @@ class Fedavg:
         # rows): stamped by _train_raw on the cohort-varying paths,
         # identity arange on the dense full-participation round.
         cohort_ids, cohort_staleness = row.pop(_COHORT_KEY, (None, None))
-        metrics, lanes = {}, {}
+        metrics, lanes, counters = {}, {}, {}
         for k, v in raw.items():
             a = np.asarray(v)
             if k.startswith("lane_"):
                 if a.ndim > 1:
                     a = a[-1 if idx is None else idx]
                 lanes[k[len("lane_"):]] = a
+            elif k.startswith("counter_"):
+                # A task's own row counters (parallel/streamed.py), under
+                # their schema-registered names: an int stays an int.
+                if a.ndim:
+                    a = a[-1 if idx is None else idx]
+                counters[k[len("counter_"):]] = a.item()
             elif a.ndim:
                 metrics[k] = float(a[-1 if idx is None else idx])
             else:
@@ -1542,6 +1551,7 @@ class Fedavg:
             for name in ("store_blocks", "store_blocks_aligned",
                          "surplus_lanes"):
                 row[name] = int(metrics[name])
+        row.update(counters)
         if self.config.fault_config:  # chaos layer (blades_tpu/faults)
             # Participation is per round; the dispatch summary reports the
             # LAST round (consistent with the scalar metrics above) plus
@@ -1762,6 +1772,10 @@ class Fedavg:
                     "test_acc": float(ev["test_acc"]),
                     "test_acc_top3": float(ev["test_acc_top3"]),
                 }
+            if self.fed_round.task.sequence:
+                # A sequence task's test_loss is the mean token loss.
+                self._last_eval["test_perplexity"] = math.exp(
+                    min(self._last_eval["test_loss"], 700.0))
         return dict(self._last_eval)
 
     # -- compiled-cost analysis (obs subsystem) ------------------------------

@@ -158,7 +158,7 @@ def build_cycle(fed_round, *, staleness_cap: int, weight_schedule: str,
             return task.local_round(
                 unravel(pvec), opt, bx, by, k_train, mal,
                 hooks.data, hooks.grad, hooks.round_begin, hooks.round_end,
-            )
+            )[:3]
 
         with jax.named_scope("blades/step"):
             updates, new_opt, losses = jax.vmap(one_event)(

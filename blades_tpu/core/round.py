@@ -374,7 +374,7 @@ class FedRound:
                 )
         else:
             with jax.named_scope("blades/step"):
-                updates, client_opt, losses = self.task.local_round_batched(
+                updates, client_opt, losses, _ = self.task.local_round_batched(
                     state.server.params, state.client_opt, bx, by,
                     client_keys, malicious, *hooks,
                 )
@@ -741,7 +741,7 @@ class FedRound:
             lambda kb: sample_batch(kb, tx, ty, jnp.array(tx.shape[0]), self.batch_size)
         )(keys)
         opt0 = self.task.init_client_opt_state(global_params)
-        update, _, _ = self.task.local_round(
+        update, *_ = self.task.local_round(
             global_params, opt0, batches[0], batches[1], k_train,
             jnp.array(False),
         )

@@ -13,6 +13,15 @@ A ``Task`` binds a flax module to a loss and exposes pure functions:
 - ``evaluate`` — summed cross-entropy + top-k accuracies over a client's
   test shard (ref: task.py:104-121, 188-202), masked for padding.
 
+A model that declares ``sequence_model = True`` (models/mla_moe.py) makes
+the task a SEQUENCE task: ``x`` is ``(batch, S)`` int32 tokens of packed
+documents and ``y`` ``(batch, S)`` next-token targets, ``-1`` where the
+next token lies in another document (or past the row): the loss is the
+mean next-token cross-entropy over the positions that have a target, one
+term per logits plane the model returns (MTP), and ``evaluate`` counts
+tokens, so that ``exp(test_loss)`` is the perplexity.  Everything else (the
+hooks, the scan, the update vector, ``vmap`` over clients) is shared.
+
 Adversary interposition happens through two per-lane hooks threaded into
 the scan — ``data_hook(x, y, malicious)`` (label-flip style, ref:
 blades/adversaries/labelflip_adversary.py:10-16) and
@@ -96,7 +105,19 @@ class Task:
             return optax.sgd(self.spec.lr, momentum=self.spec.momentum)
         return optax.sgd(self.spec.lr)
 
+    @property
+    def sequence(self) -> bool:
+        """Next-token loss over ``(batch, S)`` int32 tokens (module
+        docstring), declared by the model."""
+        return bool(getattr(self.model, "sequence_model", False))
+
     def init_params(self, key: jax.Array):
+        if self.sequence:
+            # One compiled program: an eager init of a model this deep is
+            # hundreds of one-operation programs.
+            x = jnp.zeros((1,) + self.spec.input_shape, jnp.int32)
+            return jax.jit(lambda k: self.model.init(
+                {"params": k}, x)["params"])(key)
         x = jnp.zeros((1,) + self.spec.input_shape, jnp.float32)
         return self.model.init({"params": key, "dropout": key}, x)["params"]
 
@@ -123,12 +144,78 @@ class Task:
         if self.spec.compute_dtype is None:
             return tree
         dt = jnp.dtype(self.spec.compute_dtype)
-        return jax.tree.map(
-            lambda p: p.astype(dt) if jnp.issubdtype(p.dtype, jnp.floating) else p,
-            tree,
-        )
+        # Leaves the model names stay float32 whatever the compute type
+        # (a router: its top-k flips on near-tied bf16 scores).
+        keep = getattr(self.model, "float32_params", ())
+        return jax.tree_util.tree_map_with_path(
+            lambda path, p: p.astype(dt)
+            if jnp.issubdtype(p.dtype, jnp.floating)
+            and not any(getattr(k, "key", None) in keep for k in path)
+            else p, tree)
+
+    def sequence_planes(self, params, tokens, *, stats: bool = False):
+        """The sequence model's float32 logits planes ``(B, S, V)`` (and,
+        with ``stats``, what its layers sowed, one stacked leaf a name)."""
+        out = self.model.apply({"params": params}, tokens,
+                               mutable=["stats"] if stats else False)
+        if not stats:
+            return out
+        planes, state = out
+        sown = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+                state.get("stats", {})):
+            name = [k.key for k in path if hasattr(k, "key")][-1]
+            sown.setdefault(name, []).append(leaf)
+        return planes, {k: jnp.stack(v) for k, v in sorted(sown.items())}
+
+    @staticmethod
+    def plane_targets(y, depth: int):
+        """Targets of logits plane ``depth`` (it predicts token ``i + 1 +
+        depth`` at position ``i``) from next-token targets ``y`` ``(..., S)``
+        with ``-1`` where a position has none: valid where every step of
+        the way stays inside the document."""
+        t = y
+        for _ in range(depth):
+            ahead = jnp.concatenate(
+                [t[..., 1:], jnp.full_like(t[..., :1], -1)], axis=-1)
+            t = jnp.where(y >= 0, ahead, -1)
+        return t
+
+    def sequence_loss(self, params, x, y):
+        """``(loss, stats)``: the mean next-token cross-entropy over the
+        positions with a target, logits and loss in float32, one weighted
+        term per plane; and what the model's layers sowed."""
+        params = self.cast_to_compute(params)
+        planes, sown = self.sequence_planes(params, x, stats=True)
+        weights = getattr(self.model, "loss_weights", (1.0,) * len(planes))
+        loss = 0.0
+        for depth, (logits, w) in enumerate(zip(planes, weights)):
+            t = self.plane_targets(y, depth)
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), jnp.maximum(t, 0))
+            valid = (t >= 0).astype(jnp.float32)
+            loss = loss + w * (ce * valid).sum() / jnp.maximum(
+                valid.sum(), 1.0)
+        return jnp.clip(loss, 0.0, self.spec.loss_clamp), sown
+
+    def loss_and_stats(self, params, x, y, dropout_key=None):
+        """``(loss, stats)``: ``stats`` is what the model's layers sowed
+        on this batch (a sequence model's expert counts), ``{}`` for every
+        other model."""
+        if self.sequence:
+            return self.sequence_loss(params, x, y)
+        return self.loss_fn(params, x, y, dropout_key), {}
+
+    def round_counters(self, stats) -> dict:
+        """A round's row counters from the trained lanes' stacked stats
+        (:meth:`local_round_batched`), reduced by the model that sowed
+        them (its ``round_counters``); ``{}`` where there are none."""
+        reduce = getattr(self.model, "round_counters", None)
+        return reduce(stats) if reduce is not None and stats else {}
 
     def loss_fn(self, params, x, y, dropout_key=None):
+        if self.sequence:
+            return self.sequence_loss(params, x, y)[0]
         if self.spec.compute_dtype is not None:
             dt = jnp.dtype(self.spec.compute_dtype)
             params = self.cast_to_compute(params)
@@ -150,7 +237,9 @@ class Task:
         data_hook: DataHook = identity_data_hook,
         grad_hook: GradHook = identity_grad_hook,
     ):
-        """One local SGD step with adversary hooks (ref: task.py:170-186).
+        """One local SGD step with adversary hooks (ref: task.py:170-186):
+        ``(params, opt_state, loss, stats)``, ``stats`` as
+        :meth:`loss_and_stats` gives them.
 
         Order matches the reference loader->callback pipeline: augmentation
         first (DataLoader transform), then the adversary's data hook
@@ -163,11 +252,12 @@ class Task:
             k_aug, key = jax.random.split(key)
             x = aug(k_aug, x)
         x, y = data_hook(x, y, malicious)
-        loss, grads = jax.value_and_grad(self.loss_fn)(params, x, y, key)
+        (loss, stats), grads = jax.value_and_grad(
+            self.loss_and_stats, has_aux=True)(params, x, y, key)
         grads = grad_hook(grads, malicious)
         updates, opt_state = self.client_optimizer().update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        return params, opt_state, loss, stats
 
     def local_round(
         self,
@@ -205,8 +295,11 @@ class Task:
                 at storage width instead of f32.
 
         Returns:
-            ``(update_vec, new_opt_state, mean_loss)`` where ``update_vec`` is
-            the flat pseudo-gradient (ref: task.py:162-168, functionally).
+            ``(update_vec, new_opt_state, mean_loss, stats)`` where
+            ``update_vec`` is the flat pseudo-gradient (ref: task.py:162-168,
+            functionally) and ``stats`` what the model's layers sowed
+            (:meth:`loss_and_stats`), summed over the local steps: ``{}``
+            for a model that sows nothing.
         """
         ravel, _, _ = ravel_fn(global_params)
         num_batches = batches_x.shape[0]
@@ -216,12 +309,12 @@ class Task:
         def step(carry, inp):
             params, opt_state = carry
             x, y, k = inp
-            params, opt_state, loss = self.train_one_batch(
+            params, opt_state, loss, stats = self.train_one_batch(
                 params, opt_state, x, y, k, malicious, data_hook, grad_hook
             )
-            return (params, opt_state), loss
+            return (params, opt_state), (loss, stats)
 
-        (params, opt_state), losses = jax.lax.scan(
+        (params, opt_state), (losses, stats) = jax.lax.scan(
             step, (params0, opt_state), (batches_x, batches_y, keys)
         )
         # Pseudo-grad is always vs the INCOMING global params (the
@@ -236,7 +329,8 @@ class Task:
             update = round_end_hook(update, malicious)
             if out_dtype is not None:
                 update = update.astype(out_dtype)
-        return update, opt_state, losses.mean()
+        return (update, opt_state, losses.mean(),
+                jax.tree.map(lambda a: a.sum(0), stats))
 
     def local_round_batched(
         self,
@@ -253,7 +347,8 @@ class Task:
         out_dtype=None,
     ):
         """A whole client block's local rounds: ``(G, nb, B, ...)`` batches
-        -> ``(updates (G, d), new_opt_states, losses (G,))``.
+        -> ``(updates (G, d), new_opt_states, losses (G,), stats)``, each
+        lane's stats stacked (``{}`` for a model that sows nothing).
 
         Semantically ``vmap(local_round)`` over the client axis.  (A
         merged-batch "FedSGD" formulation — one shared forward over
@@ -282,8 +377,13 @@ class Task:
 
         Returns summed-CE loss, top-1/top-3 correct counts, and the sample
         count — so the driver can do the reference's weighted average
-        (ref: blades/algorithms/fedavg/fedavg.py:268-277).
+        (ref: blades/algorithms/fedavg/fedavg.py:268-277).  A sequence
+        task counts tokens: ``count`` is the test rows' positions with a
+        target, so the driver's ``ce_sum / count`` is the mean token loss
+        and its exponential the perplexity.
         """
+        if self.sequence:
+            return self._evaluate_sequence(params, x, y, mask)
         logits = self.apply(params, x, train=False)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
         m = mask.astype(jnp.float32)
@@ -297,3 +397,24 @@ class Task:
             "top3_sum": (topk * m).sum(),
             "count": m.sum(),
         }
+
+    def _evaluate_sequence(self, params, x, y, mask):
+        """One row at a time (``lax.map``): a row's ``(S, vocab)`` float32
+        logits are the largest array alive."""
+
+        def one_row(row):
+            tokens, t, keep = row
+            logits = self.sequence_planes(params, tokens[None])[0][0]
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                logits, jnp.maximum(t, 0))
+            m = (t >= 0).astype(jnp.float32) * keep.astype(jnp.float32)
+            top3 = jax.lax.top_k(logits, min(3, logits.shape[-1]))[1]
+            return jnp.stack([
+                (ce * m).sum(),
+                ((top3[:, 0] == t) * m).sum(),
+                (jnp.any(top3 == t[:, None], axis=-1) * m).sum(),
+                m.sum()])
+
+        sums = jax.lax.map(one_row, (x, y, mask)).sum(0)
+        return {"ce_sum": sums[0], "top1_sum": sums[1], "top3_sum": sums[2],
+                "count": sums[3]}

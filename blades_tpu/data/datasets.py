@@ -349,6 +349,86 @@ def build_cifar100(num_clients=60, iid=True, alpha=0.1, seed=0, **kw) -> FLDatas
     )
 
 
+def pack_documents(doc_tokens, seq_len: int, bos_id: int = 0, rows=None):
+    """Pack documents (1-D int arrays without their start token; any
+    iterable, endless ones included) into rows of ``seq_len``: each
+    document is written as ``bos_id`` then its tokens, rows are filled in
+    order, and a document that does not fit the rest of its row is cut
+    there (a row always starts a document).  Stops after ``rows`` full
+    rows, or when the documents run out.  Returns ``(x, y)`` ``(rows,
+    seq_len)`` int32: tokens and next-token targets, ``-1`` where the next
+    token is not in the same document."""
+    rows_x, rows_y = [], []
+    x = np.full(seq_len, bos_id, np.int32)
+    y = np.full(seq_len, -1, np.int32)
+    at = 0
+    for doc in doc_tokens:
+        if rows is not None and len(rows_x) == rows:
+            break
+        doc = np.concatenate([[bos_id], doc])[: seq_len - at]
+        x[at:at + len(doc)] = doc
+        y[at:at + len(doc) - 1] = doc[1:]
+        at += len(doc)
+        if at == seq_len:
+            rows_x.append(x)
+            rows_y.append(y)
+            x = np.full(seq_len, bos_id, np.int32)
+            y = np.full(seq_len, -1, np.int32)
+            at = 0
+    if at and (rows is None or len(rows_x) < rows):
+        # the last row's tail: one-token documents with no target
+        rows_x.append(x)
+        rows_y.append(y)
+    return np.stack(rows_x), np.stack(rows_y)
+
+
+def build_packed_tokens(num_clients=10, iid=False, alpha=0.1, seed=0,
+                        seq_len=512, vocab_size=4096, train_rows=16,
+                        test_rows=2, topics=8, zipf=1.1, doc_median=512,
+                        doc_sigma=1.0, **kw) -> FLDataset:
+    """Seeded synthetic token corpus for the sequence task: the
+    program's own fall-back, as the class-mean images are the image
+    tasks'.  Documents of lognormal length (median ``doc_median`` tokens,
+    clipped to ``seq_len - 1``) whose ids follow Zipf(``zipf``) over their
+    topic's permutation of ``[1, vocab_size)`` (id 0 starts a document);
+    a client draws each document's topic from its own mixture,
+    Dirichlet(``alpha``) over ``topics`` (uniform when ``iid``); packed
+    into ``train_rows`` + ``test_rows`` rows of ``seq_len`` a client."""
+    del kw
+    rng = np.random.default_rng([int(seed), 0x70C5])
+    ranks = np.arange(1, vocab_size, dtype=np.float64)
+    cdf = np.cumsum(ranks ** -float(zipf))
+    cdf /= cdf[-1]
+    perms = np.stack([1 + rng.permutation(vocab_size - 1)
+                      for _ in range(topics)]).astype(np.int32)
+    rows = train_rows + test_rows
+    xs, ys = [], []
+    for _ in range(num_clients):
+        mix = (np.full(topics, 1.0 / topics) if iid
+               else rng.dirichlet(np.full(topics, float(alpha))))
+
+        def documents():
+            while True:
+                n = int(np.clip(rng.lognormal(np.log(doc_median), doc_sigma),
+                                1, seq_len - 1))
+                topic = rng.choice(topics, p=mix)
+                yield perms[topic][np.searchsorted(cdf, rng.random(n))]
+
+        x, y = pack_documents(documents(), seq_len, rows=rows)
+        xs.append(x)
+        ys.append(y)
+    x, y = np.stack(xs), np.stack(ys)
+
+    def part(lo, hi):
+        return Partition(x=x[:, lo:hi], y=y[:, lo:hi],
+                         lengths=np.full(num_clients, hi - lo, np.int32))
+
+    return FLDataset(
+        name="packed_tokens", train=part(0, train_rows), test_x=None,
+        test_y=None, test=part(train_rows, rows), num_classes=vocab_size,
+        input_shape=(seq_len,), synthetic=True)
+
+
 def _load_mnist_like_factory(subdir: str):
     return lambda: _load_mnist_like(subdir)
 
@@ -362,6 +442,7 @@ _REGISTRY: Dict[str, Callable[..., FLDataset]] = {
     "fashionmnist": build_fashionmnist,
     "cifar10": build_cifar10,
     "cifar100": build_cifar100,
+    "packed_tokens": build_packed_tokens,
 }
 
 

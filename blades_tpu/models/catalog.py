@@ -1,8 +1,10 @@
 """Model catalog (ref: fllib/models/catalog.py:16-47).
 
 Resolves a model spec — substring-matched name ("cct"/"resnet"/"mlp"/"cnn",
-same matching rule as the reference), a flax Module instance, or a custom
-registered name — to a linen module.
+same matching rule as the reference), a flax Module instance, a custom
+registered name, or a dict ``{"type": name, **builder keywords}`` (how a
+YAML states a model that has a configuration of its own, e.g.
+``mla_moe_lm``) — to a linen module.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ import flax.linen as nn
 from blades_tpu.models.cct import VARIANTS as _CCT_VARIANTS
 from blades_tpu.models.cct import cct_2_3x2_32
 from blades_tpu.models.cnn import FashionCNN
+from blades_tpu.models.mla_moe import mla_moe_lm
 from blades_tpu.models.mlp import MLP
 from blades_tpu.models.resnet import (
     ResNet10,
@@ -25,6 +28,9 @@ from blades_tpu.models.resnet import (
 )
 
 _CUSTOM: Dict[str, Callable[..., nn.Module]] = {}
+
+# Models built from keywords (a dict spec's keys beside "type").
+_CONFIGURED = {"mla_moe_lm": mla_moe_lm}
 
 _RESNETS = {
     "resnet10": ResNet10,
@@ -55,7 +61,12 @@ class ModelCatalog:
         if callable(spec) and not isinstance(spec, str):
             return spec()
         kw = {} if num_classes is None else {"num_classes": num_classes}
+        if isinstance(spec, dict):
+            kw.update({k: v for k, v in spec.items() if k != "type"})
+            spec = spec["type"]
         name = str(spec).lower()
+        if name in _CONFIGURED:
+            return _CONFIGURED[name](**kw)
         if name in _CUSTOM:
             return _CUSTOM[name](**kw)
         if name in _RESNETS:

@@ -224,3 +224,111 @@ class BatchStatsNorm(nn.Module):
         if bias is not None:
             y = y + bias
         return y
+
+
+# --------------------------------------------------------------------------
+# Sequence-model primitives: RMS norm, interleaved rotary, SwiGLU, and
+# causal attention over packed documents in query blocks
+# --------------------------------------------------------------------------
+
+
+class RMSNorm(nn.Module):
+    """``x / rms(x) * scale``, statistics in float32, no bias."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        xf = x.astype(jnp.float32)
+        xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
+                            + self.epsilon)
+        return (xf * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def kernel_param(module, name, shape, std=0.02):
+    return module.param(name, nn.initializers.normal(std), shape)
+
+
+class Linear(nn.Module):
+    """Bias-free dense layer in the input's dtype."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        w = kernel_param(self, "kernel", (x.shape[-1], self.features))
+        return jnp.dot(x, w.astype(x.dtype))
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate x) * up x)``, bias-free."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        h = jax.nn.silu(Linear(self.width, name="gate")(x)) \
+            * Linear(self.width, name="up")(x)
+        return Linear(x.shape[-1], name="down")(h)
+
+
+def packed_positions(tokens: jnp.ndarray, bos_id: int):
+    """``(segment, position)`` of every token of packed rows ``(..., S)``:
+    a document starts at each ``bos_id`` token (a row's first tokens, up
+    to its first ``bos_id``, continue a document cut by the row's edge);
+    positions restart at 0 with each document."""
+    start = tokens == bos_id
+    idx = jnp.arange(tokens.shape[-1], dtype=jnp.int32)
+    segment = jnp.cumsum(start.astype(jnp.int32), axis=-1)
+    last_start = lax.cummax(jnp.where(start, idx, 0), axis=tokens.ndim - 1)
+    return segment, idx - last_start
+
+
+def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                       theta: float) -> jnp.ndarray:
+    """Rotary embedding over interleaved pairs ``(x[2i], x[2i+1])`` of the
+    last axis, angle ``position * theta ** (-2i / dim)``, in float32.
+    ``x`` is ``(B, S, ..., dim)`` and ``positions`` ``(B, S)``."""
+    dim = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (dim // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def packed_causal_attention(q, k, v, segment, scale: float,
+                            block: int = 512) -> jnp.ndarray:
+    """Softmax attention, causal within a document, in query blocks.
+
+    ``q``/``k`` are ``(B, S, H, dk)``, ``v`` ``(B, S, H, dv)``, ``segment``
+    ``(B, S)``.  Query block ``i`` reads keys ``[0, (i + 1) * block)`` only
+    and is rematerialised in the backward pass, so no ``(H, S, S)`` score
+    array outlives its block; scores and the softmax are float32."""
+    s = q.shape[1]
+    block = min(block, s)
+    if s % block:
+        raise ValueError(f"sequence length {s} is no multiple of the "
+                         f"attention block {block}")
+
+    @jax.checkpoint
+    def one_block(qi, kj, vj, seg_q, seg_k, q0):
+        sc = jnp.einsum("bqhd,bkhd->bhqk", qi, kj,
+                        preferred_element_type=jnp.float32) * scale
+        qpos = q0 + jnp.arange(qi.shape[1])
+        ok = (seg_q[:, :, None] == seg_k[:, None, :]) \
+            & (jnp.arange(kj.shape[1])[None, :] <= qpos[:, None])
+        sc = jnp.where(ok[:, None], sc, -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1).astype(vj.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vj)
+
+    out = []
+    for q0 in range(0, s, block):
+        end = q0 + block
+        out.append(one_block(q[:, q0:end], k[:, :end], v[:, :end],
+                             segment[:, q0:end], segment[:, :end], q0))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)

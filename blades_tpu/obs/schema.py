@@ -49,6 +49,9 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     "test_loss": (_NUM, False),
     "test_acc": (_NUM, False),
     "test_acc_top3": (_NUM, False),
+    # Sequence task (core/task.py): exp(test_loss), test_loss being the
+    # mean token loss there.
+    "test_perplexity": (_NUM, False),
     # health (core/health.py)
     "num_unhealthy": ((int,), False),
     "round_ok": ((bool,), False),
@@ -169,6 +172,23 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     "store_blocks": ((int,), False),
     "store_blocks_aligned": ((int,), False),
     "surplus_lanes": ((int,), False),
+    # A task's own row counters on the streamed path (the round stamps
+    # `counter_<name>`, the row takes them by prefix: hence the per-line
+    # pragmas).  A sequence task, and its expert-share layer
+    # (models/mla_moe.py::MlaMoeLM.round_counters, from counts the router
+    # sows; they ride the round's one metric fetch): tokens the trained
+    # lanes saw this round; the most and the mean tokens a held expert
+    # received, over
+    # expert layers and trained lanes; the share of selected (token,
+    # expert) pairs whose expert is held here (the rest is what absent
+    # chips would compute); and the (lane, layer, held expert) blocks
+    # that received no token, whose update rows are exact zeros under the
+    # coordinate-wise statistics (ROADMAP R6: counted, not answered).
+    "tokens_trained": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "expert_tokens_max": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "expert_tokens_mean": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "routed_here_share": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "zero_expert_blocks": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     # Row-geometry pass fusion (parallel/streamed_geometry.py): planned
     # full-matrix HBM traversals the streamed row-geometry finish runs
     # this round under the fused pass plan, vs what the

@@ -344,7 +344,7 @@ def _build_dsharded_body(fr: FedRound, mesh: Mesh,
             return fr.task.local_round_batched(
                 state.server.params,
                 jax.tree.map(lambda a: a[slc], state.client_opt),
-                bx, by, client_keys[slc], malicious[slc], *hooks)
+                bx, by, client_keys[slc], malicious[slc], *hooks)[:3]
 
         if f_local:
             # Elision: train only the benign tail; the malicious-prefix

@@ -173,10 +173,11 @@ def hier_step(
             )
         hooks = fr._hooks()
         with jax.named_scope("blades/step"):
-            upd_local, client_opt, losses_local = fr.task.local_round_batched(
-                state.server.params, state.client_opt, bx, by, local_train,
-                mal_local, *hooks,
-            )
+            upd_local, client_opt, losses_local, _ = \
+                fr.task.local_round_batched(
+                    state.server.params, state.client_opt, bx, by,
+                    local_train, mal_local, *hooks,
+                )
         d_full = upd_local.shape[1]
 
         # Per-shard robust pre-aggregation: (n_local, d) -> (m, d).

@@ -139,7 +139,7 @@ def shard_map_step(fr: FedRound, mesh: Mesh) -> Callable:
         hooks = fr._hooks()
         client_keys = jax.random.split(k_train, n_local)
 
-        upd_local, client_opt, losses_local = fr.task.local_round_batched(
+        upd_local, client_opt, losses_local, _ = fr.task.local_round_batched(
             state.server.params, state.client_opt, bx, by, client_keys,
             malicious, *hooks,
         )

@@ -406,7 +406,7 @@ def streamed_step(
         # half the assembly traffic); DP needs the f32 row norms BEFORE
         # storage rounding, so there the cast stays at the buffer write.
         with jax.named_scope("blades/step"):
-            upd, opt2, loss = fr.task.local_round_batched(
+            upd, opt2, loss, stats = fr.task.local_round_batched(
                 params, opt_b, bx, by, sl(train_keys), sl(malicious), *hooks,
                 out_dtype=None if dp else update_dtype,
             )
@@ -440,7 +440,9 @@ def streamed_step(
                     full, keep(new, old), lane0, 0),
                 client_opt, opt2, opt_b,
             )
-        return updates_buf, client_opt, loss, norms
+        # ``stats``: what the model's layers sowed, a lane a row; ``{}``
+        # (no output at all) for a model that sows nothing.
+        return updates_buf, client_opt, loss, norms, stats
 
     @jax.jit
     def _finish(server_state, updates_buf, malicious, losses, row_norms,
@@ -527,6 +529,15 @@ def streamed_step(
             metrics["num_unhealthy"] = bad_rows.sum()
             metrics["round_ok"] = ok
         return server, metrics
+
+    @partial(jax.jit, static_argnames=("surplus",))
+    def _round_counters(blocks, surplus):
+        """The task's row counters (``Task.round_counters``) from the
+        blocks' stats, the padded last block's ``surplus`` lanes dropped:
+        one tiny program, fetched with the round's metrics."""
+        return fr.task.round_counters(jax.tree.map(
+            lambda *parts: jnp.concatenate(
+                parts[:-1] + (parts[-1][surplus:],)), *blocks))
 
     spec = _fused_spec(fr)
 
@@ -871,18 +882,20 @@ def streamed_step(
             # Elided lanes: no program runs, their losses and norms read 0.
             elided = ([jnp.zeros((plan.first_lane,), jnp.float32)]
                       if plan.first_lane else [])
-            losses, norms = list(elided), list(elided)
+            losses, norms, stats = list(elided), list(elided), []
         for b in range(plan.blocks):
             with span("blades/block"):
                 # The index goes in as a NumPy scalar: an argument of the
                 # block's own launch, not an eager convert program.
-                updates_buf, client_opt, loss, blk_norms = _train_block(
-                    updates_buf, client_opt, state.server.params, data_x,
-                    data_y, lengths, malicious, sample_keys, train_keys,
-                    np.uint32(b), plan=plan,
-                )
+                updates_buf, client_opt, loss, blk_norms, blk_stats = \
+                    _train_block(
+                        updates_buf, client_opt, state.server.params, data_x,
+                        data_y, lengths, malicious, sample_keys, train_keys,
+                        np.uint32(b), plan=plan,
+                    )
             losses.append(loss)
             norms.append(blk_norms)
+            stats.append(blk_stats)
 
         def per_lane(parts):
             """The blocks' per-lane vectors as one ``(n,)`` vector: the
@@ -970,6 +983,18 @@ def streamed_step(
                 # caveats for the shared contract).  Only added when elision
                 # engages, so non-elided rounds' metrics are unchanged.
                 metrics["elided_lanes"] = np.int32(plan.first_lane)
+            # Row counters (``counter_<name>``: the row takes them under
+            # their schema-registered names).  A sequence task's tokens
+            # the trained lanes saw, a host int; and whatever the model
+            # counts of its own layers, device scalars.
+            if fr.task.sequence:
+                metrics["counter_tokens_trained"] = np.int32(
+                    (n - plan.first_lane) * fr.num_batches_per_round
+                    * fr.batch_size * data_x.shape[-1])
+            if jax.tree.leaves(stats):
+                metrics.update(
+                    ("counter_" + name, v) for name, v in _round_counters(
+                        stats, surplus=plan.surplus).items())
             # Store telemetry (schema-registered, host-side like
             # elided_lanes): the blocks whose rows were written into the
             # matrix, how many of those writes were whole storage tiles at

@@ -247,7 +247,7 @@ def gossip_step(
         # Per-node local training: unlike every server path, params are
         # MAPPED — each node trains from its own replica.
         def one_node(p, o, cbx, cby, ck, m):
-            return fr.task.local_round(p, o, cbx, cby, ck, m, *hooks)
+            return fr.task.local_round(p, o, cbx, cby, ck, m, *hooks)[:3]
 
         with jax.named_scope("blades/step"):
             upd_local, client_opt, losses_local = jax.vmap(one_node)(

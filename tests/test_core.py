@@ -53,7 +53,7 @@ def test_local_round_update_is_param_delta(tiny):
     task = fr.task
     ravel, _, d = ravel_fn(state.server.params)
     bx, by = sample_client_batches(jax.random.PRNGKey(3), x, y, ln, 16, 2)
-    upd, opt, loss = task.local_round(
+    upd, opt, loss, _ = task.local_round(
         state.server.params, jax.tree.map(lambda a: a[0], state.client_opt),
         bx[0], by[0], jax.random.PRNGKey(4), jnp.array(False),
     )

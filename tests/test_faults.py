@@ -423,7 +423,7 @@ def faulty_round():
 
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
     bx, by = sample_client_batches(k1, x, y, ln, 4, 1)
-    updates, _, _ = fr.task.local_round_batched(
+    updates, *_ = fr.task.local_round_batched(
         state.server.params, state.client_opt, bx, by,
         jax.random.split(k2, n), jnp.zeros((n,), bool),
         identity_data_hook, identity_grad_hook,

@@ -1,0 +1,117 @@
+#!/usr/bin/env python3
+"""Compile-only check of the language-model round's two big programs: the
+TPU compiler builds ``_train_block`` and ``_finish_fused_compact``
+(parallel/streamed.py) for device 0 of a described (not attached)
+``v5e:2x2`` at ``tuned_examples/fedavg_lm_crosssilo.yaml``'s shapes (10
+clients, 2 elided, ``client_block`` 1, rows of 4096 tokens) and prints each
+one's ``memory_analysis()``: arguments + outputs - aliased + temporaries is
+what the program needs of the chip's 15.75 GB.
+
+    JAX_PLATFORMS=cpu python3 tools/aot_lm_round.py [key=json ...]
+
+``key=json`` pairs override the YAML's ``global_model`` (e.g.
+``num_nextn_predict_layers=1``: the MTP module at the chip's size, which
+PR 29 decided by these numbers).  About two minutes a block.
+Nothing runs on a device: a compile that passes is not a chip run
+(tools/aot_train_block.py is the image cells' twin).
+"""
+
+import json
+import os
+import sys
+import time
+from unittest import mock
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KEYS = ("argument_size_in_bytes", "output_size_in_bytes",
+        "temp_size_in_bytes", "alias_size_in_bytes",
+        "generated_code_size_in_bytes")
+
+
+def main() -> int:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    sys.path.insert(0, CHECKOUT)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from blades_tpu.algorithms import get_algorithm_class
+    from blades_tpu.ops.pallas_select import _BLOCK_D
+    from blades_tpu.parallel.streamed import block_plan, streamed_step
+    from blades_tpu.tune import expand_grid, load_experiments_from_file
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    (spec,) = load_experiments_from_file(os.path.join(
+        CHECKOUT, "blades_tpu", "tuned_examples",
+        "fedavg_lm_crosssilo.yaml")).values()
+    (trial,) = [t for t in expand_grid(spec["config"])
+                if t["server_config"]["aggregator"]["type"] == "Median"]
+    _, config = get_algorithm_class(spec["run"], return_config=True)
+    config.update_from_dict(trial)
+    model = dict(config.global_model)
+    for pair in sys.argv[1:]:
+        key, value = pair.split("=", 1)
+        model[key] = json.loads(value)
+    config.update_from_dict({"global_model": model})
+    config.validate()
+    n, f = config.num_clients, config.num_malicious_clients
+    block = config.client_block
+    fr = config.get_fed_round()
+    dtype = getattr(jnp, str(config.update_dtype))
+    step = streamed_step(fr, client_block=block, d_chunk=config.d_chunk,
+                         update_dtype=dtype, malicious_prefix=f)
+    # The cell's compact geometry, which the round itself takes only on a
+    # TPU backend (the kernel gate sees the CPU here).
+    plan = block_plan(n, f, block, dtype, compact=True)
+    state = jax.eval_shape(lambda k: fr.init(k, n), jax.random.PRNGKey(0))
+    d = sum(p.size for p in jax.tree.leaves(state.server.params))
+    rows = -(-(plan.blocks * plan.block) // 8) * 8
+    d_alloc = -(-d // _BLOCK_D) * _BLOCK_D
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=chip)
+
+    def report(name, lowered):
+        t = time.time()
+        out = {"program": name}
+        try:
+            m = lowered.compile().memory_analysis()
+            out.update({k: int(getattr(m, k)) for k in KEYS
+                        if hasattr(m, k)})
+            out["needs_bytes"] = (
+                out["argument_size_in_bytes"] + out["output_size_in_bytes"]
+                - out["alias_size_in_bytes"] + out["temp_size_in_bytes"])
+        except Exception as e:   # the compiler's own words
+            out["refused"] = str(e)[:2000]
+        out["compile_s"] = time.time() - t
+        print(json.dumps(out), flush=True)
+
+    seq, cap = tuple(config.input_shape)[0], 16
+    print(json.dumps({"model": model, "num_params": d, "plan": plan._asdict(),
+                      "matrix": [rows, d_alloc], "topology": "v5e:2x2"}),
+          flush=True)
+    with mock.patch.object(jax, "default_backend", return_value="tpu"):
+        report("_train_block", step.train_block.lower(
+            shape((rows, d_alloc), dtype), on_chip(state.client_opt),
+            on_chip(state.server.params), shape((n, cap, seq), jnp.int32),
+            shape((n, cap, seq), jnp.int32), shape((n,), jnp.int32),
+            shape((n,), jnp.bool_), shape((n, 2), jnp.uint32),
+            shape((n, 2), jnp.uint32), shape((), jnp.uint32), plan=plan))
+        report("_finish_fused_compact", step.finish_fused_compact.lower(
+            on_chip(state.server), shape((rows, d_alloc), dtype),
+            shape((n,), jnp.bool_), shape((n,), jnp.float32),
+            shape((2,), jnp.uint32), nb_real=n - f))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
