@@ -1551,6 +1551,9 @@ class Fedavg:
             for name in ("store_blocks", "store_blocks_aligned",
                          "surplus_lanes"):
                 row[name] = int(metrics[name])
+        if "finish_stripe_cols" in metrics:
+            # A fused finish served the round: its stripe's width.
+            row["finish_stripe_cols"] = int(metrics["finish_stripe_cols"])
         row.update(counters)
         if self.config.fault_config:  # chaos layer (blades_tpu/faults)
             # Participation is per round; the dispatch summary reports the

@@ -172,6 +172,12 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     "store_blocks": ((int,), False),
     "store_blocks_aligned": ((int,), False),
     "surplus_lanes": ((int,), False),
+    # Streamed path, when a fused Pallas finish serves the round
+    # (ops/pallas_round.py): the columns one grid step of it takes, which
+    # follow from the stored matrix's height
+    # (ops/pallas_select.py::stripe_cols: 512 from 64 rows up, wider
+    # below) and to which the matrix's columns were padded at allocation.
+    "finish_stripe_cols": ((int,), False),
     # A task's own row counters on the streamed path (the round stamps
     # `counter_<name>`, the row takes them by prefix: hence the per-line
     # pragmas).  A sequence task, and its expert-share layer

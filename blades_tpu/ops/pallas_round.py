@@ -11,7 +11,9 @@ This kernel fuses the whole finish into ONE HBM pass: each grid step
 loads a full-height ``(n, block_d)`` column stripe into VMEM and, fully
 in-core, (a) casts to f32, (b) zeroes rows with non-finite values
 (stripe-local, the health-detection semantics of
-:func:`blades_tpu.core.health.sanitize_updates` at stripe granularity),
+:func:`blades_tpu.core.health.sanitize_updates` at stripe granularity;
+the stripe is :func:`~blades_tpu.ops.pallas_select.stripe_cols` of the
+matrix's height wide: 512 columns from 64 rows up, 3072 at 8 rows),
 (c) computes the benign column statistics and overwrites malicious rows
 with the forged row (ALIE ``mean + z*std``, IPM ``-scale*mean``, or the
 Fang/Adaptive directed deviation with pre-drawn uniforms — the
@@ -47,12 +49,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from blades_tpu.ops.pallas_select import (
-    _BLOCK_D,
     _keys_of,
     _kth_key,
     _next_key_above,
     _vals_of,
     kernel_applicable,
+    stripe_cols,
     stripe_compiler_params,
 )
 
@@ -426,6 +428,33 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
         raise ValueError(f"unknown aggregator {akind!r}")
 
 
+def _pad_to_stripes(updates, rbuf, cols: int):
+    """Zero-pad the matrix's and ``rbuf``'s columns to a whole number of
+    ``cols``-wide stripes (padding columns aggregate to values the
+    callers slice off).  Padding the matrix COPIES it: callers at giant
+    scale allocate it ``stripe_cols(rows)``-aligned
+    (parallel/streamed.py::step) and only the one-row ``rbuf`` is padded
+    here.  Returns ``(updates, rbuf, dpad)``."""
+    d = updates.shape[1]
+    dpad = -(-d // cols) * cols
+    if dpad != d:
+        updates = jnp.pad(updates, ((0, 0), (0, dpad - d)))
+    if rbuf.shape[1] != dpad:
+        rbuf = jnp.pad(rbuf, ((0, 0), (0, dpad - rbuf.shape[1])))
+    return updates, rbuf, dpad
+
+
+def _block_specs(npad: int, cols: int):
+    """The three block shapes of a fused finish: the ``(npad, cols)``
+    stripe and the ``(1, cols)`` row, both walking the columns with the
+    grid, and the resident ``(npad, 1)`` per-row column."""
+    return (
+        pl.BlockSpec((npad, cols), lambda i: (0, i), memory_space=pltpu.VMEM),
+        pl.BlockSpec((npad, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, cols), lambda i: (0, i), memory_space=pltpu.VMEM),
+    )
+
+
 def parse_mxu_mode(mode: str) -> Tuple[bool, bool]:
     """``(radix_mxu, stats_mxu)`` from a finish-mode string: ``""``
     (VPU reductions), ``"counts"`` (radix counts on the MXU — bit-exact,
@@ -500,7 +529,7 @@ def fused_finish_compact(
 @functools.partial(
     jax.jit,
     static_argnames=("forged_mult", "forge", "agg", "sanitize", "num_real",
-                     "interpret", "radix_mxu", "stats_mxu"),
+                     "interpret", "radix_mxu", "stats_mxu", "cols"),
 )
 def _fused_finish_compact_jit(
     updates: jax.Array,
@@ -514,6 +543,7 @@ def _fused_finish_compact_jit(
     interpret: bool = False,
     radix_mxu: bool = False,
     stats_mxu: bool = False,
+    cols: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """The jitted body of :func:`fused_finish_compact`.
 
@@ -542,6 +572,12 @@ def _fused_finish_compact_jit(
     NOT ulp-level.  Here both are concrete
     static booleans; the public wrapper resolves the
     ``BLADES_TPU_MXU_FINISH`` env default per call.
+
+    ``cols``: the stripe's width, for the sake of the tests and of
+    ``tools/chip_kernels.py --sweep`` only (they force 512, or sweep it,
+    to compare widths); the public wrapper passes none and the width is
+    :func:`~blades_tpu.ops.pallas_select.stripe_cols` of the matrix's
+    height.
     """
     nb, d = updates.shape
     if num_real is not None:
@@ -582,54 +618,32 @@ def _fused_finish_compact_jit(
             updates = jnp.concatenate([updates, pad], axis=0)
             wb = jnp.concatenate(
                 [wb, jnp.zeros((npad - nb, 1), jnp.float32)], axis=0)
-    dpad = -(-d // _BLOCK_D) * _BLOCK_D
-    if dpad != d:
-        updates = jnp.pad(updates, ((0, 0), (0, dpad - d)))
-    if rbuf.shape[1] != dpad:
-        rbuf = jnp.pad(rbuf, ((0, 0), (0, dpad - rbuf.shape[1])))
+    cols = cols or stripe_cols(npad)
+    updates, rbuf, dpad = _pad_to_stripes(updates, rbuf, cols)
 
     kernel = functools.partial(
         _compact_kernel, nb_true=nb, mult=forged_mult, forge=forge, agg=agg,
         sanitize=sanitize, keys16=updates.dtype == jnp.bfloat16,
         radix_mxu=radix_mxu, stats_mxu=stats_mxu,
     )
+    stripe, rows1, row = _block_specs(npad, cols)
     agg_vec, sq, bad, forged = pl.pallas_call(
         kernel,
-        grid=(dpad // _BLOCK_D,),
-        in_specs=[
-            pl.BlockSpec((npad, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
+        grid=(dpad // cols,),
+        in_specs=[stripe, rows1, row],
+        out_specs=[row, rows1, rows1, row],
         out_shape=[
             jax.ShapeDtypeStruct((1, dpad), jnp.float32),
             jax.ShapeDtypeStruct((npad, 1), jnp.float32),
             jax.ShapeDtypeStruct((npad, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, dpad), jnp.float32),
         ],
-        compiler_params=stripe_compiler_params(npad),
+        compiler_params=stripe_compiler_params(npad, cols=cols),
         interpret=interpret,
     )(updates, wb, rbuf)
     return agg_vec[0, :d], sq[:nb, 0], bad[:nb, 0] > 0, forged[0, :d]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("forge", "agg", "sanitize", "interpret"),
-)
 def fused_finish(
     updates: jax.Array,
     malicious: jax.Array,
@@ -654,7 +668,9 @@ def fused_finish(
             ``("ipm", scale)`` or ``("adaptive", b)``.
         agg: ``("mean",)``, ``("median",)`` or ``("trimmed", k_cut)``
             with ``k_cut`` rows dropped per side.
-        sanitize: zero non-finite rows (stripe-local) and report them.
+        sanitize: zero non-finite rows (stripe-local: within the
+            ``stripe_cols(n)`` columns of the value's stripe) and report
+            them.
 
     Returns:
         ``(agg_vec, sq_norms, bad)`` — the ``(d,)`` f32 aggregate, the
@@ -662,6 +678,27 @@ def fused_finish(
         ``(n,)`` bool row-unhealthy flags (all-False when ``sanitize``
         is off).
     """
+    return _fused_finish_jit(updates, malicious, forge_noise, forge=forge,
+                             agg=agg, sanitize=sanitize, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("forge", "agg", "sanitize", "interpret", "cols"),
+)
+def _fused_finish_jit(
+    updates: jax.Array,
+    malicious: jax.Array,
+    forge_noise: Optional[jax.Array] = None,
+    *,
+    forge: Optional[tuple] = None,
+    agg: tuple = ("median",),
+    sanitize: bool = False,
+    interpret: bool = False,
+    cols: Optional[int] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The jitted body of :func:`fused_finish`; ``cols`` as in
+    :func:`_fused_finish_compact_jit` (the tests' and the sweep's only)."""
     n, d = updates.shape
     if agg[0] == "trimmed" and n <= 2 * agg[1]:
         raise ValueError(f"trimmed mean needs > {2 * agg[1]} rows, got {n}")
@@ -687,47 +724,25 @@ def fused_finish(
         z = jnp.zeros((npad - n, 1), jnp.float32)
         wb = jnp.concatenate([wb, z], axis=0)
         fm = jnp.concatenate([fm, z], axis=0)
-    # Column padding copies the matrix — callers at giant scale should
-    # allocate the update buffer pre-padded to a _BLOCK_D multiple
-    # (zero-filled padding columns aggregate to values that are sliced
-    # off below).
-    dpad = -(-d // _BLOCK_D) * _BLOCK_D
-    if dpad != d:
-        updates = jnp.pad(updates, ((0, 0), (0, dpad - d)))
-    if rbuf.shape[1] != dpad:
-        rbuf = jnp.pad(rbuf, ((0, 0), (0, dpad - rbuf.shape[1])))
+    cols = cols or stripe_cols(npad)
+    updates, rbuf, dpad = _pad_to_stripes(updates, rbuf, cols)
 
     kernel = functools.partial(
         _fused_kernel, n_true=n, forge=forge, agg=agg, sanitize=sanitize,
         keys16=updates.dtype == jnp.bfloat16,
     )
+    stripe, rows1, row = _block_specs(npad, cols)
     agg_vec, sq, bad = pl.pallas_call(
         kernel,
-        grid=(dpad // _BLOCK_D,),
-        in_specs=[
-            pl.BlockSpec((npad, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, _BLOCK_D), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((npad, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        grid=(dpad // cols,),
+        in_specs=[stripe, rows1, rows1, row],
+        out_specs=[row, rows1, rows1],
         out_shape=[
             jax.ShapeDtypeStruct((1, dpad), jnp.float32),
             jax.ShapeDtypeStruct((npad, 1), jnp.float32),
             jax.ShapeDtypeStruct((npad, 1), jnp.float32),
         ],
-        compiler_params=stripe_compiler_params(npad),
+        compiler_params=stripe_compiler_params(npad, cols=cols),
         interpret=interpret,
     )(updates, wb, fm, rbuf)
     return agg_vec[0, :d], sq[:n, 0], bad[:n, 0] > 0

@@ -20,6 +20,18 @@ rather than payload-preserving).
 Used by :class:`blades_tpu.ops.aggregators.Median` / ``Trimmedmean`` when
 running on a TPU backend with a large matrix, and directly by the
 single-chip streamed round (:mod:`blades_tpu.parallel.streamed`).
+
+The stripe's width.  The kernels here and the row statistics
+(:mod:`blades_tpu.ops.pallas_rowstats`) walk the matrix in stripes of
+``_BLOCK_D`` = 512 columns at any height.  The fused finishes
+(:mod:`blades_tpu.ops.pallas_round`) take theirs from the matrix's height,
+:func:`stripe_cols`: 512 from 64 rows up, wider below (3072 at 8 rows),
+because a grid step has a fixed cost that four vregs of data do not pay
+for.  Whoever allocates a matrix for them pads its columns to
+:func:`stripe_padded`, a multiple of 512, so every kernel here is served
+by the same allocation.  ``sanitize`` in the fused finishes is
+stripe-local, so its granularity is that width too: a non-finite value
+blanks its row over the columns of its stripe.
 """
 
 from __future__ import annotations
@@ -34,13 +46,55 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Column-stripe width per grid step.
+# Narrowest column stripe of a grid step: the width of every stripe kernel
+# that keeps one width at any height (the rank-select kernels here,
+# ops/pallas_rowstats.py), and of the fused finishes from 64 rows up.
 _BLOCK_D = 512
 
+# A ``(1, cols)`` float32 value fills 8 sublanes: as many vregs, and as
+# much VMEM, as a whole 8-row stripe.
+_ONE_ROW = 8
 
-def stripe_compiler_params(rows: int, extra_bytes: int = 0):
+# What one grid step of a fused finish (ops/pallas_round.py) works on, in
+# float32 bytes: its stripe and the two one-row values a radix step keeps
+# beside it (``res`` and ``cnt``), ``(rows + 16) x stripe_cols(rows) x 4``.  A grid step costs ~0.33 us
+# before it has done anything, and at 8 rows x 512 columns (4 vregs of
+# data) that was most of a step's 0.6 us; but the radix search goes over
+# the stripe 16 times, and past 72 vregs each pass goes through VMEM
+# again.  Chosen by sweeping the width at 8, 16, 24, 32 and 64 rows on a
+# v5e (``tools/chip_kernels.py --sweep``; the readings are in PERF.md §6,
+# PR 30): 3072 columns at 8 rows, 2048 at 16, 1536 at 24 and 32, 512 from
+# 64 rows up.
+_STRIPE_BUDGET = 288 << 10
+
+
+def stripe_cols(rows: int) -> int:
+    """Stripe width of the fused finishes at a matrix ``rows`` high: the
+    multiple of ``_BLOCK_D`` at which the stripe and the radix search's
+    one-row values fill ``_STRIPE_BUDGET``, never under ``_BLOCK_D``.  A
+    function of the height alone, so whoever allocates the matrix
+    (parallel/streamed.py::step, the tools) pads its columns to the
+    width the kernel will use (:func:`stripe_padded`).  512 from 64 rows
+    up: the federations of hundreds of clients compile the programs they
+    always did.
+    """
+    rows = -(-rows // 8) * 8 + 2 * _ONE_ROW
+    return max(_BLOCK_D, _STRIPE_BUDGET // (rows * 4) // _BLOCK_D * _BLOCK_D)
+
+
+def stripe_padded(d: int, rows: int) -> int:
+    """``d`` columns rounded up to a whole number of the fused finishes'
+    stripes at a matrix ``rows`` high: the width to ALLOCATE the matrix
+    at, so that no pad inside the call copies it."""
+    cols = stripe_cols(rows)
+    return -(-d // cols) * cols
+
+
+def stripe_compiler_params(rows: int, extra_bytes: int = 0,
+                           cols: int = _BLOCK_D):
     """Mosaic parameters for a kernel that holds a full-height
-    ``(rows, _BLOCK_D)`` stripe in VMEM.
+    ``(rows, cols)`` stripe in VMEM (``cols``: ``_BLOCK_D``, or the
+    fused finishes' :func:`stripe_cols`).
 
     libtpu's default scoped-VMEM limit is 16 MiB, and a stripe kernel
     needs several stripe-sized buffers at once: the double-buffered
@@ -50,8 +104,16 @@ def stripe_compiler_params(rows: int, extra_bytes: int = 0):
     default refuses.  Eight f32 stripes (plus ``extra_bytes`` for
     non-stripe residents such as a Gram block) clears every kernel at
     the gate's height bound; below 16 MiB the default stands.
+
+    A short, wide stripe also pays for its ONE-ROW residents, which a
+    tall one can forget: a ``(1, cols)`` float32 block (the forge's
+    uniforms, the aggregate, the forged row: each double-buffered) and
+    every ``(1, cols)`` temporary of the radix search (``res``,
+    ``cand``, ``cnt``) fills 8 sublanes, so each costs as much VMEM as
+    a whole 8-row stripe.  Sixteen of them are reckoned (at the widths
+    :func:`stripe_cols` gives, 1.5 MiB at most).
     """
-    need = 8 * rows * _BLOCK_D * 4 + extra_bytes
+    need = (8 * rows + 16 * _ONE_ROW) * cols * 4 + extra_bytes
     return pltpu.CompilerParams(vmem_limit_bytes=max(16 << 20, need))
 
 

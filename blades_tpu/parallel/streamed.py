@@ -848,30 +848,35 @@ def streamed_step(
                         "as benign on the compacted path)"
                     )
                 _checked_mask[0] = malicious
-            # The fused pallas finishes want stripe-aligned columns; padding
-            # at allocation (zero columns, sliced off the aggregate) avoids a
-            # whole-matrix pad copy inside the kernel call.  The row-geometry
-            # path pads for the same reason whenever the fused row-stats
-            # kernel can serve its planner bundles (chunk traversals are
-            # bounded to d_model either way, so padding is inert on the
-            # fallback path).
-            pad_cols = use_fused
-            if row_geom or row_forges:
-                from blades_tpu.ops.pallas_rowstats import (
-                    kernel_applicable as _rowstats_ok,
-                )
-
-                pad_cols = pad_cols or _rowstats_ok(n, d_model)
-            if pad_cols:
-                from blades_tpu.ops.pallas_select import _BLOCK_D
-
-                d_alloc = -(-d_model // _BLOCK_D) * _BLOCK_D
-            else:
-                d_alloc = d_model
             # The compact matrix is a whole number of blocks (and of
             # sublanes) high: its +inf padding rows, which the kernel
             # excludes via num_real, take a padded last block's surplus.
             rows = -(-(plan.blocks * plan.block) // 8) * 8 if compact else n
+            # The fused pallas finishes want stripe-aligned columns; padding
+            # at allocation (zero columns, sliced off the aggregate) avoids a
+            # whole-matrix pad copy inside the kernel call.  Their stripe
+            # is as wide as the matrix's height allows (stripe_cols), a
+            # multiple of the 512 the row-stats kernel walks.  The
+            # row-geometry path pads for that kernel whenever it can serve
+            # its planner bundles (chunk traversals are bounded to d_model
+            # either way, so padding is inert on the fallback path).
+            from blades_tpu.ops.pallas_select import (
+                _BLOCK_D,
+                stripe_cols,
+                stripe_padded,
+            )
+
+            finish_cols, d_alloc = None, d_model
+            if use_fused:
+                finish_cols = stripe_cols(rows)
+                d_alloc = stripe_padded(d_model, rows)
+            elif row_geom or row_forges:
+                from blades_tpu.ops.pallas_rowstats import (
+                    kernel_applicable as _rowstats_ok,
+                )
+
+                if _rowstats_ok(n, d_model):
+                    d_alloc = -(-d_model // _BLOCK_D) * _BLOCK_D
             if compact and rows != nb:
                 updates_buf = _alloc_row_padded(rows, nb, d_alloc)
             else:
@@ -1003,6 +1008,11 @@ def streamed_step(
             metrics["store_blocks"] = np.int32(plan.blocks)
             metrics["store_blocks_aligned"] = np.int32(plan.aligned_stores)
             metrics["surplus_lanes"] = np.int32(plan.surplus)
+            if finish_cols:
+                # The fused finish's stripe width at this matrix's height
+                # (pallas_select.stripe_cols): the columns its allocation
+                # was padded to, and the grid is d_alloc over it.
+                metrics["finish_stripe_cols"] = np.int32(finish_cols)
         return RoundState(server=server, client_opt=client_opt), metrics
 
     # Expose the jitted phases for profiling / inspection.  A round runs

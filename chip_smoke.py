@@ -128,6 +128,7 @@ def train_rounds(algo, rounds: int, compiles: CompileLog) -> dict:
         "round_ok": True,
         "num_unhealthy": [int(r.get("num_unhealthy", 0)) for r in rows],
         "elided_lanes": rows[-1].get("elided_lanes"),
+        "finish_stripe_cols": rows[-1].get("finish_stripe_cols"),
         "round_s": secs,
         "compile_s_in_round": compile_s,
         "steady_round_s": min(secs),
@@ -157,7 +158,7 @@ def finish_that_ran(algo, compiles: CompileLog) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from blades_tpu.ops.pallas_select import _BLOCK_D
+    from blades_tpu.ops.pallas_select import stripe_padded
 
     want = "jit(_finish_fused_compact)"
     ran = sorted({"jit(_finish)", "jit(_finish_fused)", want}
@@ -168,7 +169,7 @@ def finish_that_ran(algo, compiles: CompileLog) -> dict:
     cfg = algo.config
     n, f = cfg.num_clients, cfg.num_malicious_clients
     rows = -(-(n - f) // 8) * 8
-    cols = -(-algo._num_params // _BLOCK_D) * _BLOCK_D
+    cols = stripe_padded(algo._num_params, rows)
     buf = jax.ShapeDtypeStruct((rows, cols), jnp.dtype(cfg.update_dtype))
     losses = jax.ShapeDtypeStruct((n,), jnp.float32)
     compiled = compiles.programs[want]

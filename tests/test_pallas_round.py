@@ -14,8 +14,12 @@ import pytest
 
 from blades_tpu.adversaries.base import benign_mean_std
 from blades_tpu.ops.pallas_round import fused_finish
-
-STRIPE = 512  # pallas_select._BLOCK_D
+from blades_tpu.ops.pallas_select import (
+    _BLOCK_D,
+    stripe_cols,
+    stripe_compiler_params,
+    stripe_padded,
+)
 
 
 def _ref_forge(x, mal, forge, round_bf16=False):
@@ -40,6 +44,164 @@ def _ref_agg(x, agg):
         return (s[(n - 1) // 2] + s[n // 2]) / 2
     k = agg[1]
     return s[k:n - k].mean(axis=0)
+
+
+# ---------------------------------------------------------------------------
+# The stripe's width follows the matrix's height (pallas_select.stripe_cols)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows", [64, 512, 576, 752, 2048])
+def test_stripe_cols_is_512_for_tall_matrices(rows):
+    """The federations of hundreds of clients compile the programs they
+    always did."""
+    assert stripe_cols(rows) == _BLOCK_D == 512
+
+
+@pytest.mark.parametrize("rows", [8, 16])
+def test_stripe_cols_is_wide_for_short_matrices(rows):
+    cols = stripe_cols(rows)
+    assert cols > 512 and cols % 512 == 0
+    # Rows pad to sublanes inside the call: the width is the padded height's.
+    assert stripe_cols(rows - 3) == cols
+
+
+def test_stripe_cols_never_widens_with_height():
+    widths = [stripe_cols(rows) for rows in range(1, 2049)]
+    assert all(a >= b for a, b in zip(widths, widths[1:]))
+    assert all(w >= 512 and w % 512 == 0 for w in widths)
+
+
+@pytest.mark.parametrize("rows", [8, 16, 24, 64, 256, 512, 752, 2048])
+def test_stripe_vmem_limit_can_be_granted(rows):
+    """What stripe_compiler_params asks Mosaic for at the rule's width
+    (eight stripes and sixteen one-row residents, or the 16 MiB that
+    stand by default) is far under a v5e core's 128 MiB at every height:
+    no width asks for what cannot be had, and a short matrix's wide
+    stripe asks for no more than a tall one's."""
+    limit = stripe_compiler_params(rows, cols=stripe_cols(rows))
+    assert (16 << 20) <= limit.vmem_limit_bytes <= (8 * 2048 + 128) * 512 * 4
+    if rows <= 752:
+        assert limit.vmem_limit_bytes == 16 << 20   # the cells' programs
+
+
+@pytest.mark.parametrize("rows,d", [(8, 1000), (8, 413_959_168), (752, 4_903_242)])
+def test_stripe_padded_is_whole_stripes(rows, d):
+    cols, padded = stripe_cols(rows), stripe_padded(d, rows)
+    assert padded % cols == 0 and 0 <= padded - d < cols
+
+
+def _widths_case(rows, stripes, off):
+    """d just under / at / just over ``stripes`` wide stripes."""
+    return rows, stripes * stripe_cols(rows) + off
+
+
+# Every (height, width) edge once, the aggregator x forge x storage grid
+# spread over them (each column is computed alone and does not know the
+# stripe: an edge is an edge for all of them).
+_WIDTH_GRID = [
+    (*_widths_case(8, 1, -1), ("median",), ("alie", 0.7), jnp.bfloat16),
+    (*_widths_case(8, 1, 0), ("trimmed", 2), ("ipm", 1.5), jnp.float32),
+    (*_widths_case(8, 1, 1), ("mean",), ("alie", 0.7), jnp.float32),
+    (*_widths_case(8, 2, -1), ("trimmed", 2), ("alie", 0.7), jnp.bfloat16),
+    (*_widths_case(8, 2, 0), ("median",), ("ipm", 1.5), jnp.bfloat16),
+    (*_widths_case(8, 2, 1), ("median",), ("alie", 0.7), jnp.float32),
+    (*_widths_case(16, 1, -1), ("median",), ("ipm", 1.5), jnp.float32),
+    (*_widths_case(16, 1, 0), ("mean",), ("ipm", 1.5), jnp.bfloat16),
+    (*_widths_case(16, 1, 1), ("trimmed", 3), ("alie", 0.7), jnp.float32),
+    (*_widths_case(13, 2, -1), ("median",), ("alie", 0.7), jnp.bfloat16),
+    (*_widths_case(16, 2, 0), ("trimmed", 3), ("ipm", 1.5), jnp.bfloat16),
+    (*_widths_case(16, 2, 1), ("mean",), ("alie", 0.7), jnp.bfloat16),
+    (*_widths_case(24, 1, -1), ("trimmed", 4), ("ipm", 1.5), jnp.bfloat16),
+    (*_widths_case(24, 1, 0), ("median",), ("alie", 0.7), jnp.float32),
+    (*_widths_case(24, 1, 1), ("median",), ("ipm", 1.5), jnp.bfloat16),
+    (*_widths_case(24, 2, -1), ("mean",), ("ipm", 1.5), jnp.float32),
+    (*_widths_case(21, 2, 0), ("median",), ("alie", 0.7), jnp.bfloat16),
+    (*_widths_case(24, 2, 1), ("trimmed", 4), ("alie", 0.7), jnp.float32),
+]
+
+
+def _width_id(v):
+    return getattr(v, "__name__", None) or (
+        "-".join(str(p) for p in v) if isinstance(v, tuple) else str(v))
+
+
+@pytest.mark.parametrize("kernel", ["compact", "full"])
+@pytest.mark.parametrize("rows,d,agg,forge,dtype", _WIDTH_GRID, ids=_width_id)
+def test_wide_stripe_has_the_bits_of_the_512_column_kernel(
+        kernel, rows, d, agg, forge, dtype):
+    """The aggregate and the forged row are computed per column and do not
+    know the stripe: at the rule's width they have the bits the same call
+    gives when forced to 512 columns.  The row norms accumulate across
+    stripes, so their summation order follows the width.
+
+    float32 storage is held to 2e-6 here and not to the bit: the
+    interpreter's kernel body is an XLA:CPU program, whose reductions over
+    the rows are emitted by the block's shape (the forge's float32 mean
+    and variance then differ in the last place at some shapes, and bf16
+    storage rounds that away).  Mosaic reduces a column's rows the same
+    way at any width: tools/chip_kernels.py --sweep compares the bits on
+    the chip, in either storage."""
+    from blades_tpu.ops import pallas_round
+
+    rng = np.random.default_rng(seed=rows * 7 + d)
+    x = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32).astype(dtype)
+    if kernel == "compact":
+        def call(cols):
+            return pallas_round._fused_finish_compact_jit(
+                x, None, forged_mult=3, forge=forge, agg=agg, sanitize=True,
+                interpret=True, cols=cols)
+    else:
+        mal = jnp.arange(rows) < max(rows // 4, 1)
+
+        def call(cols):
+            agg_vec, sq, bad = pallas_round._fused_finish_jit(
+                x, mal, None, forge=forge, agg=agg, sanitize=True,
+                interpret=True, cols=cols)
+            return agg_vec, sq, bad, agg_vec
+    wide, narrow = call(None), call(512)
+    assert wide[0].shape == (d,)
+    for i in (0, 3):   # the aggregate and the forged row
+        if dtype == jnp.bfloat16:
+            np.testing.assert_array_equal(
+                np.asarray(wide[i]).view(np.uint32),
+                np.asarray(narrow[i]).view(np.uint32))
+        else:
+            np.testing.assert_allclose(np.asarray(wide[i]),
+                                       np.asarray(narrow[i]),
+                                       rtol=0, atol=2e-6)
+    assert not np.asarray(wide[2]).any()
+    np.testing.assert_allclose(np.asarray(wide[1]), np.asarray(narrow[1]),
+                               rtol=1e-6)
+
+
+def test_compact_sanitize_is_local_to_the_wide_stripe():
+    """The one semantic that follows the width: a non-finite value blanks
+    its row over its stripe, which at 8 rows is the wide one.  The flag is
+    the 512-column kernel's, and so is every column outside that stripe."""
+    from blades_tpu.ops import pallas_round
+
+    rows, width = 8, stripe_cols(8)
+    d = 2 * width + 100
+    rng = np.random.default_rng(seed=3)
+    x = jnp.asarray(rng.normal(size=(rows, d)), jnp.bfloat16)
+    x = x.at[5, width + 700].set(jnp.nan)       # in the second wide stripe
+
+    def call(x, cols):
+        return pallas_round._fused_finish_compact_jit(
+            x, None, forged_mult=2, forge=("alie", 0.7), agg=("median",),
+            sanitize=True, interpret=True, cols=cols)
+
+    wide, narrow = call(x, None), call(x, 512)
+    blanked = call(x.at[5, width:2 * width].set(0), None)
+    assert list(np.nonzero(np.asarray(wide[2]))[0]) == [5]
+    np.testing.assert_array_equal(np.asarray(wide[2]), np.asarray(narrow[2]))
+    outside = np.r_[0:width, 2 * width:d]
+    for i in (0, 3):   # the aggregate and the forged row
+        np.testing.assert_array_equal(np.asarray(wide[i]),
+                                      np.asarray(blanked[i]))
+        np.testing.assert_array_equal(np.asarray(wide[i])[outside],
+                                      np.asarray(narrow[i])[outside])
 
 
 @pytest.mark.parametrize("n,d", [(24, 1000), (17, 700), (64, 2048)])
@@ -135,10 +297,13 @@ def test_fused_adaptive_requires_noise():
 
 
 def test_fused_sanitize_stripe_local():
-    """A non-finite value zeroes its row within that 512-wide stripe only
-    (same chunk-local semantics as the streamed chunk path), and the row
-    is reported unhealthy."""
-    n, d = 16, STRIPE + 40
+    """A non-finite value zeroes its row within that stripe only (same
+    chunk-local semantics as the streamed chunk path; the stripe is as
+    wide as the matrix's height allows), and the row is reported
+    unhealthy."""
+    n = 16
+    STRIPE = stripe_cols(n)
+    d = STRIPE + 40
     rng = np.random.default_rng(seed=7)
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     x = x.at[3, 2].set(jnp.inf)
@@ -175,8 +340,7 @@ def test_streamed_step_fused_branch_matches_chunked(monkeypatch):
     monkeypatch.setattr(pallas_round, "should_use", lambda n, d: True)
     monkeypatch.setattr(
         pallas_round, "fused_finish",
-        functools.partial(pallas_round.fused_finish.__wrapped__,
-                          interpret=True),
+        functools.partial(pallas_round.fused_finish, interpret=True),
     )
 
     n, f = 12, 3
@@ -565,6 +729,73 @@ def test_streamed_step_compact_with_row_padding(monkeypatch):
         fr, client_block=4, update_dtype=jnp.float32, donate=False)
     s2, m2 = step_chunked(state0, x, y, lengths, mal, key)
 
+    for k in ("train_loss", "agg_norm", "update_norm_mean"):
+        np.testing.assert_allclose(float(m1[k]), float(m2[k]), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(s1.server.params),
+                    jax.tree.leaves(s2.server.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_streamed_round_at_8_rows_allocates_to_the_wide_stripe(monkeypatch):
+    """The language-model cell's geometry on the MLP: 10 clients, 2 ALIE
+    elided, 8 benign rows stored.  The matrix is allocated a whole number
+    of the finish's WIDE stripes across (no pad inside the call copies
+    it), the round equals the chunked finish's, and the metrics carry the
+    width as a host int that the row's schema knows."""
+    import functools
+
+    from blades_tpu import parallel
+    from blades_tpu.adversaries import get_adversary, make_malicious_mask
+    from blades_tpu.core import FedRound, Server, TaskSpec
+    from blades_tpu.obs.schema import ROUND_RECORD_FIELDS
+    from blades_tpu.ops import pallas_round, pallas_select
+
+    seen = []
+
+    def interpreted(updates, *args, **kw):
+        seen.append(updates.shape)
+        return compact(updates, *args, **kw, interpret=True)
+
+    compact = pallas_round.fused_finish_compact
+    monkeypatch.setattr(pallas_round, "should_use", lambda n, d: True)
+    monkeypatch.setattr(pallas_select, "kernel_applicable",
+                        lambda n, d: True)
+    monkeypatch.setattr(pallas_round, "fused_finish_compact", interpreted)
+
+    n, f = 10, 2
+    task = TaskSpec(model="mlp", input_shape=(8, 8, 1), num_classes=10,
+                    lr=0.1).build()
+    server = Server.from_config(aggregator="Median", lr=0.5)
+    adv = get_adversary("ALIE", num_clients=n, num_byzantine=f)
+    fr = FedRound(task=task, server=server, adversary=adv, batch_size=4,
+                  num_batches_per_round=1)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(n, 8, 8, 8, 1)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 10, size=(n, 8)), jnp.int32)
+    lengths = jnp.full((n,), 8, jnp.int32)
+    mal = make_malicious_mask(n, f)
+    key = jax.random.PRNGKey(3)
+
+    step = functools.partial(parallel.streamed.streamed_step, fr,
+                             client_block=1, update_dtype=jnp.float32,
+                             donate=False)
+    s1, m1 = step(malicious_prefix=f)(
+        fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal, key)
+
+    width = stripe_cols(8)
+    d = sum(p.size for p in jax.tree.leaves(s1.server.params))
+    assert seen == [(8, stripe_padded(d, 8))] and seen[0][1] % width == 0
+    assert int(m1["finish_stripe_cols"]) == width > 512
+    assert isinstance(m1["finish_stripe_cols"], np.integer)  # a host stamp
+    assert "finish_stripe_cols" in ROUND_RECORD_FIELDS
+
+    monkeypatch.setattr(pallas_round, "should_use", lambda n, d: False)
+    monkeypatch.setattr(pallas_select, "kernel_applicable",
+                        lambda n, d: False)
+    s2, m2 = step()(fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal,
+                    key)
+    assert "finish_stripe_cols" not in m2   # the chunked finish: no stripe
     for k in ("train_loss", "agg_norm", "update_norm_mean"):
         np.testing.assert_allclose(float(m1[k]), float(m2[k]), rtol=1e-5)
     for a, b in zip(jax.tree.leaves(s1.server.params),

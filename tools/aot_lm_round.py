@@ -38,7 +38,7 @@ def main() -> int:
     from jax.sharding import SingleDeviceSharding
 
     from blades_tpu.algorithms import get_algorithm_class
-    from blades_tpu.ops.pallas_select import _BLOCK_D
+    from blades_tpu.ops.pallas_select import stripe_cols, stripe_padded
     from blades_tpu.parallel.streamed import block_plan, streamed_step
     from blades_tpu.tune import expand_grid, load_experiments_from_file
 
@@ -71,7 +71,7 @@ def main() -> int:
     state = jax.eval_shape(lambda k: fr.init(k, n), jax.random.PRNGKey(0))
     d = sum(p.size for p in jax.tree.leaves(state.server.params))
     rows = -(-(plan.blocks * plan.block) // 8) * 8
-    d_alloc = -(-d // _BLOCK_D) * _BLOCK_D
+    d_alloc = stripe_padded(d, rows)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -97,7 +97,9 @@ def main() -> int:
 
     seq, cap = tuple(config.input_shape)[0], 16
     print(json.dumps({"model": model, "num_params": d, "plan": plan._asdict(),
-                      "matrix": [rows, d_alloc], "topology": "v5e:2x2"}),
+                      "matrix": [rows, d_alloc],
+                      "finish_stripe_cols": stripe_cols(rows),
+                      "topology": "v5e:2x2"}),
           flush=True)
     with mock.patch.object(jax, "default_backend", return_value="tpu"):
         report("_train_block", step.train_block.lower(

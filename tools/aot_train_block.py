@@ -63,7 +63,7 @@ def main() -> int:
     from jax.sharding import SingleDeviceSharding
 
     from blades_tpu.algorithms import get_algorithm_class
-    from blades_tpu.ops.pallas_select import _BLOCK_D
+    from blades_tpu.ops.pallas_select import stripe_padded
     from blades_tpu.parallel.streamed import block_plan, streamed_step
     from blades_tpu.tune import expand_grid, load_experiments_from_file
 
@@ -92,7 +92,8 @@ def main() -> int:
     plan = block_plan(n, f, client_block, dtype, compact=True)
     state = jax.eval_shape(lambda k: fr.init(k, n), jax.random.PRNGKey(0))
     d = sum(p.size for p in jax.tree.leaves(state.server.params))
-    rows, d_alloc = plan.blocks * plan.block, -(-d // _BLOCK_D) * _BLOCK_D
+    rows = plan.blocks * plan.block
+    d_alloc = stripe_padded(d, rows)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(

@@ -17,6 +17,10 @@ admit.  Two consumers:
 
       chiprun -- python tools/chip_kernels.py [name-substring ...]
 
+  ``--sweep [rows [d [dtype [width ...]]]]`` instead TIMES the compact finish
+  alone at a short matrix over stripe widths (:func:`sweep`): the
+  readings ``pallas_select._STRIPE_BUDGET`` was chosen from.
+
 - ``tests/test_chip_contract.py``, on the CPU: lowers each case for
   ``platforms=["tpu"]`` via ``jax.export`` — catches Pallas API drift in
   seconds without a chip.
@@ -28,6 +32,7 @@ says it applies: fix the kernel or tighten the gate, then re-run this.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -83,14 +88,14 @@ def _round_bf16(x: np.ndarray) -> np.ndarray:
 # -- references -------------------------------------------------------------
 
 
-def _ref_sanitize(x: np.ndarray, real: np.ndarray):
+def _ref_sanitize(x: np.ndarray, real: np.ndarray, stripe: int = STRIPE):
     """Stripe-local: a row with a non-finite value is zeroed within that
     stripe only, and reported."""
     x = x.copy()
     bad = np.zeros(x.shape[0], bool)
-    for s in range(0, x.shape[1], STRIPE):
-        row_bad = real & ~np.isfinite(x[:, s:s + STRIPE]).all(axis=1)
-        x[row_bad, s:s + STRIPE] = 0.0
+    for s in range(0, x.shape[1], stripe):
+        row_bad = real & ~np.isfinite(x[:, s:s + stripe]).all(axis=1)
+        x[row_bad, s:s + stripe] = 0.0
         bad |= row_bad
     return x, bad
 
@@ -114,11 +119,20 @@ def _ref_agg(full: np.ndarray, agg: tuple) -> np.ndarray:
 # -- case builders ----------------------------------------------------------
 
 
-def _matrix(name: str, n: int, dtype, nan_at=None) -> np.ndarray:
-    x = _rng(name).normal(size=(n, D)).astype(np.float32)
+def _matrix(name: str, n: int, dtype, nan_at=None, d: int = D) -> np.ndarray:
+    x = _rng(name).normal(size=(n, d)).astype(np.float32)
     if nan_at is not None:
         x[nan_at] = np.nan
     return _store(x, dtype)
+
+
+def _stripe_and_d(rows: int) -> Tuple[int, int]:
+    """A fused finish's stripe at this height, and the cases' width there:
+    ``D`` where the stripe is the 512 of the tall matrices, else two
+    stripes and a ragged third, so that the accumulators still cross
+    stripe boundaries and the last stripe is padded inside the call."""
+    stripe = pallas_select.stripe_cols(rows)
+    return stripe, D if stripe == STRIPE else 2 * stripe + 74
 
 
 def _compact_case(nb: int, mult: int, dtype, agg: tuple, mxu: str = "") -> Case:
@@ -130,9 +144,10 @@ def _compact_case(nb: int, mult: int, dtype, agg: tuple, mxu: str = "") -> Case:
     name = (f"compact_{agg[0]}_{jnp.dtype(dtype).name}_nb{nb}_mult{mult}"
             + (f"_mxu-{mxu}" if mxu else ""))
     radix_mxu, stats_mxu = pallas_round.parse_mxu_mode(mxu)
+    stripe, d = _stripe_and_d(rows)
 
     def inputs():
-        x = _matrix(name, rows, dtype, nan_at=(3, STRIPE + 7))
+        x = _matrix(name, rows, dtype, nan_at=(3, stripe + 7), d=d)
         x[nb:] = np.inf
         return (x,)
 
@@ -144,7 +159,7 @@ def _compact_case(nb: int, mult: int, dtype, agg: tuple, mxu: str = "") -> Case:
         return {"agg": agg_vec, "sq": sq, "bad": bad, "forged": forged}
 
     def reference(x):
-        benign, bad = _ref_sanitize(_f64(x)[:nb], np.ones(nb, bool))
+        benign, bad = _ref_sanitize(_f64(x)[:nb], np.ones(nb, bool), stripe)
         forged = _ref_forged(benign, bf16)
         full = np.concatenate([np.tile(forged, (mult, 1)), benign])
         return {"agg": _ref_agg(full, agg), "sq": (benign ** 2).sum(axis=1),
@@ -162,9 +177,10 @@ def _full_case(n: int, f: int, dtype, agg: tuple) -> Case:
     """``fused_finish`` over the full matrix with a malicious prefix."""
     bf16 = dtype == jnp.bfloat16
     name = f"fused_{agg[0]}_{jnp.dtype(dtype).name}_n{n}_f{f}"
+    stripe, d = _stripe_and_d(n)
 
     def inputs():
-        return (_matrix(name, n, dtype, nan_at=(f + 3, STRIPE + 7)),
+        return (_matrix(name, n, dtype, nan_at=(f + 3, stripe + 7), d=d),
                 np.arange(n) < f)
 
     def run(x, mal):
@@ -173,7 +189,7 @@ def _full_case(n: int, f: int, dtype, agg: tuple) -> Case:
         return {"agg": agg_vec, "sq": sq, "bad": bad}
 
     def reference(x, mal):
-        xs, bad = _ref_sanitize(_f64(x), np.ones(n, bool))
+        xs, bad = _ref_sanitize(_f64(x), np.ones(n, bool), stripe)
         forged = _ref_forged(xs[~mal], bf16)
         full = np.where(mal[:, None], forged, xs)
         return {"agg": _ref_agg(full, agg), "sq": (full ** 2).sum(axis=1),
@@ -289,11 +305,19 @@ def _cases() -> Tuple[Case, ...]:
         _compact_case(750, 250, bf16, ("median",), mxu="counts"),
         _compact_case(750, 250, bf16, ("median",), mxu="all"),
         _compact_case(2048, 680, f32, ("median",), mxu="all"),
+        # Short matrices, whose stripe is wide (pallas_select.stripe_cols):
+        # the language-model cell's 8 benign rows + 2 forged, and heights
+        # whose rows are padded inside the call.
+        _compact_case(8, 2, bf16, ("median",)),
+        _compact_case(8, 2, f32, ("trimmed", 2)),
+        _compact_case(13, 3, bf16, ("trimmed", 3)),
+        _compact_case(24, 8, f32, ("median",)),
         # Full-matrix finish (no elision).
         _full_case(1000, 250, bf16, ("median",)),
         _full_case(2048, 512, bf16, ("median",)),
         _full_case(2048, 512, f32, ("median",)),
         _full_case(2048, 512, f32, ("trimmed", 512)),
+        _full_case(16, 4, bf16, ("median",)),
         # Rank-select kernels behind Median/Trimmedmean (f32 only).
         _select_case(1000),
         _select_case(2048),
@@ -358,12 +382,99 @@ def check(case: Case) -> Dict[str, Any]:
     return rec
 
 
+SWEEP_ROWS, SWEEP_D = 8, 413_959_168  # joyai_n10_median's stored matrix
+SWEEP_WIDTHS = (512, 1024, 2048, 3072, 4096, 8192, 32768)
+
+
+def _bits_sums(v):
+    """Two wrapping uint32 sums over a float32 vector's bits (plain, and
+    weighted by an odd multiple of the index): equal vectors give equal
+    pairs, and the widths' 1.7 GB outputs need not be held side by side."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    index = jax.lax.iota(jnp.uint32, v.shape[0])
+    return jnp.stack([jnp.sum(bits), jnp.sum(bits * (2 * index + 1))])
+
+
+def sweep(argv) -> int:
+    """Time the compact finish ALONE (2 ALIE rows, Median, sanitize on,
+    bfloat16 unless told: the language-model cell's call) at a ``rows`` x
+    ``d`` matrix for each stripe width, the rule's own (``stripe_cols``)
+    included.  One JSON line a width: the first call (the compile), the
+    steady calls' milliseconds by the host's clock around
+    ``block_until_ready`` (the kernel and the ~20 ms of slices and sums
+    around it), the grid, whether the aggregate and the forged row have
+    the bits the 512-column kernel gave (by :func:`_bits_sums`), and the
+    row norms' largest relative gap to that kernel's (their float32 sums
+    run across stripes).  A refused compile is a line, not a failure."""
+    rows = int(argv[0]) if argv else SWEEP_ROWS
+    d = int(argv[1]) if len(argv) > 1 else SWEEP_D
+    dtype = jnp.dtype(argv[2] if len(argv) > 2 else "bfloat16")
+    widths = tuple(int(w) for w in argv[3:]) or SWEEP_WIDTHS
+    ruled = pallas_select.stripe_cols(rows)
+
+    @functools.partial(jax.jit, static_argnames=("dpad",))
+    def matrix(dpad):
+        """The matrix as the round allocates it, padded to the stripe
+        with zero columns: values in [-1, 1) hashed from (row, column),
+        one elementwise program with no temporary beside the 6.6 GB."""
+        col = jax.lax.broadcasted_iota(jnp.uint32, (rows, dpad), 1)
+        row = jax.lax.broadcasted_iota(jnp.uint32, (rows, dpad), 0)
+        h = col * jnp.uint32(2654435761) + row * jnp.uint32(40503)
+        h = (h ^ (h >> 15)) * jnp.uint32(2246822519)
+        h = h ^ (h >> 13)
+        v = (h >> 8).astype(jnp.float32) * 2.0 ** -23 - 1.0
+        return jnp.where(col < d, v, 0).astype(dtype)
+
+    @functools.partial(jax.jit, static_argnames=("cols",))
+    def finish(x, cols):
+        agg, sq, _, forged = pallas_round._fused_finish_compact_jit(
+            x, None, forged_mult=2, forge=("alie", ALIE_Z), agg=("median",),
+            sanitize=True, num_real=rows, cols=cols)
+        return _bits_sums(agg), _bits_sums(forged), sq
+
+    records, want = [], None
+    for cols in sorted({STRIPE, ruled, *widths}):
+        dpad = -(-d // cols) * cols
+        rec = {"rows": rows, "d": d, "dtype": dtype.name, "cols": cols,
+               "grid": dpad // cols, "ruled": cols == ruled}
+        try:
+            x = jax.block_until_ready(matrix(dpad))
+            t0 = time.perf_counter()
+            got = jax.block_until_ready(finish(x, cols))
+            rec["first_call_s"] = round(time.perf_counter() - t0, 2)
+            ms = []
+            for _ in range(4):
+                t0 = time.perf_counter()
+                jax.block_until_ready(finish(x, cols))
+                ms.append(round((time.perf_counter() - t0) * 1e3, 2))
+            rec["ms"] = ms
+            got = [np.asarray(g) for g in got]
+            want = want or got   # the narrowest width comes first
+            rec["agg_bits_equal"] = bool((got[0] == want[0]).all())
+            rec["forged_bits_equal"] = bool((got[1] == want[1]).all())
+            rec["sq_rel_err"] = float(
+                np.abs(got[2] / want[2] - 1.0).max())
+            del x, got
+        except Exception as e:  # a refused width is this sweep's finding
+            rec["error"] = f"{type(e).__name__}: {e}"[-600:]
+        records.append(rec)
+        print(json.dumps(rec), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join(
+            "chiprun_out", f"chip_kernels_sweep_{rows}x{d}_{dtype.name}.json"),
+            "w") as f:
+        json.dump(records, f, indent=1)
+    return 0
+
+
 def main(argv) -> int:
     dev = jax.devices()
     if dev[0].platform != "tpu":
         print(f"chip_kernels: needs a TPU, JAX found {dev[0].platform}",
               file=sys.stderr)
         return 2
+    if argv and argv[0] == "--sweep":
+        return sweep(argv[1:])
     picked = [c for c in CASES
               if not argv or any(s in c.name for s in argv)]
     records = []
