@@ -76,15 +76,6 @@ class FedavgConfig:
         self.augment: Any = "auto"
         # mixed-precision compute dtype (e.g. "bfloat16"); params stay f32
         self.compute_dtype: Any = None
-        # rounds fused per device dispatch (lax.scan); 1 = round-per-call
-        self.rounds_per_dispatch: int = 1
-        # chained_dispatch: with rounds_per_dispatch > 1, derive each
-        # scanned round's key by split-chaining the driver's carry
-        # (multi_step_chained) instead of multi_step's one-shot
-        # split(key, n) fan.  Rounds are then bit-identical to
-        # round-per-dispatch execution — the sweep's scan-window mode
-        # sets this.  Dense/streamed single-chip paths only.
-        self.chained_dispatch: bool = False
         # round-pipeline perf layer (blades_tpu/perf):
         # donate_buffers: donate RoundState into each dense dispatch —
         # the stacked client opt states are updated in place instead of
@@ -132,8 +123,8 @@ class FedavgConfig:
         # finish (documented float-reassociation tolerances).  Winners
         # persist to the on-disk plan cache (autotune_cache_dir /
         # $BLADES_TPU_PLAN_CACHE_DIR).  Explicitly-set knobs (execution,
-        # d_chunk, client_packing, mxu_finish, rounds_per_dispatch,
-        # prefetch) are never varied — the tuner only resolves what was
+        # d_chunk, client_packing, mxu_finish, prefetch) are never
+        # varied — the tuner only resolves what was
         # left at "auto"/default.
         self.autotune: Any = False
         self.autotune_cache_dir: Optional[str] = None
@@ -317,10 +308,6 @@ class FedavgConfig:
         # composition contract keys off this: an explicitly-set knob is
         # pinned in the plan space, a defaulted one may be tuned.
         self._explicit: set = set()
-        # Scan-window candidates the sweep runner computed for the
-        # autotuner (eligible chained windows, descending); private
-        # plumbing like _packing_decision.
-        self._autotune_windows = None
 
     # -- fluent setters ------------------------------------------------------
 
@@ -662,10 +649,6 @@ class FedavgConfig:
                  ".observability(ledger=False)"),
                 (self.control_config, "the control plane",
                  "drop .control()"),
-                (int(self.rounds_per_dispatch or 1) != 1,
-                 "rounds_per_dispatch > 1", "rounds_per_dispatch=1"),
-                (self.chained_dispatch, "chained_dispatch",
-                 "chained_dispatch=False"),
                 (self.autotune_mode, "the execution autotuner",
                  ".resources(autotune='off')"),
                 (self.mesh_shape is not None, "2-D mesh_shape",
@@ -735,10 +718,6 @@ class FedavgConfig:
                  ".resources(client_packing='off')"),
                 (self.autotune_mode, "the execution autotuner",
                  ".resources(autotune='off')"),
-                (int(self.rounds_per_dispatch or 1) != 1,
-                 "rounds_per_dispatch > 1", "rounds_per_dispatch=1"),
-                (self.chained_dispatch, "chained_dispatch",
-                 "chained_dispatch=False"),
                 (self.health_check, "the in-round health check",
                  ".fault_tolerance(health_check=False)"),
                 (self.dp_clip_threshold, "client DP",
@@ -766,8 +745,6 @@ class FedavgConfig:
                     "execution='dsharded' width-shards the update matrix "
                     "over a mesh; set .resources(num_devices=...) > 1"
                 )
-            # rounds_per_dispatch > 1 chains k d-sharded rounds in one
-            # lax.scan'ed program (parallel/dsharded.dsharded_multi_step).
         # Pod-scale knobs (parallel/hier.py): fail-fast on every
         # structural impossibility, naming the exact pair and the knob
         # that flips it.
@@ -805,13 +782,6 @@ class FedavgConfig:
                     "representatives over a mesh; set "
                     ".resources(num_devices=...) > 1"
                 )
-            if int(self.rounds_per_dispatch or 1) != 1:
-                raise ValueError(
-                    "execution='hier' × rounds_per_dispatch>1 is an "
-                    "unsupported pair: the hierarchical round is dispatched "
-                    "per-round (no chained-scan formulation yet) — set "
-                    "rounds_per_dispatch=1, or use a flat mesh path"
-                )
         if self.execution == "streamed":
             if self.num_devices and self.num_devices > 1:
                 raise ValueError(
@@ -820,9 +790,6 @@ class FedavgConfig:
                     "path — set .resources(num_devices=None), or use a "
                     "mesh execution (dsharded/hier) for multi-chip"
                 )
-            # rounds_per_dispatch > 1 chains k streamed rounds through the
-            # dispatch pipeline with no host sync between them
-            # (parallel/streamed.streamed_multi_step).
         if self.forensics:
             if self.execution in ("streamed", "dsharded"):
                 raise ValueError(
@@ -1034,11 +1001,6 @@ class FedavgConfig:
                  ".resources(client_packing='off')"),
                 (self.agg_domain != "f32", "wire-domain aggregation",
                  ".communication(agg_domain='f32')"),
-                (int(self.rounds_per_dispatch or 1) != 1,
-                 "rounds_per_dispatch > 1 (cohort staging happens "
-                 "between dispatches)", "rounds_per_dispatch=1"),
-                (self.chained_dispatch, "chained_dispatch",
-                 "chained_dispatch=False"),
             ):
                 if knob:
                     raise ValueError(
@@ -1127,13 +1089,6 @@ class FedavgConfig:
         # pairs with the exact knob that flips each one.
         policy = self.get_control_policy()
         if policy is not None:
-            if int(self.rounds_per_dispatch or 1) != 1:
-                raise ValueError(
-                    "control × rounds_per_dispatch > 1 is an unsupported "
-                    "pair: the controller observes and actuates between "
-                    "HOST-VISIBLE rounds, and a fused dispatch gives it "
-                    "none — set rounds_per_dispatch=1, or drop .control()"
-                )
             if self.execution in ("streamed", "dsharded"):
                 raise ValueError(
                     f"control × execution={self.execution!r} is an "
@@ -1272,12 +1227,6 @@ class FedavgConfig:
             from blades_tpu.perf.autotune import Plan
 
             Plan.from_dict(self.tuned_plan)
-        if self.chained_dispatch and self.num_devices and self.num_devices > 1:
-            raise ValueError(
-                "chained_dispatch (the sweep's scan-window key discipline) "
-                "has no mesh formulation; run without num_devices or drop "
-                "chained_dispatch"
-            )
         if self.prefetch not in ("auto", "on", "off", True, False):
             raise ValueError(
                 f"prefetch must be 'auto', True or False, got "

@@ -22,7 +22,7 @@ import pickle
 import warnings
 from dataclasses import replace as _dc_replace
 from pathlib import Path
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,14 +32,6 @@ from blades_tpu.adversaries import make_malicious_mask
 from blades_tpu.core import FedRound
 from blades_tpu.data import DatasetCatalog
 from blades_tpu.obs.trace import Timers, now
-from blades_tpu.perf.async_metrics import DEVICE_METRICS_KEY
-
-#: Private row key carrying the round's cohort id-vector (+ per-event
-#: staleness on async rows) from _train_raw to _fill_round_metrics —
-#: stamped at dispatch time so DEFERRED rows (train_raw + flush) keep
-#: their own cohort even after the engine has moved on.  Popped before
-#: the row reaches any sink; never schema-visible.
-_COHORT_KEY = "_cohort_ids"
 
 
 class Fedavg:
@@ -207,12 +199,6 @@ class Fedavg:
             self._plan, self._plan_provenance = self._resolve_autotune_plan()
             self._apply_plan(self._plan)
 
-        self._chunk = max(1, int(getattr(cfg, "rounds_per_dispatch", 1)))
-        # Chained key discipline (multi_step_chained): each scanned round
-        # consumes split(carry) exactly like the sequential driver, so
-        # windowed rounds are bit-identical to round-per-dispatch ones.
-        self._chained = (bool(getattr(cfg, "chained_dispatch", False))
-                         and self._chunk > 1)
         self._prefetcher = None   # set by _setup_dense_pipeline when active
         self._cache_wrappers = []  # CachedFunctions feeding the obs counters
         self._async = None        # AsyncEngine under execution="async"
@@ -286,7 +272,7 @@ class Fedavg:
             self._evaluate = gossip_evaluate(self.fed_round)
         elif cfg.num_devices and cfg.num_devices > 1:
             from blades_tpu.parallel import make_mesh, shard_federation, sharded_step
-            from blades_tpu.parallel.sharded import sharded_evaluate, sharded_multi_step
+            from blades_tpu.parallel.sharded import sharded_evaluate
 
             self.mesh = make_mesh(num_devices=cfg.num_devices,
                                   mesh_shape=getattr(cfg, "mesh_shape", None))
@@ -342,22 +328,12 @@ class Fedavg:
                     bucket_size=int(getattr(cfg, "bucket_size", 1)),
                 )
             elif use_dsharded:
-                from blades_tpu.parallel.dsharded import (dsharded_multi_step,
-                                                          dsharded_step)
+                from blades_tpu.parallel.dsharded import dsharded_step
 
                 # Width-sharded giant-federation round: per-device memory
                 # is n*d/n_dev — the (n, d) matrix never exists anywhere.
-                if self._chunk > 1:
-                    self._step = dsharded_multi_step(
-                        self.fed_round, self.mesh, self._chunk,
-                        malicious_prefix=mal_prefix)
-                else:
-                    self._step = dsharded_step(self.fed_round, self.mesh,
-                                               malicious_prefix=mal_prefix)
-            elif self._chunk > 1:
-                self._step = sharded_multi_step(
-                    self.fed_round, self.mesh, self._chunk, donate=False
-                )
+                self._step = dsharded_step(self.fed_round, self.mesh,
+                                           malicious_prefix=mal_prefix)
             else:
                 self._step = sharded_step(self.fed_round, self.mesh, donate=False)
             self._evaluate = sharded_evaluate(self.fed_round, self.mesh)
@@ -404,7 +380,8 @@ class Fedavg:
                 with self.timers.span("blades/setup/data"):
                     x, y, ln = self._train_arrays
                     self._train_arrays = (x.astype(jnp.dtype(cd)), y, ln)
-            streamed_kw = dict(
+            self._step = streamed_step(
+                self.fed_round,
                 client_block=cfg.client_block,
                 d_chunk=cfg.d_chunk,
                 mxu_finish=getattr(cfg, "mxu_finish", None),
@@ -414,14 +391,6 @@ class Fedavg:
                 # skip the dead malicious-lane training blocks.
                 malicious_prefix=cfg.num_malicious_clients,
             )
-            if self._chunk > 1:
-                from blades_tpu.parallel.streamed import streamed_multi_step
-
-                self._step = streamed_multi_step(
-                    self.fed_round, self._chunk, chained=self._chained,
-                    **streamed_kw)
-            else:
-                self._step = streamed_step(self.fed_round, **streamed_kw)
             self._evaluate = jax.jit(self.fed_round.evaluate)
         else:
             self._setup_dense_pipeline()
@@ -500,22 +469,13 @@ class Fedavg:
         round computes.  All three are bit-transparent: aggregates and
         round metrics match the eager ``jax.jit(fr.step)`` path exactly
         (tests/test_perf.py)."""
-        from functools import partial
-
         from blades_tpu.perf import cached_jit
 
         cfg = self.config
         donate = (0,) if getattr(cfg, "donate_buffers", True) else ()
         fp = self._program_fingerprint()
         self._prefetcher = None
-        if self._chunk > 1 and self._chained:
-            step_fn = partial(self.fed_round.multi_step_chained,
-                              num_rounds=self._chunk)
-            key = ("step", "chained", self._chunk, fp)
-        elif self._chunk > 1:
-            step_fn = partial(self.fed_round.multi_step, num_rounds=self._chunk)
-            key = ("step", "multi", self._chunk, fp)
-        elif self._resolve_prefetch():
+        if self._resolve_prefetch():
             from blades_tpu.data.prefetch import BatchPrefetcher
 
             sample = (cached_jit(self.fed_round.sample_round_batches,
@@ -585,17 +545,14 @@ class Fedavg:
             self._evaluate = jax.jit(self.fed_round.evaluate)
 
     def _resolve_prefetch(self) -> bool:
-        """``prefetch='auto'`` resolves to ON for the dense single-round
-        dispatch (the path with a per-round sampling stage to overlap)
-        on an accelerator backend; ``rounds_per_dispatch > 1`` samples
-        inside the scan, where there is nothing left to stage, and the
-        single-threaded CPU backend has no transfer/compute overlap to
-        win — there 'auto' skips the second program's compile.  ``True``
-        forces it anywhere (the bit-identity tests do)."""
+        """``prefetch='auto'`` resolves to ON for the dense dispatch
+        (the path with a per-round sampling stage to overlap) on an
+        accelerator backend; the single-threaded CPU backend has no
+        transfer/compute overlap to win — there 'auto' skips the second
+        program's compile.  ``True`` forces it anywhere (the
+        bit-identity tests do)."""
         want = getattr(self.config, "prefetch", "auto")
         if want in (False, "off"):
-            return False
-        if self._chunk != 1:
             return False
         if want in (True, "on"):
             return True
@@ -723,8 +680,7 @@ class Fedavg:
     def _dsharded_auto(self) -> bool:
         """On a mesh, pick the width-sharded round when the replicated
         (n, d) matrix the gather formulations materialise per device
-        would strain HBM (dsharded_multi_step covers rounds_per_dispatch
-        > 1 since round 5)."""
+        would strain HBM."""
         return self._dense_matrix_bytes() > self.dense_matrix_hbm_limit()
 
     def _streamed_supported(self) -> bool:
@@ -913,19 +869,7 @@ class Fedavg:
                 if dec and int(dec.get("pack_factor", 1)) == p:
                     packs.append(p)
 
-        # Scan windows: a pinned rounds_per_dispatch stays pinned; the
-        # sweep runner supplies the eligible chained windows
-        # (descending, its own current pick first) via
-        # _autotune_windows — outside a sweep there is no window
-        # machinery to drive, so the space stays at 1.
-        rpd = int(getattr(cfg, "rounds_per_dispatch", 1) or 1)
-        if rpd != 1:
-            windows = [rpd]
-        else:
-            windows = [int(w) for w in
-                       (getattr(cfg, "_autotune_windows", None) or (1,))]
-
-        # Prefetch (dense single-round batch staging, bit-transparent):
+        # Prefetch (dense batch staging, bit-transparent):
         # resolved default first, the flip offered only when left "auto".
         base_pre = (False if cfg.prefetch in (False, "off")
                     else True if cfg.prefetch in (True, "on")
@@ -1001,7 +945,7 @@ class Fedavg:
 
         return at.enumerate_plans(
             executions=execs, d_chunks=d_chunks, mxu_modes=mxu_modes,
-            pack_factors=packs, scan_windows=windows,
+            pack_factors=packs,
             prefetch_options=prefetch_options, agg_domains=agg_domains,
             state_stores=state_stores, state_windows=state_windows,
             mesh_shapes=mesh_shapes, collectives=collectives,
@@ -1044,15 +988,11 @@ class Fedavg:
                                  "winner": plan.as_dict(),
                                  "winner_id": plan.plan_id})
                     return plan, prov
-                # The cached winner is no longer in THIS run's legal
-                # space: the fingerprint can't see sweep-level window
-                # context (max_rounds / checkpoint_freq shape the
-                # eligible scan windows), so a winner tuned under one
-                # round budget could carry a rounds_per_dispatch that
-                # overshoots another run's stop criterion or skips its
-                # checkpoint boundaries.  Re-tune (and overwrite below)
-                # rather than apply a plan the current constraints
-                # forbid.
+                # The cached winner is not in THIS run's legal space
+                # (the fingerprint does not see everything that shapes
+                # it, e.g. which knobs were set explicitly): re-tune,
+                # and overwrite below, rather than apply a plan the
+                # current constraints forbid.
                 cache_stale = True
         measure = (at.timed_measure_fn(cfg) if at.timing_available()
                    else None)
@@ -1242,8 +1182,8 @@ class Fedavg:
         return raw_metrics
 
     def train(self) -> Dict:
-        """One training dispatch (= ``rounds_per_dispatch`` FL rounds, 1 by
-        default) + periodic eval, returns the last round's result dict.
+        """One FL round: dispatch it, fetch its metrics, evaluate where
+        the cadence fires, return its result row.
 
         The call is the ``blades/round`` span; inside it
         ``training_step`` (dispatch + fetch) holds the round body's
@@ -1254,23 +1194,11 @@ class Fedavg:
         after the round's spans close, so a row's ``timers`` minus the
         previous row's is that round's phase times."""
         with self.timers.span("blades/round", step=self._iteration):
-            row = self._train_raw(fetch=True, finalize=True)
+            row = self._train_raw()
         row["timers"] = self.timers.summary()
         return row
 
-    def train_raw(self) -> Dict:
-        """One training dispatch WITHOUT the host sync on round-scalar
-        metrics: the returned row carries its device metrics under
-        ``perf.async_metrics.DEVICE_METRICS_KEY`` and must be passed
-        through :meth:`finalize_row` (or ``perf.flush_rows``, which
-        batches the ``device_get`` across rows) before it is consumed.
-        The async sweep loop (``metrics_every > 1``) drives this.  Such
-        a row's ``timers`` is the snapshot at dispatch: its
-        ``blades/round`` and ``blades/row`` lag by that round's."""
-        with self.timers.span("blades/round", step=self._iteration):
-            return self._train_raw(fetch=False)
-
-    def _train_raw(self, fetch: bool, finalize: bool = False) -> Dict:
+    def _train_raw(self) -> Dict:
         cycle_t0 = now() if self._async is not None else None
         with self.timers.time("training_step"):
             if self._async is not None:
@@ -1284,14 +1212,6 @@ class Fedavg:
                     self.state, self._train_arrays, self.malicious)
             elif self._state_pf is not None:
                 raw_metrics = self._windowed_round()
-            elif self._chained:
-                # The window program advances the key chain itself, one
-                # split per scanned round — handing back the carry a
-                # sequential driver would hold at the same round.
-                self.state, self._key, raw_metrics = self._step(
-                    self.state, *self._train_arrays, self.malicious,
-                    self._key
-                )
             elif self._prefetcher is not None:
                 round_key, self._key = jax.random.split(self._key)
                 # Staged last dispatch (or drawn now on the first); the
@@ -1302,40 +1222,38 @@ class Fedavg:
                 self.state, raw_metrics = self._step(
                     self.state, bx, by, self.malicious, round_key
                 )
-                self._prefetcher.stage(self._iteration + self._chunk,
+                self._prefetcher.stage(self._iteration + 1,
                                        jax.random.split(self._key)[0])
             else:
                 round_key, self._key = jax.random.split(self._key)
                 self.state, raw_metrics = self._step(
                     self.state, *self._train_arrays, self.malicious, round_key
                 )
-            if fetch:
-                # The fetch sits inside the timer: the dispatch is
-                # asynchronous, so the span otherwise times the enqueue.
-                with self.timers.span("blades/fetch"):
-                    raw_metrics = jax.device_get(raw_metrics)
-        self._iteration += self._chunk
-        self._rounds_since_eval += self._chunk
+            # The fetch sits inside the timer: the dispatch is
+            # asynchronous, so the span otherwise times the enqueue.
+            with self.timers.span("blades/fetch"):
+                raw_metrics = jax.device_get(raw_metrics)
+        self._iteration += 1
+        self._rounds_since_eval += 1
         with self.timers.span("blades/row"):
-            row = self._new_row(raw_metrics, cycle_t0)
-            if finalize:
-                self._fill_round_metrics(
-                    row, row.pop(DEVICE_METRICS_KEY), idx=None)
+            row = self._new_row(cycle_t0)
+            self._fill_round_metrics(row, raw_metrics)
         return row
 
-    def _new_row(self, raw_metrics, cycle_t0) -> Dict:
-        """The row as ``_train_raw`` stamps it at dispatch time: host
-        counters, and the evaluation where its cadence fires."""
+    def _new_row(self, cycle_t0) -> Dict:
+        """The row before the fetched metrics fill it: host counters,
+        and the evaluation where its cadence fires."""
+        # "timers" as of the dispatch and fetch: what the control
+        # driver's watchdog reads; train() takes it again once the
+        # round's spans have closed.
         row = {
             "training_iteration": self._iteration,
-            DEVICE_METRICS_KEY: raw_metrics,
             "timers": self.timers.summary(),
         }
         if self._async is not None:
-            # Host-side ingest digest (blades_tpu/arrivals): stamped at
-            # row creation — these are host ints the engine already
-            # holds, no device fetch to defer.  updates_per_sec is the
-            # one wall-clock field (the bench's ingest metric), measured
+            # Host-side ingest digest (blades_tpu/arrivals): host ints
+            # the engine already holds.  updates_per_sec is the
+            # one wall-clock field (the ingest rate), measured
             # through the span layer's sanctioned clock; everything else
             # is deterministic and replay-comparable.
             info = self._async.last_info
@@ -1355,22 +1273,10 @@ class Fedavg:
             # arrival count — host ints, replay-comparable.
             row["cycle_ticks"] = int(info["cycle_ticks"])
             row["arrivals_quarantined"] = int(info["arrivals_quarantined"])
-            # Event cohort: lane i of this cycle's diag/metrics lanes is
-            # registered client last_clients[i].  Captured NOW so a
-            # deferred row keeps its own cohort after later cycles
-            # overwrite the engine's last_* columns.
-            row[_COHORT_KEY] = (
-                np.asarray(self._async.last_clients, np.int64),
-                np.asarray(self._async.last_staleness, np.int64))
-        elif self._state_pf is not None and self._window_prev is not None:
-            # Sampled window cohort: lane i diagnoses registered client
-            # _window_prev[0][i] (set by the round that just ran).
-            row[_COHORT_KEY] = (
-                np.asarray(self._window_prev[0], np.int64), None)
         if self._state_store is not None:
             # Participation-window staging digest (blades_tpu/state):
-            # host counters the staging layer already holds — no device
-            # fetch to defer.  state_peak_hbm_bytes is the analytic
+            # host counters the staging layer already holds.
+            # state_peak_hbm_bytes is the analytic
             # ceiling on device-resident per-client state (store-held
             # bytes + the staged/live/write-back cohort slots) — the
             # number the memory-ceiling acceptance test pins against a
@@ -1386,8 +1292,8 @@ class Fedavg:
             row["state_peak_hbm_bytes"] = int(stats.peak_hbm_bytes)
         if self._data_store is not None:
             # Out-of-core data staging digest (blades_tpu/data): host
-            # counters the DataPrefetcher already holds — no device
-            # fetch to defer.  data_bytes_staged is the LAST cohort/
+            # counters the DataPrefetcher already holds.
+            # data_bytes_staged is the LAST cohort/
             # event gather's device-put volume, the number the 1M
             # acceptance test pins against a cohort-proportional bound.
             dstats = self._data_pf.stats
@@ -1402,8 +1308,9 @@ class Fedavg:
                 w.stats["hits"] for w in self._cache_wrappers)
             row["compile_cache_misses"] = sum(
                 w.stats["misses"] for w in self._cache_wrappers)
-        # Rounds-since-last-eval cadence: robust to rounds_per_dispatch not
-        # dividing evaluation_interval (a modulo test would then never fire).
+        # Rounds since the last evaluation, not a modulo of the round
+        # number: a restored trial keeps its cadence (the counter is
+        # checkpointed).
         if self.config.evaluation_interval and (
             self._rounds_since_eval >= self.config.evaluation_interval
         ):
@@ -1413,49 +1320,32 @@ class Fedavg:
             row.update(self._last_eval)
         return row
 
-    def finalize_row(self, row: Dict) -> Dict:
-        """Convert a (possibly deferred) row's device metrics into the
-        host-scalar result dict ``train()`` has always returned.  "lane_"
-        keys are per-lane forensics vectors (``(n,)``, stacked to
-        ``(rounds, n)`` under ``rounds_per_dispatch``) — kept whole, last
-        round reported."""
-        raw = row.pop(DEVICE_METRICS_KEY, None)
-        if raw is None:
-            return row
-        with self.timers.span("blades/fetch"):
-            raw = jax.device_get(raw)
-        with self.timers.span("blades/row"):
-            self._fill_round_metrics(row, raw, idx=None)
-        return row
+    def _round_cohort(self):
+        """The cohort of the round that just ran: ``(ids, staleness)``,
+        lane ``i`` of its diag/metrics lanes being registered client
+        ``ids[i]``; ``(None, None)`` on the full-participation round
+        (the identity arange)."""
+        if self._async is not None:
+            return (np.asarray(self._async.last_clients, np.int64),
+                    np.asarray(self._async.last_staleness, np.int64))
+        if self._state_pf is not None and self._window_prev is not None:
+            return np.asarray(self._window_prev[0], np.int64), None
+        return None, None
 
-    def _fill_round_metrics(self, row: Dict, raw: Dict, idx) -> None:
-        """Fill ``row`` with the host form of the fetched metrics dict.
-
-        ``idx=None``: the classic dispatch summary — scalars from the
-        chunk's LAST round, health counts reduced over the whole chunk (a
-        lane that went non-finite mid-chunk must surface even if it
-        recovered by the last round).  ``idx=r``: round ``r``'s values
-        from a stacked multi-round dispatch (the per-round rows of the
-        sweep's scan-window path)."""
-        # The round's cohort id-vector (+ per-event staleness on async
-        # rows): stamped by _train_raw on the cohort-varying paths,
-        # identity arange on the dense full-participation round.
-        cohort_ids, cohort_staleness = row.pop(_COHORT_KEY, (None, None))
+    def _fill_round_metrics(self, row: Dict, raw: Dict) -> None:
+        """Fill ``row`` with the host form of the round's fetched
+        metrics dict.  "lane_" keys are per-lane forensics vectors
+        (``(n,)``), kept whole."""
+        cohort_ids, cohort_staleness = self._round_cohort()
         metrics, lanes, counters = {}, {}, {}
         for k, v in raw.items():
             a = np.asarray(v)
             if k.startswith("lane_"):
-                if a.ndim > 1:
-                    a = a[-1 if idx is None else idx]
                 lanes[k[len("lane_"):]] = a
             elif k.startswith("counter_"):
                 # A task's own row counters (parallel/streamed.py), under
                 # their schema-registered names: an int stays an int.
-                if a.ndim:
-                    a = a[-1 if idx is None else idx]
                 counters[k[len("counter_"):]] = a.item()
-            elif a.ndim:
-                metrics[k] = float(a[-1 if idx is None else idx])
             else:
                 metrics[k] = float(a)
         row["train_loss"] = metrics["train_loss"]
@@ -1556,9 +1446,8 @@ class Fedavg:
             row["finish_stripe_cols"] = int(metrics["finish_stripe_cols"])
         row.update(counters)
         if self.config.fault_config:  # chaos layer (blades_tpu/faults)
-            # Participation is per round; the dispatch summary reports the
-            # LAST round (consistent with the scalar metrics above) plus
-            # the static fault seed so a chaos run's stream is replayable.
+            # The round's participation counts, plus the static fault
+            # seed so a chaos run's stream is replayable.
             # Async cycles carry no participation mask (dropped arrivals
             # never enter the buffer; the drop counter rides the
             # arrival stamps instead), so only the seed lands here.
@@ -1574,13 +1463,9 @@ class Fedavg:
             row["staleness_mean"] = float(metrics["staleness_mean"])
             row["staleness_max"] = int(metrics["staleness_max"])
         if self.config.health_check or self.config.forensics:
-            u = np.asarray(raw["num_unhealthy"])
-            row["num_unhealthy"] = int(u.sum() if idx is None
-                                       else (u[idx] if u.ndim else u))
+            row["num_unhealthy"] = int(raw["num_unhealthy"])
         if self.config.health_check:  # failure-detection metrics (health.py)
-            ok = np.asarray(raw["round_ok"])
-            row["round_ok"] = bool(ok.all() if idx is None
-                                   else (ok[idx] if ok.ndim else ok))
+            row["round_ok"] = bool(raw["round_ok"])
         if self.config.forensics:  # defense forensics (obs subsystem)
             for k in ("byz_precision", "byz_recall", "byz_fpr"):
                 row[k] = metrics[k]
@@ -1714,37 +1599,6 @@ class Fedavg:
                           RuntimeWarning, stacklevel=2)
             return
         self._setup_dense_pipeline()
-
-    def train_rows(self, per_round: bool = False) -> List[Dict]:
-        """One training dispatch, returned as result ROWS.
-
-        ``per_round=False`` (or a single-round dispatch): exactly
-        ``[self.train()]``.  ``per_round=True`` with
-        ``rounds_per_dispatch > 1`` expands the dispatch's stacked
-        metrics into one row per FL round — the sweep's scan-window
-        path: per-round granularity on disk, ONE program dispatch and
-        ONE batched ``device_get`` per window.  Rows before the window's
-        final round carry the previous evaluation (the same
-        repeat-last-eval convention as sequential rows); the final row
-        carries whatever :meth:`_train_raw` attached (fresh eval when
-        the cadence fired)."""
-        if not per_round or self._chunk == 1:
-            return [self.train()]
-        prev_eval = dict(self._last_eval)
-        start = self._iteration
-        tail = self._train_raw(fetch=True)
-        raw = tail.pop(DEVICE_METRICS_KEY)
-        shared = {k: tail[k] for k in ("timers", "compile_cache_hits",
-                                       "compile_cache_misses") if k in tail}
-        eval_keys = {k: tail[k] for k in ("test_loss", "test_acc",
-                                          "test_acc_top3") if k in tail}
-        rows = []
-        for r in range(self._chunk):
-            row = {"training_iteration": start + r + 1, **shared}
-            self._fill_round_metrics(row, raw, idx=r)
-            row.update(eval_keys if r == self._chunk - 1 else prev_eval)
-            rows.append(row)
-        return rows
 
     def evaluate(self) -> Dict:
         """Weighted per-client evaluation (ref: fedavg.py:247-279)."""
@@ -1923,6 +1777,12 @@ class Fedavg:
         self._iteration = payload["iteration"]
         self._rounds_since_eval = payload.get("rounds_since_eval", 0)
         saved_plan = payload.get("plan")
+        if saved_plan is not None:
+            # Through the parser: a plan this build cannot run (an old
+            # checkpoint's dispatch window) is refused here, by name.
+            from blades_tpu.perf.autotune import Plan
+
+            saved_plan = Plan.from_dict(saved_plan).as_dict()
         cur_plan = self._plan.as_dict() if self._plan is not None else None
         if saved_plan is not None and saved_plan != cur_plan:
             # Plan drift on resume: this instance resolved a different

@@ -40,7 +40,7 @@ REFERENCE_MALICIOUS_FRACS = [0.0, 0.1, 0.2, 0.3]
 
 
 def run_cell(dataset, model, aggregator, num_malicious, adversary, rounds,
-             seed, num_clients, chunk, iid=True, alpha=0.1,
+             seed, num_clients, iid=True, alpha=0.1,
              synthetic_noise=0.5, synthetic_heterogeneity=0.0,
              client_lr=0.1, server_lr=1.0,
              batch_size=None, compute_dtype=None):
@@ -78,7 +78,6 @@ def run_cell(dataset, model, aggregator, num_malicious, adversary, rounds,
         )
         .evaluation(evaluation_interval=max(rounds // 4, 1))
     )
-    cfg.rounds_per_dispatch = chunk
     if compute_dtype:
         cfg = cfg.resources(compute_dtype=compute_dtype)
     algo = cfg.build()
@@ -110,7 +109,6 @@ def main(argv=None) -> int:
                    "'{\"type\": \"IPM\", \"scale\": 100.0}'")
     p.add_argument("--aggregators", nargs="+", default=DEFAULT_AGGREGATORS)
     p.add_argument("--malicious", nargs="+", type=int, default=DEFAULT_MALICIOUS)
-    p.add_argument("--rounds-per-dispatch", type=int, default=10)
     p.add_argument("--out", default="curves_out")
     p.add_argument("--seed", type=int, default=122)
     p.add_argument("--noniid-alpha", type=float, default=None,
@@ -242,7 +240,6 @@ def main(argv=None) -> int:
             t0 = now()
             row = run_cell(args.dataset, model, agg, m, args.adversary,
                            args.rounds, args.seed, args.num_clients,
-                           args.rounds_per_dispatch,
                            iid=args.noniid_alpha is None,
                            alpha=args.noniid_alpha or 0.1,
                            synthetic_noise=args.synthetic_noise,

@@ -572,7 +572,7 @@ class FedRound:
             if not self.health_check:
                 metrics["num_unhealthy"] = (~healthy_mask).sum()
             # Per-lane bundle (prefix "lane_"): hosts split these from the
-            # scalar metrics.  f32 so lax.scan stacking stays uniform.
+            # scalar metrics.  One dtype (f32) for the whole bundle.
             metrics["lane_benign_mask"] = diag["benign_mask"].astype(jnp.float32)
             metrics["lane_scores"] = diag["scores"].astype(jnp.float32)
             metrics["lane_healthy"] = healthy_mask.astype(jnp.float32)
@@ -666,63 +666,6 @@ class FedRound:
             arrivals=getattr(state, "arrivals", None),
             cohort=getattr(state, "cohort", None),
         ), metrics
-
-    def multi_step(
-        self,
-        state: RoundState,
-        data_x: jax.Array,
-        data_y: jax.Array,
-        lengths: jax.Array,
-        malicious: jax.Array,
-        key: jax.Array,
-        num_rounds: int,
-    ) -> Tuple[RoundState, dict]:
-        """``num_rounds`` FL rounds as ONE ``lax.scan``-ed XLA program.
-
-        The hot-loop form: host dispatch is paid once per chunk instead
-        of once per round.  Metrics come back stacked ``(num_rounds, ...)``.
-        Jit with ``static_argnums`` on ``num_rounds`` or wrap in a
-        functools.partial.
-        """
-
-        def body(st, k):
-            return self.step(st, data_x, data_y, lengths, malicious, k)
-
-        keys = jax.random.split(key, num_rounds)
-        return jax.lax.scan(body, state, keys)
-
-    def multi_step_chained(
-        self,
-        state: RoundState,
-        data_x: jax.Array,
-        data_y: jax.Array,
-        lengths: jax.Array,
-        malicious: jax.Array,
-        key: jax.Array,
-        num_rounds: int,
-    ) -> Tuple[RoundState, jax.Array, dict]:
-        """:meth:`multi_step` with the DRIVER's key discipline: ``key`` is
-        the host loop's carry, and each scanned round consumes
-        ``round_key, carry = split(carry)`` — exactly what the sequential
-        driver does once per ``train()`` call.  Round ``r`` therefore
-        sees the identical PRNG key it would under round-per-dispatch
-        execution, making the windowed rounds bit-identical to eager
-        ones (which :meth:`multi_step`'s ``split(key, num_rounds)`` fan
-        is not).  Returns ``(state, advanced_carry, stacked_metrics)``;
-        the caller replaces its key chain with ``advanced_carry``, so a
-        checkpoint taken after a window matches a sequential checkpoint
-        at the same round, key and all."""
-
-        def body(carry, _):
-            st, ck = carry
-            rk, ck = jax.random.split(ck)
-            st, m = self.step(st, data_x, data_y, lengths, malicious, rk)
-            return (st, ck), m
-
-        (state, key), metrics = jax.lax.scan(
-            body, (state, key), None, length=num_rounds
-        )
-        return state, key, metrics
 
     def compute_trusted_update(self, global_params, key) -> Optional[jax.Array]:
         """The server's own local round on its clean root data (FLTrust's

@@ -123,8 +123,7 @@ class CsvSink(Sink):
 
 class StdoutSink(Sink):
     """Heartbeat: one line every ``every`` ROUNDS (by the record's
-    ``training_iteration`` — one record can advance several rounds under
-    ``rounds_per_dispatch``; falls back to record count when absent) and
+    ``training_iteration``; falls back to record count when absent) and
     always the first, so a long sweep shows life without drowning the
     console."""
 

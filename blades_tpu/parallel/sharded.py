@@ -79,22 +79,6 @@ def sharded_step(fr: FedRound, mesh: Mesh, donate: bool = True) -> Callable:
     )
 
 
-def sharded_multi_step(
-    fr: FedRound, mesh: Mesh, num_rounds: int, donate: bool = True
-) -> Callable:
-    """GSPMD-sharded ``FedRound.multi_step``: ``num_rounds`` rounds fused
-    into one dispatch (metrics stacked)."""
-    cs = client_axis_sharding(mesh)
-    rep = replicated_sharding(mesh)
-    st = _state_shardings(mesh)
-    return jax.jit(
-        _gspmd_traced(partial(fr.multi_step, num_rounds=num_rounds)),
-        in_shardings=(st, cs, cs, cs, cs, rep),
-        out_shardings=(st, rep),
-        donate_argnums=(0,) if donate else (),
-    )
-
-
 def sharded_evaluate(fr: FedRound, mesh: Mesh) -> Callable:
     cs = client_axis_sharding(mesh)
     rep = replicated_sharding(mesh)

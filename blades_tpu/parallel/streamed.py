@@ -96,11 +96,10 @@ _COORDWISE_FORGERS = (ALIEAdversary, IPMAdversary, NoiseAdversary,
 _COORDWISE_AGGREGATORS = (Mean, Median, Trimmedmean)
 
 # Canonical streamed-finish chunk width (the historical hard-coded
-# value, now named).  The config default (algorithms/config.py), the
-# bench protocol (bench.py D_CHUNK) and the center of the autotuner's
-# candidate ladder (perf/autotune.py D_CHUNK_LADDER — stdlib-only by
-# design, so it repeats the literal) all pin the same 1 << 17; the
-# autotuner's chunk tests assert the agreement.
+# value, now named).  The config default (algorithms/config.py) and the
+# center of the autotuner's candidate ladder (perf/autotune.py
+# D_CHUNK_LADDER — stdlib-only by design, so it repeats the literal)
+# pin the same 1 << 17; the autotuner's chunk tests assert the agreement.
 DEFAULT_D_CHUNK = 1 << 17
 
 
@@ -265,9 +264,8 @@ def streamed_step(
         fuse_rowgeom: run the row-geometry finish through the fused pass
             planner (default).  ``False`` executes one traversal per
             accumulator request — the pre-fusion baseline the
-            ``BLADES_BENCH_ROWGEOM`` A/B and equivalence tests compare
-            against.  Row-geometry rounds stamp ``hbm_passes`` /
-            ``hbm_passes_unfused`` (planned full-matrix traversals,
+            equivalence tests compare against.  Row-geometry rounds
+            stamp ``hbm_passes`` / ``hbm_passes_unfused`` (planned full-matrix traversals,
             fused plan vs per-request baseline) into the round metrics.
         mxu_finish: config-resolved MXU finish variant for the compact
             fused pallas finish (``""``/``"counts"``/``"all"``; see
@@ -1027,60 +1025,3 @@ def streamed_step(
         step.finish_fused = _finish_fused
         step.finish_fused_compact = _finish_fused_compact
     return step
-
-
-def streamed_multi_step(
-    fr: FedRound,
-    num_rounds: int,
-    chained: bool = False,
-    **kw,
-) -> Callable:
-    """``rounds_per_dispatch`` for the streamed path: chain ``num_rounds``
-    streamed rounds without ANY host synchronization between them.
-
-    The streamed round is a host loop of donated async dispatches, so
-    "one dispatch" cannot mean one XLA program the way the dense
-    ``FedRound.multi_step`` scan does — but the property that matters is
-    the same: the driver never blocks between rounds.  Every training
-    block and finish of all ``num_rounds`` rounds is enqueued
-    back-to-back through the dispatch pipeline (donated buffers chain
-    round r's outputs into round r+1), and the host's metric fetch is
-    paid once per CHAIN, not once per round.
-
-    Same RNG stream as ``multi_step`` (``split(key, num_rounds)``, round
-    r consuming ``keys[r]``), so at f32 storage the chained rounds are
-    bit-identical to both the dense scan and ``num_rounds`` sequential
-    ``streamed_step`` calls.  Metrics come back stacked
-    ``(num_rounds, ...)`` like ``multi_step``'s.  The caller's
-    ``state.client_opt`` is donated (pass ``donate=False`` in ``kw`` to
-    keep it).
-
-    ``chained=True`` switches to the DRIVER's key discipline (see
-    :meth:`~blades_tpu.core.round.FedRound.multi_step_chained`): ``key``
-    is the host carry, each round consumes ``split(carry)``, and the
-    callable returns ``(state, advanced_carry, metrics)`` — the sweep's
-    scan-window mode, bit-identical per round to round-per-dispatch
-    execution.
-    """
-    step = streamed_step(fr, **kw)
-
-    def multi(state: RoundState, data_x, data_y, lengths, malicious, key):
-        if chained:
-            round_keys = []
-            for _ in range(num_rounds):
-                rk, key = jax.random.split(key)
-                round_keys.append(rk)
-        else:
-            round_keys = jax.random.split(key, num_rounds)
-        all_metrics = []
-        for r in range(num_rounds):
-            state, m = step(state, data_x, data_y, lengths, malicious,
-                            round_keys[r])
-            all_metrics.append(m)
-        metrics = jax.tree.map(lambda *vs: jnp.stack(vs), *all_metrics)
-        if chained:
-            return state, key, metrics
-        return state, metrics
-
-    multi.step = step
-    return multi

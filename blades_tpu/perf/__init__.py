@@ -1,6 +1,6 @@
 """Round-pipeline performance layer (host side).
 
-Three coupled pieces that make the *orchestration around* the jitted
+The pieces that make the *orchestration around* the jitted
 round as fast as the round itself (ByzFL arXiv:2505.24802 and
 ring-allreduce Byzantine FL arXiv:2501.17392 both locate the
 robust-FL throughput ceiling here, not in the defense kernels):
@@ -10,20 +10,15 @@ robust-FL throughput ceiling here, not in the defense kernels):
   on abstract shapes/dtypes + a static round-config fingerprint) shared
   across sweep trials and lane groups, plus wiring for JAX's persistent
   compilation cache so repeat sweeps skip XLA entirely.
-- :mod:`blades_tpu.perf.async_metrics` — batched ``device_get`` of
-  per-round scalar metrics every ``metrics_every`` rounds (flushed at
-  checkpoint / preemption / fault boundaries so the chaos layer's
-  replay guarantees hold).
 - :mod:`blades_tpu.data.prefetch` (sibling) — double-buffered
   device staging of the next round's per-client batches.
 - :mod:`blades_tpu.perf.autotune` — the execution autotuner: measured
   plan selection over the round pipeline's perf levers (execution
-  path, streamed ``d_chunk``, lane packing, MXU finish, scan windows,
-  prefetch) with a persistent on-disk plan cache.  See the README
-  "Execution autotuner" section.
+  path, streamed ``d_chunk``, lane packing, MXU finish, prefetch)
+  with a persistent on-disk plan cache.  See the README "Execution
+  autotuner" section.
 """
 
-from blades_tpu.perf.async_metrics import flush_rows  # noqa: F401
 from blades_tpu.perf.autotune import (  # noqa: F401
     Plan,
     PlanCache,

@@ -1,8 +1,8 @@
 """Execution autotuner: measured plan selection for the round pipeline.
 
 The repo accumulated a deep stack of perf levers — ``execution="auto"``,
-``client_packing="auto"``, ``scan_window="auto"``, the streamed
-``d_chunk``, the pallas MXU-finish variants — each resolved by its own
+``client_packing="auto"``, the streamed ``d_chunk``, the pallas
+MXU-finish variants — each resolved by its own
 hand-written heuristic that has never been validated against a
 measurement.  This module replaces that scatter with one measured
 decision, the way XLA-era systems pick tilings: enumerate the legal
@@ -14,8 +14,8 @@ Three pieces:
   candidates derived from the constraints already encoded at validate
   time, partitioned into a **numerics-preserving default tier** (knobs
   the existing equivalence tests prove bit-exact: streamed chunk sizes
-  on chunk-invariant rounds, the bit-exact MXU radix counts, chained
-  scan windows, prefetch) and an opt-in **reassociating tier**
+  on chunk-invariant rounds, the bit-exact MXU radix counts,
+  prefetch) and an opt-in **reassociating tier**
   (dense<->streamed<->packed switches and the ``stats_mxu`` finish,
   which carry the documented float-reassociation tolerances).  A run
   that never opts in can only be handed a plan that reproduces the
@@ -58,7 +58,9 @@ import warnings
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-PLAN_CACHE_VERSION = 1
+# 2: the plan lost its dispatch-window field (a round is one dispatch);
+# entries written with it read as stale and are re-tuned, never applied.
+PLAN_CACHE_VERSION = 2
 ENV_CACHE_DIR = "BLADES_TPU_PLAN_CACHE_DIR"
 _DEFAULT_CACHE_DIR = "~/.cache/blades_tpu/plans"
 
@@ -69,7 +71,7 @@ _DEFAULT_CACHE_DIR = "~/.cache/blades_tpu/plans"
 D_CHUNK_LADDER = (1 << 16, 1 << 17, 1 << 18)
 
 # Enumeration ceiling.  The knob grid is small by construction, but a
-# pathological composition (reassociating tier x windows x ladder) must
+# pathological composition (reassociating tier x stores x ladder) must
 # not turn one trial's tuning into a compile marathon; the drop count is
 # recorded in the provenance so the cap is never silent.
 MAX_CANDIDATES = 32
@@ -92,8 +94,7 @@ class Plan:
     d_chunk: int = 1 << 17            # streamed finish chunk width
     client_packing: int = 1           # clients per grouped-kernel lane
     mxu_finish: str = ""              # "" | "counts" | "all" (streamed)
-    rounds_per_dispatch: int = 1      # chained scan window; 1 = per-round
-    prefetch: bool = False            # dense single-round batch staging
+    prefetch: bool = False            # dense batch staging
     agg_domain: str = "f32"           # "f32" | "wire" (dense + quant codec)
     # Participation-window store (blades_tpu/state): where off-cohort
     # per-client rows live and the pinned cohort size (None = no
@@ -154,9 +155,6 @@ class Plan:
         if int(self.client_packing) < 1:
             raise ValueError(f"plan client_packing must be >= 1, "
                              f"got {self.client_packing}")
-        if int(self.rounds_per_dispatch) < 1:
-            raise ValueError(f"plan rounds_per_dispatch must be >= 1, "
-                             f"got {self.rounds_per_dispatch}")
 
     @property
     def plan_id(self) -> str:
@@ -166,7 +164,6 @@ class Plan:
         return (f"{self.execution}|c{int(self.d_chunk)}"
                 f"|p{int(self.client_packing)}"
                 f"|mxu={self.mxu_finish or 'off'}"
-                f"|w{int(self.rounds_per_dispatch)}"
                 f"|{'pre' if self.prefetch else 'nopre'}"
                 + ("|wire" if self.agg_domain == "wire" else "")
                 # Window-store marker only when engaged: every
@@ -192,6 +189,16 @@ class Plan:
         half-applied."""
         if not isinstance(d, dict):
             raise ValueError(f"plan must be a dict, got {type(d).__name__}")
+        d = dict(d)
+        # Plans written before the dispatch window went (checkpoints,
+        # operator pins) still name it: 1 is what every round does now;
+        # any other window has no program left to run it.
+        window = d.pop("rounds_per_dispatch", 1)
+        if window != 1:
+            raise ValueError(
+                f"plan names rounds_per_dispatch={window!r}: multi-round "
+                "dispatch windows were removed, a train() call is one "
+                "round — drop the field from the pin, or re-tune")
         known = {f.name for f in dataclasses.fields(cls)}
         unknown = sorted(set(d) - known)
         if unknown:
@@ -223,8 +230,7 @@ def apply_plan(config, plan: Plan) -> None:
     if plan.execution == "dense":
         config.client_packing = (int(plan.client_packing)
                                  if plan.client_packing >= 2 else "off")
-        if plan.rounds_per_dispatch == 1:
-            config.prefetch = bool(plan.prefetch)
+        config.prefetch = bool(plan.prefetch)
         # Wire-domain aggregation (dense + deferrable codec only; the
         # plan space never offers "wire" elsewhere, and an explicit
         # user agg_domain pins its list to one entry).
@@ -232,19 +238,6 @@ def apply_plan(config, plan: Plan) -> None:
     else:
         config.client_packing = "off"
         config.mxu_finish = plan.mxu_finish
-    rpd = int(plan.rounds_per_dispatch)
-    prior = int(getattr(config, "rounds_per_dispatch", 1) or 1)
-    config.rounds_per_dispatch = rpd
-    if rpd > 1 and prior != rpd:
-        # The chained key discipline is what makes windowed rows
-        # bit-identical to round-per-dispatch execution (PR 3); every
-        # window the plan space INTRODUCES comes from the sweep's
-        # eligibility gate, which only ever engages chained windows.  A
-        # window the USER pinned (prior == rpd — the plan space never
-        # varies it) keeps the user's own chained_dispatch setting: the
-        # plain multi_step discipline is a legal explicit choice the
-        # tuner must not silently rewrite.
-        config.chained_dispatch = True
 
 
 # ---------------------------------------------------------------------------
@@ -274,7 +267,6 @@ def enumerate_plans(
     d_chunks: Sequence[int],
     mxu_modes: Sequence[str] = ("",),
     pack_factors: Sequence[int] = (1,),
-    scan_windows: Sequence[int] = (1,),
     prefetch_options: Sequence[bool] = (False,),
     agg_domains: Sequence[str] = ("f32",),
     state_stores: Sequence[str] = ("resident",),
@@ -296,7 +288,7 @@ def enumerate_plans(
     aggregating in the quantized wire domain, or enabling the
     ``stats_mxu`` finish ("all") reassociates float reductions and
     lands in :data:`REASSOCIATING_TIER`; chunk sizes, the bit-exact
-    radix counts ("counts"), chained scan windows and prefetch stay
+    radix counts ("counts") and prefetch stay
     :data:`DEFAULT_TIER`.  Without ``allow_reassociating`` the
     reassociating tier is not enumerated at all — an un-opted run can
     never be handed one.  ``agg_domains`` applies to the dense path
@@ -336,67 +328,60 @@ def enumerate_plans(
                             else REASSOCIATING_TIER)
                 if exe == "streamed" and ms is not None:
                     continue  # streamed × mesh does not exist
-                for w in scan_windows:
-                    if coll == "hier" and int(w) != 1:
-                        continue  # hier is dispatched per-round (no scan)
-                    if exe == "streamed":
-                        for dc in d_chunks:
-                            for mxu in mxu_modes:
+                if exe == "streamed":
+                    for dc in d_chunks:
+                        for mxu in mxu_modes:
+                            tier = exe_tier
+                            if mxu == "all" and mxu_modes[0] != "all":
+                                tier = REASSOCIATING_TIER
+                            plans.append(Plan(
+                                execution="streamed", d_chunk=int(dc),
+                                client_packing=1, mxu_finish=mxu,
+                                prefetch=False, tier=tier))
+                    continue
+                for p in pack_factors:
+                    for ad in agg_domains:
+                        for ss in state_stores:
+                            for sw in state_windows:
+                                if coll == "hier" and (
+                                        int(p) != 1 or ad != "f32"
+                                        or sw is not None):
+                                    # packing / wire-domain / window
+                                    # store have no hierarchical
+                                    # formulation
+                                    continue
                                 tier = exe_tier
-                                if mxu == "all" and mxu_modes[0] != "all":
+                                if p != pack_factors[0]:
                                     tier = REASSOCIATING_TIER
-                                plans.append(Plan(
-                                    execution="streamed", d_chunk=int(dc),
-                                    client_packing=1, mxu_finish=mxu,
-                                    rounds_per_dispatch=int(w), prefetch=False,
-                                    tier=tier))
-                    else:
-                        for p in pack_factors:
-                            for ad in agg_domains:
-                                for ss in state_stores:
-                                    for sw in state_windows:
-                                        if coll == "hier" and (
-                                                int(p) != 1 or ad != "f32"
-                                                or sw is not None):
-                                            # packing / wire-domain /
-                                            # window store have no
-                                            # hierarchical formulation
-                                            continue
-                                        tier = exe_tier
-                                        if p != pack_factors[0]:
-                                            tier = REASSOCIATING_TIER
-                                        if ad != agg_domains[0]:
-                                            # Quantized-domain statistics
-                                            # reassociate f32 reductions AND
-                                            # rank on the int8 grid — never a
-                                            # default-tier handout.
-                                            tier = REASSOCIATING_TIER
-                                        if (ss != state_stores[0]
-                                                or sw != state_windows[0]):
-                                            # Store backends are bit-identical,
-                                            # but reshaping the staging pipeline
-                                            # is an opt-in probe (ISSUE 15), not
-                                            # a default-tier handout.
-                                            tier = REASSOCIATING_TIER
-                                        pres = (prefetch_options
-                                                if int(w) == 1
-                                                and coll != "hier"
-                                                else (False,))
-                                        for pre in pres:
-                                            plans.append(Plan(
-                                                execution="dense",
-                                                d_chunk=int(d_chunks[0]),
-                                                client_packing=int(p),
-                                                mxu_finish="",
-                                                rounds_per_dispatch=int(w),
-                                                prefetch=bool(pre),
-                                                agg_domain=str(ad),
-                                                state_store=str(ss),
-                                                state_window=(None if sw is None
-                                                              else int(sw)),
-                                                mesh_shape=ms,
-                                                collective=str(coll),
-                                                tier=tier))
+                                if ad != agg_domains[0]:
+                                    # Quantized-domain statistics
+                                    # reassociate f32 reductions AND rank
+                                    # on the int8 grid — never a
+                                    # default-tier handout.
+                                    tier = REASSOCIATING_TIER
+                                if (ss != state_stores[0]
+                                        or sw != state_windows[0]):
+                                    # Store backends are bit-identical,
+                                    # but reshaping the staging pipeline
+                                    # is an opt-in probe (ISSUE 15), not
+                                    # a default-tier handout.
+                                    tier = REASSOCIATING_TIER
+                                pres = (prefetch_options if coll != "hier"
+                                        else (False,))
+                                for pre in pres:
+                                    plans.append(Plan(
+                                        execution="dense",
+                                        d_chunk=int(d_chunks[0]),
+                                        client_packing=int(p),
+                                        mxu_finish="",
+                                        prefetch=bool(pre),
+                                        agg_domain=str(ad),
+                                        state_store=str(ss),
+                                        state_window=(None if sw is None
+                                                      else int(sw)),
+                                        mesh_shape=ms,
+                                        collective=str(coll),
+                                        tier=tier))
     if not allow_reassociating:
         plans = [p for p in plans if p.tier == DEFAULT_TIER]
     # Dedupe preserving order (e.g. a chunk ladder whose entries clamp
@@ -435,12 +420,8 @@ def timed_measure_fn(
     build: Optional[Callable[[Any], Any]] = None,
 ) -> Callable[[Plan], Optional[float]]:
     """Build the measured-trial function: plan -> median seconds per
-    **FL round** (or ``None`` when the candidate fails to build).
-
-    One ``train()`` dispatch advances ``plan.rounds_per_dispatch``
-    rounds, so the raw dispatch median is divided by the window width —
-    otherwise a w=8 scan-window candidate would measure ~8x a w=1
-    candidate's dispatch and the tuner could never select a window.
+    ``train()`` call, one FL round (or ``None`` when the candidate
+    fails to build).
 
     The candidate config is a copy with ``autotune`` disabled and the
     plan materialised, so it compiles through the PR 3 executable cache
@@ -457,7 +438,6 @@ def timed_measure_fn(
         cand = config.copy()
         cand.autotune = False
         cand.tuned_plan = None
-        cand._autotune_windows = None
         apply_plan(cand, plan)
         algo = None
         try:
@@ -480,8 +460,7 @@ def timed_measure_fn(
         finally:
             if algo is not None and callable(getattr(algo, "stop", None)):
                 algo.stop()
-        return float(statistics.median(times)) / max(
-            1, int(plan.rounds_per_dispatch))
+        return float(statistics.median(times))
 
     return measure
 

@@ -63,17 +63,6 @@ def main(argv=None) -> int:
                         help="skip the per-trial XLA cost analysis (it "
                         "recompiles the training dispatch once — expensive "
                         "for ResNet-scale models on CPU)")
-    common.add_argument("--metrics-every", type=int, default=1, metavar="N",
-                        help="batch the per-round scalar-metric fetch: "
-                        "device_get every N rounds instead of blocking per "
-                        "round (flushed at checkpoint/preemption "
-                        "boundaries; see README Performance)")
-    common.add_argument("--scan-window", default="auto", metavar="W",
-                        help="run eligible trials as multi_step scan "
-                        "windows of up to W rounds per dispatch while "
-                        "keeping one result row per round; 'auto' "
-                        "(default) picks the largest safe window, 1 "
-                        "disables")
     common.add_argument("--autotune", nargs="?", const="on", default=None,
                         choices=("on", "reassociating"),
                         help="execution autotuner (perf/autotune.py): "
@@ -85,7 +74,7 @@ def main(argv=None) -> int:
                         "offers dense<->streamed<->packed switches and the "
                         "stats-MXU finish (documented float tolerances).  "
                         "Explicit knobs (--client-packing, execution, "
-                        "d_chunk, --scan-window N) are never overridden — "
+                        "d_chunk) are never overridden — "
                         "the tuner only resolves what was left at 'auto'; "
                         "see README \"Execution autotuner\"")
     common.add_argument("--plan-cache-dir", default=None, metavar="DIR",
@@ -231,8 +220,6 @@ def main(argv=None) -> int:
                        "are device-resident under a host/disk store")
 
     args = parser.parse_args(argv)
-    scan_window = (args.scan_window if args.scan_window == "auto"
-                   else int(args.scan_window))
 
     # --watchdog-rules: parse + validate BEFORE building experiments so a
     # typo'd rule spec dies here, not 40 minutes into a sweep.  The parsed
@@ -281,8 +268,6 @@ def main(argv=None) -> int:
                 lanes=not args.no_lanes,
                 metrics_csv=args.metrics_csv,
                 cost_analysis=not args.no_cost_analysis,
-                metrics_every=args.metrics_every,
-                scan_window=scan_window,
                 autotune=args.autotune,
                 plan_cache_dir=args.plan_cache_dir,
                 trace_dir=args.trace_dir,
@@ -344,8 +329,6 @@ def main(argv=None) -> int:
                 verbose=args.verbose,
                 metrics_csv=args.metrics_csv,
                 cost_analysis=not args.no_cost_analysis,
-                metrics_every=args.metrics_every,
-                scan_window=scan_window,
                 autotune=args.autotune,
                 plan_cache_dir=args.plan_cache_dir,
                 trace_dir=args.trace_dir,

@@ -72,7 +72,6 @@ def run_lanes(
     lane_overrides: List[Dict],
     max_rounds: int,
     program_key=None,
-    metrics_every: int = 1,
     donate: bool = True,
     tracer=None,
 ) -> List[List[Dict]]:
@@ -91,11 +90,6 @@ def run_lanes(
             through the process-wide AOT executable cache
             (:mod:`blades_tpu.perf`), so identical lane groups compile
             once per process.
-        metrics_every: batch the per-round metric fetch: the host keeps
-            dispatching rounds and ``device_get``\\ s the stacked lane
-            metrics every this-many rounds (flushed at eval rounds'
-            cadence implicitly — eval results ride the same batch — and
-            at the end).  ``1`` reproduces the classic blocking loop.
         donate: donate the lane states into each round dispatch (the
             L-times-stacked client opt states are the group's largest
             buffers); the pre-round states object is consumed.
@@ -281,39 +275,6 @@ def run_lanes(
     interval = base.evaluation_interval
     results: List[List[Dict]] = [[] for _ in range(L)]
     last_eval: List[Dict] = [{} for _ in range(L)]
-    # (round, lane metrics, eval bundle or None), fetched in ONE
-    # device_get per flush so the dispatch pipeline never drains on a
-    # per-round scalar (perf layer; metrics_every=1 == classic loop).
-    pending: List = []
-
-    def flush():
-        nonlocal last_eval
-        if not pending:
-            return
-        with tracer.span("fetch", rows=len(pending)):
-            fetched = jax.device_get([(m, e) for _, m, e in pending])
-        for (r, _, _), (metrics, ev) in zip(pending, fetched):
-            if ev is not None:
-                last_eval = [
-                    {k: float(ev[k][i]) for k in ("test_loss", "test_acc",
-                                                  "test_acc_top3")}
-                    for i in range(L)
-                ]
-            for i in range(L):
-                row = {
-                    "training_iteration": r,
-                    "train_loss": float(metrics["train_loss"][i]),
-                    "agg_norm": float(metrics["agg_norm"][i]),
-                    "update_norm_mean": float(metrics["update_norm_mean"][i]),
-                    "seed": int(seeds[i]),
-                }
-                row.update(comm_row)
-                row.update({k: v for k, v in lane_overrides[i].items()
-                            if k != "seed"})
-                row.update(last_eval[i])
-                results[i].append(row)
-        pending.clear()
-
     for r in range(1, max_rounds + 1):
         round_keys, carry = jnp.moveaxis(jax.vmap(jax.random.split)(carry), 1, 0)
         # The first dispatch pays XLA compilation — same phase split as
@@ -323,10 +284,29 @@ def run_lanes(
             states, metrics = step(states, x, y, ln, mal, round_keys, sc)
             ev = (evaluate(states, tx, ty, tln, sc)
                   if interval and r % interval == 0 else None)
-        pending.append((r, metrics, ev))
-        if len(pending) >= max(1, metrics_every):
-            flush()
-    flush()
+        # The round's stacked lane metrics and its evaluation, where one
+        # ran, come back in one device_get.
+        with tracer.span("fetch"):
+            metrics, ev = jax.device_get((metrics, ev))
+        if ev is not None:
+            last_eval = [
+                {k: float(ev[k][i]) for k in ("test_loss", "test_acc",
+                                              "test_acc_top3")}
+                for i in range(L)
+            ]
+        for i in range(L):
+            row = {
+                "training_iteration": r,
+                "train_loss": float(metrics["train_loss"][i]),
+                "agg_norm": float(metrics["agg_norm"][i]),
+                "update_norm_mean": float(metrics["update_norm_mean"][i]),
+                "seed": int(seeds[i]),
+            }
+            row.update(comm_row)
+            row.update({k: v for k, v in lane_overrides[i].items()
+                        if k != "seed"})
+            row.update(last_eval[i])
+            results[i].append(row)
     return results
 
 

@@ -163,7 +163,6 @@ def _lanes_eligible(spec_run: str, trial: Dict, group: List[int]) -> bool:
         cfg.execution in ("auto", "dense")
         and cfg.num_clients <= 200
         and not cfg.num_devices
-        and int(getattr(cfg, "rounds_per_dispatch", 1)) == 1
     ):
         return False
     if getattr(cfg, "forensics", False):
@@ -228,63 +227,6 @@ def _lanes_eligible(spec_run: str, trial: Dict, group: List[int]) -> bool:
 
 def _trial_name(base: str, idx: int, trial_cfg: Dict) -> str:
     return f"{base}_{idx:05d}"
-
-
-# ---------------------------------------------------------------------------
-# scan windows: multi_step dispatch with per-round rows (perf layer)
-# ---------------------------------------------------------------------------
-
-_SCAN_WINDOW_CAP = 8
-
-
-def _eligible_scan_windows(config, max_rounds: int, checkpoint_freq: int,
-                           cap: int = _SCAN_WINDOW_CAP) -> Tuple[int, ...]:
-    """Every dispatch window ``w`` (``<= cap``, descending, 1 last)
-    whose windowed execution is OBSERVABLY identical to
-    round-per-dispatch: ``w`` must divide the round budget (no
-    overshoot past the stop criterion), the eval interval (evaluations
-    land on the same rounds, against the same state), and the
-    checkpoint frequency (checkpoints can only fire on dispatch
-    boundaries).  Trials where the user pinned ``rounds_per_dispatch``
-    offer no windows (they keep their setting); forensics trials stay
-    sequential (their per-lane bundles are reported per dispatch).
-    The head of this list is the classic ``scan_window="auto"`` pick;
-    the whole list is the execution autotuner's window candidate set.
-    """
-    if int(getattr(config, "rounds_per_dispatch", 1) or 1) != 1:
-        return (1,)
-    if getattr(config, "forensics", False):
-        return (1,)
-    if getattr(config, "state_window", None) is not None \
-            and config.state_window >= 1:
-        # Participation-window trials stay sequential: cohort staging
-        # (store gather/scatter) happens BETWEEN dispatches — a scanned
-        # window would need an in-program store round trip.
-        return (1,)
-    if getattr(config, "num_devices", None):
-        return (1,)
-    if getattr(config, "execution", "auto") not in ("auto", "dense"):
-        return (1,)
-    interval = int(getattr(config, "evaluation_interval", 0) or 0)
-    out = []
-    for w in range(min(cap, max_rounds), 1, -1):
-        if max_rounds % w:
-            continue
-        if interval and interval % w:
-            continue
-        if checkpoint_freq and checkpoint_freq % w:
-            continue
-        out.append(w)
-    out.append(1)
-    return tuple(out)
-
-
-def _auto_scan_window(config, max_rounds: int, checkpoint_freq: int,
-                      cap: int = _SCAN_WINDOW_CAP) -> int:
-    """Largest eligible dispatch window (see
-    :func:`_eligible_scan_windows`); 1 when no window qualifies."""
-    return _eligible_scan_windows(config, max_rounds, checkpoint_freq,
-                                  cap)[0]
 
 
 def _pin_checkpoint_plan(config, tdir: Path) -> None:
@@ -408,8 +350,8 @@ def _latest_checkpoint(tdir: Path) -> Optional[Path]:
 
 def verify_result_rounds(path) -> List[int]:
     """The no-duplicate/no-gap round-sequence check for a trial's
-    ``result.json``: ``training_iteration`` must be strictly increasing
-    with a uniform stride (1, or ``rounds_per_dispatch``).  A resume that
+    ``result.json``: ``training_iteration`` must rise by one a row.  A
+    resume that
     restored a stale checkpoint without truncating, or skipped rounds,
     fails here.  Returns the iteration list on success, raises
     ``ValueError`` otherwise."""
@@ -419,9 +361,7 @@ def verify_result_rounds(path) -> List[int]:
         raise ValueError(f"{path}: rows missing training_iteration")
     if not its:
         return its
-    stride = its[1] - its[0] if len(its) > 1 else 1
-    expected = list(range(its[0], its[0] + stride * len(its), stride))
-    if stride < 1 or its != expected:
+    if its != list(range(its[0], its[0] + len(its))):
         raise ValueError(
             f"{path}: round sequence has duplicates or gaps: {its[:20]}..."
             if len(its) > 20 else
@@ -494,7 +434,6 @@ def _run_lane_group(
     verbose: int,
     metrics_csv: bool = False,
     strict_metrics: bool = True,
-    metrics_every: int = 1,
     trace_dir: Optional[str] = None,
     wd_rules=None,
     flightrec_rounds: int = 0,
@@ -552,7 +491,7 @@ def _run_lane_group(
     results = run_lanes(builder, overrides, max_rounds,
                         program_key=(spec_run.upper(), fingerprint(sig_cfg),
                                      len(overrides)),
-                        metrics_every=metrics_every, tracer=tracer)
+                        tracer=tracer)
     tracer.finish(gspan)
     wall = gspan.duration
     if trace_dir:
@@ -713,8 +652,6 @@ def run_experiments(
     retry_backoff_base: float = 0.5,
     retry_backoff_cap: float = 30.0,
     preempt_after: Optional[int] = None,
-    scan_window="auto",
-    metrics_every: int = 1,
     autotune=None,
     plan_cache_dir: Optional[str] = None,
     trace_dir: Optional[str] = None,
@@ -755,23 +692,9 @@ def run_experiments(
 
     **Round-pipeline perf layer** (:mod:`blades_tpu.perf`):
 
-    - ``scan_window="auto"`` (default): fresh simple sweeps (no
-      resume / retries / preemption hook) run each eligible trial
-      through ``multi_step`` scan windows — one XLA dispatch and ONE
-      batched metric fetch per window of up to ``8`` rounds — while
-      still writing one result row per FL round.  The window is chosen
-      by :func:`_auto_scan_window` so evaluation rounds, checkpoint
-      rounds and the stop criterion are untouched; rows are bit-
-      identical to sequential execution.  Pass an int to cap the window
-      (``1`` disables), or keep user-pinned ``rounds_per_dispatch``
-      trials as-is (they keep their classic one-row-per-dispatch
-      cadence).
-    - ``metrics_every``: for trials that stay round-per-dispatch, defer
-      the per-round scalar fetch and ``device_get`` in batches of this
-      many rows (flushed before every checkpoint save and before the
-      preemption hook fires, so the chaos layer's no-gap replay
-      guarantee holds; rows pending at a crash are simply re-run from
-      the restored checkpoint).
+    - Every trial is a loop of ``algo.train()`` calls: one round
+      dispatched, its metrics fetched, its row written — before the
+      checkpoint that covers it.
     - JAX's persistent compilation cache is always on, so repeat sweeps
       skip XLA entirely: at ``$JAX_COMPILATION_CACHE_DIR`` when set,
       else the fixed ``<checkout>/.jax_cache``
@@ -785,9 +708,7 @@ def run_experiments(
       does not set its own ``autotune`` config — ``True``/``"on"`` for
       the numerics-preserving default tier, ``"reassociating"`` to also
       offer the opt-in tier.  Autotuned trials run sequentially (never
-      laned), own their dispatch window (the sweep hands the eligible
-      chained windows to the plan space instead of pre-resolving
-      ``scan_window="auto"`` itself), stamp plan provenance into their
+      laned), stamp plan provenance into their
       round rows, and surface the full selection record in the summary
       under ``"autotune"``.  Retries and resumes PIN the plan recorded
       in the latest checkpoint (``config.tuned_plan``) so a restored
@@ -872,8 +793,7 @@ def run_experiments(
     from blades_tpu.obs.trace import Timers
     from blades_tpu.obs.watchdog import Watchdog
     from blades_tpu.perf import (cache_stats,
-                                 enable_persistent_compilation_cache,
-                                 flush_rows)
+                                 enable_persistent_compilation_cache)
 
     enable_persistent_compilation_cache()
     wd_rules = _resolve_watchdog(watchdog)
@@ -892,14 +812,6 @@ def run_experiments(
         return bool(getattr(config, "autotune_mode", None))
 
     preempt_hook = PreemptionHook(preempt_after) if preempt_after else None
-    # Scan windows change dispatch boundaries, which is only safe to do
-    # implicitly on a fresh straight-line sweep: resume/retries can land
-    # on a round the window stride would overshoot, and the preemption
-    # hook's kill window is defined against per-round dispatches.
-    windows_ok = (scan_window not in (1, None, False) and not resume
-                  and max_failures == 0 and preempt_after is None)
-    window_cap = (_SCAN_WINDOW_CAP if scan_window == "auto"
-                  else int(scan_window or 1))
 
     root = Path(storage_path).expanduser()
     summaries = []
@@ -923,7 +835,6 @@ def run_experiments(
                         spec["run"], trials, group, max_rounds, exp_name,
                         root, verbose, metrics_csv=metrics_csv,
                         strict_metrics=strict_metrics,
-                        metrics_every=metrics_every,
                         trace_dir=trace_dir, wd_rules=wd_rules,
                         flightrec_rounds=flightrec_rounds,
                     ))
@@ -983,33 +894,12 @@ def run_experiments(
             algo_cls, config = get_algorithm_class(spec["run"], return_config=True)
             config.update_from_dict(trial_cfg)
             autotuned = _apply_autotune(config)
-            scan_w = (_auto_scan_window(config, max_rounds, checkpoint_freq,
-                                        window_cap) if windows_ok else 1)
-            if autotuned:
-                # The execution autotuner owns the dispatch window for
-                # this trial: hand it the whole eligible set instead of
-                # pre-resolving scan_window="auto" here, and read the
-                # effective window off the resolved plan after build.
-                if windows_ok:
-                    config._autotune_windows = _eligible_scan_windows(
-                        config, max_rounds, checkpoint_freq, window_cap)
-                scan_w = 1
-            elif scan_w > 1:
-                # Windowed dispatch with the driver's key discipline
-                # (chained_dispatch): rows stay bit-identical to
-                # round-per-dispatch execution, checkpoints included.
-                config.rounds_per_dispatch = scan_w
-                config.chained_dispatch = True
             cache_before = cache_stats()
             if resume and autotuned:
                 # Replay the checkpointed plan, never re-tune a
                 # restored trajectory (see _pin_checkpoint_plan).
                 _pin_checkpoint_plan(config, tdir)
             algo = config.build()
-            if autotuned:
-                plan = getattr(algo, "plan", None)
-                if plan is not None:
-                    scan_w = int(plan.rounds_per_dispatch)
             resumed_from = None
             if resume:
                 ckpt = _latest_checkpoint(tdir)
@@ -1080,81 +970,60 @@ def run_experiments(
                     logger = MetricsLogger(
                         sinks, base={"experiment": exp_name, "trial": tname}
                     )
-                    # Deferred-fetch mode (perf layer): rows keep their
-                    # scalar metrics on device and are flushed through ONE
-                    # batched device_get every `metrics_every` rows — and
-                    # unconditionally before checkpoint saves and the
-                    # preemption hook, so every round a checkpoint covers
-                    # is on disk first (the no-gap replay guarantee).
-                    defer = (metrics_every > 1 and scan_w <= 1
-                             and hasattr(algo, "train_raw")
-                             and hasattr(algo, "finalize_row"))
-                    per_round_rows = scan_w > 1 and hasattr(algo, "train_rows")
-                    pending: List[Dict] = []
                     # last_row deliberately NOT reset per attempt: a retry
                     # that restores at the stop round emits no new rows,
                     # and the checkpoint-score / comm summaries below must
                     # still see the last row the trial produced.
                     with open(tdir / "result.json", mode) as f:
 
-                        def emit(rows):
+                        def emit(result):
                             nonlocal best_acc, last_row
-                            for result in rows:
-                                result["trial"] = tname
-                                row = _jsonable(result)
-                                if "watchdog_events" in row:
-                                    # Controlled driver (blades_tpu/
-                                    # control): it owns its own watchdog
-                                    # and stamped the events — observing
-                                    # again would double-fire the
-                                    # rolling rules.
-                                    events = list(
-                                        row["watchdog_events"] or [])
-                                else:
-                                    events = [
-                                        e.as_dict() for e in
-                                        (wd.observe(row)
-                                         if wd is not None else [])]
-                                    if events:
-                                        row["watchdog_events"] = events
-                                f.write(json.dumps(row) + "\n")
-                                logger.log(row)
-                                if flightrec is not None:
-                                    flightrec.record(row)
-                                    trig = flightrec.check(row)
-                                    if trig is None and events:
-                                        trig = {
-                                            "kind": "watchdog",
-                                            "rules": [e["rule"]
-                                                      for e in events],
-                                            "round": row.get(
-                                                "training_iteration"),
-                                        }
-                                    if trig is not None:
-                                        flightrec.dump(trig)
-                                if trace_dir:
-                                    # Round provenance onto the span
-                                    # that dispatched this row (the
-                                    # first dispatch is the "compile"
-                                    # span).
-                                    timers.stamp_latest_of(
-                                        ("round", "compile"),
-                                        {k: row[k]
-                                         for k in _TRACE_ROW_ATTRS
-                                         if k in row})
-                                best_acc = max(best_acc,
-                                               result.get("test_acc", 0.0))
-                                last_row = result
+                            result["trial"] = tname
+                            row = _jsonable(result)
+                            if "watchdog_events" in row:
+                                # Controlled driver (blades_tpu/
+                                # control): it owns its own watchdog
+                                # and stamped the events — observing
+                                # again would double-fire the
+                                # rolling rules.
+                                events = list(
+                                    row["watchdog_events"] or [])
+                            else:
+                                events = [
+                                    e.as_dict() for e in
+                                    (wd.observe(row)
+                                     if wd is not None else [])]
+                                if events:
+                                    row["watchdog_events"] = events
+                            f.write(json.dumps(row) + "\n")
+                            logger.log(row)
+                            if flightrec is not None:
+                                flightrec.record(row)
+                                trig = flightrec.check(row)
+                                if trig is None and events:
+                                    trig = {
+                                        "kind": "watchdog",
+                                        "rules": [e["rule"]
+                                                  for e in events],
+                                        "round": row.get(
+                                            "training_iteration"),
+                                    }
+                                if trig is not None:
+                                    flightrec.dump(trig)
+                            if trace_dir:
+                                # Round provenance onto the span
+                                # that dispatched this row (the
+                                # first dispatch is the "compile"
+                                # span).
+                                timers.stamp_latest_of(
+                                    ("round", "compile"),
+                                    {k: row[k]
+                                     for k in _TRACE_ROW_ATTRS
+                                     if k in row})
+                            best_acc = max(best_acc,
+                                           result.get("test_acc", 0.0))
+                            last_row = result
 
-                        def flush_pending():
-                            nonlocal pending
-                            if pending:
-                                emit(flush_rows(pending, algo.finalize_row))
-                                pending = []
-
-                        # Stop on training_iteration (actual FL rounds), not
-                        # train() calls — one call advances
-                        # rounds_per_dispatch rounds.
                         while algo.iteration < max_rounds:
                             # The first dispatch pays XLA compilation; split
                             # it from steady-state rounds so neither timing
@@ -1164,24 +1033,12 @@ def run_experiments(
                             with timers.time("round" if compiled
                                              else "compile",
                                              step=algo.iteration):
-                                if per_round_rows:
-                                    rows = algo.train_rows(per_round=True)
-                                elif defer:
-                                    rows = None
-                                    pending.append(algo.train_raw())
-                                else:
-                                    rows = [algo.train()]
+                                result = algo.train()
                             compiled = True
-                            if rows is not None:
-                                emit(rows)
-                            elif (len(pending) >= metrics_every
-                                  or algo.iteration >= max_rounds):
-                                flush_pending()
+                            emit(result)
                             checkpoint_due = bool(
                                 checkpoint_freq
                                 and algo.iteration % checkpoint_freq == 0)
-                            if preempt_hook is not None or checkpoint_due:
-                                flush_pending()
                             if preempt_hook is not None:
                                 # Fires BETWEEN the row write and the
                                 # checkpoint save — the widest window a
@@ -1191,11 +1048,11 @@ def run_experiments(
                             if checkpoint_due:
                                 # The no-gap contract ("every round a
                                 # checkpoint covers is on disk first")
-                                # needs the rows DURABLE, not just out of
-                                # the deferred buffer: the checkpoint
-                                # below is fsynced, so a kill right after
-                                # it must not find these rows still in
-                                # the userspace file buffer.
+                                # needs the rows DURABLE, not just
+                                # written: the checkpoint below is
+                                # fsynced, so a kill right after it must
+                                # not find these rows still in the
+                                # userspace file buffer.
                                 f.flush()
                                 os.fsync(f.fileno())
                                 name = f"ckpt_{algo.iteration:06d}"
@@ -1207,7 +1064,6 @@ def run_experiments(
                                                  algo.iteration)
                                 )
                                 _prune_checkpoints(tdir, checkpoint_keep_num, ckpt_scores)
-                        flush_pending()
                     break
                 except KeyboardInterrupt:
                     raise
@@ -1386,8 +1242,6 @@ def run_experiments(
                     "dumps": flightrec.dumps,
                     "path": str(tdir / "flightrec.json"),
                 }
-            if scan_w > 1:
-                summary["scan_window"] = scan_w
             plan_summary = getattr(algo, "plan_summary", None)
             if plan_summary:
                 # Execution-autotuner provenance (perf/autotune.py):

@@ -12,7 +12,7 @@ def test_accuracy_curves_one_command(tmp_path):
     rc = main([
         "--dataset", "mnist", "--rounds", "6", "--num-clients", "8",
         "--aggregators", "Mean", "Median", "--malicious", "0", "2",
-        "--rounds-per-dispatch", "3", "--out", str(tmp_path),
+        "--out", str(tmp_path),
     ])
     assert rc == 0
     table = json.loads((tmp_path / "curves.json").read_text())
@@ -41,13 +41,13 @@ def test_resume_from_completes_a_grid(tmp_path):
     first = tmp_path / "a"
     rc = main(["--dataset", "mnist", "--rounds", "4", "--num-clients", "8",
                "--aggregators", "Mean", "--malicious", "0", "2",
-               "--rounds-per-dispatch", "2", "--out", str(first)])
+               "--out", str(first)])
     assert rc == 0
 
     second = tmp_path / "b"
     rc = main(["--dataset", "mnist", "--rounds", "4", "--num-clients", "8",
                "--aggregators", "Mean", "Median", "--malicious", "0", "2",
-               "--rounds-per-dispatch", "2", "--out", str(second),
+               "--out", str(second),
                "--resume-from", str(first / "curves.json")])
     assert rc == 0
     table = json.loads((second / "curves.json").read_text())

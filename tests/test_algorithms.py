@@ -237,22 +237,6 @@ def test_auto_augment_disabled_on_synthetic_fallback():
     assert algo.fed_round.task.spec.augment is None
 
 
-def test_rounds_per_dispatch_chunked_driver():
-    cfg = tiny_config()
-    cfg.rounds_per_dispatch = 5
-    cfg.evaluation_interval = 5
-    algo = cfg.build()
-    r = algo.train()
-    assert r["training_iteration"] == 5
-    assert "test_acc" in r  # eval fired at iteration 5
-    r = algo.train()
-    assert r["training_iteration"] == 10
-
-
-# Driver-level duplicate of tests/test_streamed.py's streamed-vs-dense
-# fixture (which keeps a tier-1 arm); ~6 s of repeat compile rides the
-# slow lane (PR 20 budget rebalance).
-@pytest.mark.slow
 def test_streamed_execution_matches_dense():
     """execution='streamed' with f32 storage reproduces the dense path
     bit-for-bit through the full Fedavg API (parallel/streamed.py's
@@ -286,17 +270,20 @@ def test_streamed_execution_matches_dense():
 
 
 def test_streamed_execution_validation():
-    import pytest
-
-    # rounds_per_dispatch > 1 is SUPPORTED on the streamed path since r4
-    # (streamed_multi_step chains the rounds with no host sync).
-    _, cfg = get_algorithm_class("FEDAVG", return_config=True)
-    cfg.update_from_dict({"execution": "streamed", "rounds_per_dispatch": 4})
-    cfg.validate()
     _, cfg = get_algorithm_class("FEDAVG", return_config=True)
     cfg.update_from_dict({"execution": "bogus"})
     with pytest.raises(ValueError, match="execution"):
         cfg.validate()
+
+
+@pytest.mark.parametrize("key, value", [("rounds_per_dispatch", 4),
+                                        ("chained_dispatch", True)])
+def test_removed_dispatch_keys_are_unknown(key, value):
+    """A round is one dispatch: a YAML or dict that still names a
+    multi-round dispatch option gets what any unknown key gets."""
+    _, cfg = get_algorithm_class("FEDAVG", return_config=True)
+    with pytest.raises(KeyError, match=f"unknown config key '{key}'"):
+        cfg.update_from_dict({key: value})
 
 
 def test_evaluation_num_samples_caps_test_shards():
@@ -347,31 +334,6 @@ def test_dsharded_execution_requires_mesh():
     cfg.update_from_dict({"execution": "dsharded"})
     with pytest.raises(ValueError, match="num_devices"):
         cfg.validate()
-
-
-def test_dsharded_rounds_per_dispatch_through_config():
-    """rounds_per_dispatch > 1 on execution='dsharded' (forced to 1
-    through round 4): one train() call advances the round counter by the
-    chunk and reduces health over the whole chunk."""
-    _, cfg = get_algorithm_class("FEDAVG", return_config=True)
-    cfg.update_from_dict({
-        "dataset_config": {"type": "mnist", "num_clients": 16, "train_bs": 8},
-        "global_model": "mlp",
-        "evaluation_interval": 4,
-        "execution": "dsharded",
-        "health_check": True,
-        "rounds_per_dispatch": 3,
-        "num_malicious_clients": 4,
-        "adversary_config": {"type": "ALIE"},
-        "server_config": {"lr": 1.0, "aggregator": {"type": "Median"}},
-    })
-    cfg.resources(num_devices=8)
-    algo = cfg.build()
-    r = algo.train()
-    assert r["training_iteration"] == 3
-    assert r["round_ok"] and r["num_unhealthy"] == 0
-    assert np.isfinite(r["train_loss"])
-    assert int(algo.state.server.round) == 3
 
 
 def test_dense_matrix_hbm_limit_is_device_derived(monkeypatch):

@@ -109,6 +109,28 @@ def test_plan_dict_roundtrip_and_unknown_fields():
         Plan.from_dict("dense")
 
 
+@pytest.mark.parametrize("where", ["from_dict", "config_pin"])
+def test_a_plan_pin_that_names_a_dispatch_window_is_refused(where):
+    """A round is one dispatch.  A pin written when plans carried a
+    window (a checkpoint's plan, an operator's ``tuned_plan``) is input
+    from outside the program: a window of 1 is what every round does
+    and reads as the same plan; any other is refused by name."""
+    old_one = {**Plan().as_dict(), "rounds_per_dispatch": 1}
+    old_four = {**Plan().as_dict(), "rounds_per_dispatch": 4}
+    if where == "from_dict":
+        assert Plan.from_dict(old_one) == Plan()
+        with pytest.raises(ValueError, match="rounds_per_dispatch=4"):
+            Plan.from_dict(old_four)
+    else:
+        cfg = tiny_config()
+        cfg.resources(autotune=True, tuned_plan=old_one)
+        cfg.validate()
+        cfg = tiny_config()
+        cfg.resources(autotune=True, tuned_plan=old_four)
+        with pytest.raises(ValueError, match="dispatch windows were removed"):
+            cfg.validate()
+
+
 def test_enumerate_baseline_first_and_default_tier_only():
     space = enumerate_plans(
         executions=["dense"], d_chunks=[1 << 17],
@@ -211,11 +233,6 @@ def test_timed_measure_fn_injected_clock_deterministic():
     # tick under this clock -> median exactly 1.0, reproducibly.
     assert t == 1.0
     assert FakeAlgo.trained == 4  # 1 warmup + 3 reps
-    # Per-ROUND normalization: one dispatch of a w=4 scan-window plan
-    # advances 4 FL rounds, so the same dispatch median reports 4x
-    # cheaper per round — without this a windowed candidate could never
-    # beat w=1 on the measured path.
-    assert measure(Plan(rounds_per_dispatch=4)) == 0.25
 
     def broken_build(cand):
         raise RuntimeError("no such kernel")
@@ -234,17 +251,9 @@ def test_apply_plan_materialises_knobs():
     assert cfg.mxu_finish == "counts"
     assert cfg.client_packing == "off"
     cfg2 = tiny_config()
-    apply_plan(cfg2, Plan(rounds_per_dispatch=4, client_packing=2))
-    assert cfg2.rounds_per_dispatch == 4
-    assert cfg2.chained_dispatch is True
+    apply_plan(cfg2, Plan(client_packing=2, prefetch=True))
     assert cfg2.client_packing == 2
-    # A USER-pinned window (the plan space never varies it, so
-    # plan.rpd == config.rpd) keeps the user's own chained_dispatch
-    # setting — the plain multi_step discipline is a legal explicit
-    # choice the tuner must not silently rewrite.
-    cfg3 = tiny_config(rounds_per_dispatch=4)
-    apply_plan(cfg3, Plan(rounds_per_dispatch=4))
-    assert cfg3.chained_dispatch is False
+    assert cfg2.prefetch is True
 
 
 # ---------------------------------------------------------------------------
@@ -517,31 +526,36 @@ def test_plan_space_pins_explicit_knobs(tmp_path):
     assert algo.plan.prefetch is False
 
 
-def test_stale_cached_window_plan_retunes_not_applies(tmp_path):
-    """The config fingerprint cannot see sweep-level window context
-    (max_rounds / checkpoint_freq shape the eligible scan windows), so
-    a cached winner may carry a rounds_per_dispatch the CURRENT run's
-    constraints forbid — e.g. a w=8 window that would overshoot a
-    12-round stop criterion or skip checkpoint boundaries.  Such an
-    entry must be rejected (re-tune, marked cache_stale), never applied
-    verbatim."""
+@pytest.mark.parametrize("stale", ["windowed_space", "not_in_space"])
+def test_stale_cached_plan_retunes_not_applies(tmp_path, stale):
+    """A cached winner the current run may not use is re-tuned and
+    overwritten, never applied: an entry of the plan space that still
+    had a dispatch window (cache version 1, a ``rounds_per_dispatch``
+    field) reads as a miss; a parsable winner that is not among this
+    run's legal candidates is marked ``cache_stale``."""
     cfg = tiny_config()
     cfg.resources(autotune=True, autotune_cache_dir=str(tmp_path))
     algo = cfg.build()
     valid_plan = algo.plan
-    # Sabotage: overwrite the entry with a windowed winner that is NOT
-    # in the direct-API plan space (no sweep => windows stay (1,)).
     cache = PlanCache(tmp_path)
-    for _, entry in cache.entries():
-        cache.put(entry["key"],
-                  Plan(rounds_per_dispatch=8), {"mode": "measured"})
+    entries = cache.entries()
+    assert entries
+    for digest, entry in entries:
+        if stale == "windowed_space":
+            old = dict(entry, version=1,
+                       plan={**entry["plan"], "rounds_per_dispatch": 8})
+            (tmp_path / f"{digest}.json").write_text(json.dumps(old))
+        else:
+            cache.put(entry["key"],
+                      Plan(execution="streamed", d_chunk=1 << 16),
+                      {"mode": "measured"})
     cfg2 = tiny_config()
     cfg2.resources(autotune=True, autotune_cache_dir=str(tmp_path))
     algo2 = cfg2.build()
-    assert algo2.plan == valid_plan  # re-tuned, not the stale w=8 plan
+    assert algo2.plan == valid_plan  # re-tuned, not the stale winner
     assert algo2.plan_summary["cache_hit"] is False
-    assert algo2.plan_summary["cache_stale"] is True
-    assert algo2.config.rounds_per_dispatch == 1
+    assert (algo2.plan_summary.get("cache_stale", False)
+            is (stale == "not_in_space"))
     # ...and the re-tune overwrote the stale entry: third build hits.
     cfg3 = tiny_config()
     cfg3.resources(autotune=True, autotune_cache_dir=str(tmp_path))
@@ -744,16 +758,16 @@ def test_direct_api_resume_warns_on_plan_drift(tmp_path):
 
 
 def test_plan_id_mesh_free_regression_pin():
-    """Mesh-free plan ids are byte-identical to the pre-pod format —
-    the cache key and every historical round row depend on it."""
-    assert Plan().plan_id == "dense|c131072|p1|mxu=off|w1|nopre"
+    """Mesh-free plan ids carry no mesh marker: the ids round rows and
+    checkpoints are stamped with."""
+    assert Plan().plan_id == "dense|c131072|p1|mxu=off|nopre"
     assert (Plan(execution="streamed", mxu_finish="counts").plan_id
-            == "streamed|c131072|p1|mxu=counts|w1|nopre")
+            == "streamed|c131072|p1|mxu=counts|nopre")
 
 
 def test_plan_id_mesh_markers_only_when_engaged():
     assert (Plan(mesh_shape=(4, 2), tier="reassociating").plan_id
-            == "dense|c131072|p1|mxu=off|w1|nopre|mesh=4x2")
+            == "dense|c131072|p1|mxu=off|nopre|mesh=4x2")
     p = Plan(mesh_shape=(4, 2), collective="hier", tier="reassociating")
     assert p.plan_id.endswith("|mesh=4x2|hier")
     assert Plan.from_dict(p.as_dict()) == p
@@ -779,9 +793,9 @@ def test_enumerate_mesh_candidates_require_devices_and_opt_in():
     assert any(p.collective == "hier" for p in mesh)
     for p in mesh:
         if p.collective == "hier":
-            # hier never composes with scan windows / packing / prefetch
-            # / the window store — the dense per-round program only.
-            assert p.rounds_per_dispatch == 1 and p.client_packing == 1
+            # hier never composes with packing / prefetch / the
+            # window store.
+            assert p.client_packing == 1
             assert p.prefetch is False and p.state_window is None
     with pytest.raises(ValueError, match="num_devices > 1"):
         enumerate_plans(executions=["dense"], d_chunks=[1 << 17],

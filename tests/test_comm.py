@@ -306,16 +306,17 @@ def test_codec_composes_with_fault_injection():
     x, y, ln, mal = data
     state = fr.init(jax.random.PRNGKey(0), 6)
     assert state.residual is not None and state.stale is not None
-    import functools
-
-    step = jax.jit(functools.partial(fr.multi_step, num_rounds=6))
-    state, m = step(state, x, y, ln, mal, jax.random.PRNGKey(2))
+    step = jax.jit(fr.step)
+    unhealthy = []
+    for key in jax.random.split(jax.random.PRNGKey(2), 6):
+        state, m = step(state, x, y, ln, mal, key)
+        assert int(m["num_unhealthy"]) >= 0
+        assert int(m["num_participating"]) <= 6
+        unhealthy.append(int(m["num_unhealthy"]))
     for p in jax.tree.leaves(state.server.params):
         assert jnp.isfinite(p).all()
     assert jnp.isfinite(state.residual).all()
-    assert bool((m["num_unhealthy"] >= 0).all())
-    assert bool((m["num_participating"] <= 6).all())
-    assert bool((m["num_unhealthy"] > 0).any())  # corruption actually fired
+    assert any(u > 0 for u in unhealthy)  # corruption actually fired
 
 
 # ---------------------------------------------------------------------------
@@ -433,11 +434,11 @@ def test_error_feedback_residual_survives_kill_and_resume(tmp_path):
     base = run_experiments(
         _codec_experiments(codec, rounds=6, evaluation_interval=6),
         storage_path=str(tmp_path / "base"), verbose=0, lanes=False,
-        cost_analysis=False, scan_window=1)
+        cost_analysis=False)
     kill = run_experiments(
         _codec_experiments(codec, rounds=6, evaluation_interval=6),
         storage_path=str(tmp_path / "kill"), verbose=0, lanes=False,
-        cost_analysis=False, scan_window=1,
+        cost_analysis=False,
         checkpoint_freq=2, max_failures=1, preempt_after=5,
         retry_backoff_base=0.0)
     (b,), (k,) = base, kill

@@ -423,9 +423,6 @@ def test_config_control_gates():
     with pytest.raises(ValueError, match="starving"):
         _controlled_config(
             control={"max_quarantine_fraction": 0.9}).validate()
-    # Fused dispatch gives the controller no host-visible rounds.
-    with pytest.raises(ValueError, match="rounds_per_dispatch"):
-        _controlled_config(rounds_per_dispatch=2).validate()
     # The tuned recipe itself validates clean.
     _controlled_config().validate()
 

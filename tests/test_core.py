@@ -116,36 +116,6 @@ def test_lr_schedule_piecewise():
     assert 0.01 < float(sched(50)) < 0.1
 
 
-def test_multi_step_matches_sequential_steps(tiny):
-    """multi_step(k) must advance the same state machine as k step() calls
-    with the same per-round keys (jax.random.split of the chunk key)."""
-    ds, fr, _, (x, y, ln) = tiny
-    from functools import partial
-
-    mal = jnp.zeros(6, bool)
-    chunk_key = jax.random.PRNGKey(11)
-    st_a = fr.init(jax.random.PRNGKey(1), 6)
-    st_b = fr.init(jax.random.PRNGKey(1), 6)
-
-    st_a, ms = jax.jit(partial(fr.multi_step, num_rounds=3))(
-        st_a, x, y, ln, mal, chunk_key
-    )
-    step = jax.jit(fr.step)
-    keys = jax.random.split(chunk_key, 3)
-    for i in range(3):
-        st_b, m = step(st_b, x, y, ln, mal, keys[i])
-
-    ravel, _, _ = ravel_fn(st_b.server.params)
-    np.testing.assert_allclose(
-        np.asarray(ravel(st_a.server.params)),
-        np.asarray(ravel(st_b.server.params)), rtol=1e-6,
-    )
-    assert ms["train_loss"].shape == (3,)
-    np.testing.assert_allclose(float(ms["train_loss"][-1]), float(m["train_loss"]),
-                               rtol=1e-6)
-    assert int(st_a.server.round) == 3
-
-
 def test_bf16_compute_learns(tiny):
     ds, _, _, (x, y, ln) = tiny
     from blades_tpu.core import FedRound, Server, TaskSpec

@@ -533,8 +533,6 @@ def test_chaos_run_20_rounds_stays_finite(faulty_round, aggregator):
     """Acceptance: 30% Bernoulli dropout + 1 straggler with staleness 2,
     20 rounds on the tiny MLP — finite params, num_participating logged
     per round, detection metrics conditioned on participation."""
-    import functools
-
     from blades_tpu.faults import FaultInjector
 
     fx = faulty_round
@@ -545,9 +543,12 @@ def test_chaos_run_20_rounds_stays_finite(faulty_round, aggregator):
     mal = jnp.arange(n) < 1
     state = fr.init(jax.random.PRNGKey(0), n)
     assert state.stale.shape == (2, n, state.stale.shape[-1])
-    step = jax.jit(functools.partial(fr.multi_step, num_rounds=20))
-    state, m = step(state, fx["x"], fx["y"], fx["ln"], mal,
-                    jax.random.PRNGKey(2))
+    step = jax.jit(fr.step)
+    per_round = []
+    for key in jax.random.split(jax.random.PRNGKey(2), 20):
+        state, m_r = step(state, fx["x"], fx["y"], fx["ln"], mal, key)
+        per_round.append(m_r)
+    m = jax.tree.map(lambda *vs: jnp.stack(vs), *per_round)
     for p in jax.tree.leaves(state.server.params):
         assert jnp.isfinite(p).all()
     part = m["num_participating"]
@@ -725,8 +726,12 @@ def test_verify_result_rounds_rejects_duplicates_and_gaps(tmp_path):
     with pytest.raises(ValueError, match="duplicates or gaps"):
         verify_result_rounds(p)
     p.write_text("".join(json.dumps({"training_iteration": i}) + "\n"
-                         for i in (2, 4, 6)))  # rounds_per_dispatch stride
-    assert verify_result_rounds(p) == [2, 4, 6]
+                         for i in (2, 4, 6)))  # a stride is a gap too
+    with pytest.raises(ValueError, match="duplicates or gaps"):
+        verify_result_rounds(p)
+    p.write_text("".join(json.dumps({"training_iteration": i}) + "\n"
+                         for i in (3, 4, 5)))
+    assert verify_result_rounds(p) == [3, 4, 5]
 
 
 # ---------------------------------------------------------------------------

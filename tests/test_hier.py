@@ -374,8 +374,6 @@ def test_pod_scale_validation_messages():
     _check(r"mesh_shape must be a \(clients, d\) pair",
            mesh_shape=(4, 2, 1), num_devices=8)
     _check("pre-aggregates per chip and gathers", execution="hier")
-    _check("rounds_per_dispatch>1 is an unsupported pair",
-           execution="hier", num_devices=8, rounds_per_dispatch=2)
     _check("preagg must be one of", preagg="mean")
     _check("bucket_size must be an int >= 1", bucket_size=0)
     _check("autotune × execution='hier' is an unsupported pair",

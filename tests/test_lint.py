@@ -519,7 +519,7 @@ def test_cli_lists_all_passes():
 
 
 def test_repo_tree_is_clean():
-    """Every pass over blades_tpu/, bench.py, tests/ and tools/: zero
+    """Every pass over blades_tpu/, tests/ and tools/: zero
     unsuppressed ERROR findings — new violations land as tier-1
     failures with file:line + fix-hint."""
     t0 = time.perf_counter()
@@ -540,7 +540,6 @@ def test_fixture_dir_is_excluded_from_tree_scan():
     files = {f.rel for f in collect_files(REPO)}
     assert not any("lint_fixtures" in rel for rel in files)
     assert "blades_tpu/core/round.py" in files
-    assert "bench.py" in files
     assert "tools/lint/core.py" in files
 
 

@@ -429,17 +429,17 @@ def test_packed_kill_and_resume_to_unpacked(tmp_path):
     base = run_experiments(
         _packed_experiments("off", rounds=6, evaluation_interval=6),
         storage_path=str(tmp_path / "base"), verbose=0, lanes=False,
-        cost_analysis=False, scan_window=1)
+        cost_analysis=False)
     kill = run_experiments(
         _packed_experiments(2, rounds=6, evaluation_interval=6),
         storage_path=str(tmp_path / "kill"), verbose=0, lanes=False,
-        cost_analysis=False, scan_window=1,
+        cost_analysis=False,
         checkpoint_freq=2, preempt_after=5)
     assert kill[0].get("status") == "ERROR"  # preempted, max_failures=0
     resumed = run_experiments(
         _packed_experiments("off", rounds=6, evaluation_interval=6),
         storage_path=str(tmp_path / "kill"), verbose=0, lanes=False,
-        cost_analysis=False, scan_window=1,
+        cost_analysis=False,
         checkpoint_freq=2, resume=True)
     (b,), (r,) = base, resumed
     assert "status" not in r and r["rounds"] == 6

@@ -323,10 +323,6 @@ def test_fused_rejects_overtrimming():
                      interpret=True)
 
 
-# Full streamed-round compile twice over (~5 s); the kernel-level fused
-# equivalence grid above stays tier-1 in interpret mode (PR 20 budget
-# rebalance).
-@pytest.mark.slow
 def test_streamed_step_fused_branch_matches_chunked(monkeypatch):
     """Force the streamed round onto the fused finish (interpret mode)
     and check the whole round matches the chunked finish."""
@@ -382,14 +378,8 @@ def test_streamed_step_fused_branch_matches_chunked(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-# Two of the four shape rows ride the slow lane — the measured-slowest
-# arms of this grid (PR 20 budget rebalance); tier-1 keeps the largest
-# and the highest-multiplicity shapes across all forge/agg pairs.
 @pytest.mark.parametrize("nb,mult,d", [
-    (24, 8, 1000),
-    pytest.param(17, 5, 700, marks=pytest.mark.slow),
-    pytest.param(18, 6, 600, marks=pytest.mark.slow),
-    (11, 13, 520)])
+    (24, 8, 1000), (17, 5, 700), (18, 6, 600), (11, 13, 520)])
 @pytest.mark.parametrize(
     "forge,agg",
     [
@@ -431,7 +421,6 @@ def test_compact_matches_full_kernel(nb, mult, d, forge, agg):
     assert not np.asarray(bad_c).any()
 
 
-@pytest.mark.slow  # duplicate compact-kernel compile fixture (~10 s; the f32 compact/full equivalence stays tier-1)
 def test_compact_bf16_matches_full_bf16():
     from blades_tpu.ops.pallas_round import fused_finish_compact
 
@@ -470,7 +459,6 @@ def test_compact_adaptive_matches_full():
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.slow  # interpret-mode MXU variant sweep (~43 s; PR 7 budget rebalance)
 def test_compact_mxu_variants_match_default():
     """The MXU radix-count formulation must be BIT-exact vs the VPU one
     (the per-step counts are small integers, exact in f32); the MXU
@@ -582,10 +570,6 @@ def test_mxu_finish_config_path_resolved_per_call(monkeypatch):
     assert seen[-1] == (False, False)
 
 
-# Same shape as the fused-branch variant above: two full streamed-round
-# compiles (~8 s) to pin a branch the compact kernel grid already covers
-# tier-1 in interpret mode (PR 20 budget rebalance).
-@pytest.mark.slow
 def test_streamed_step_compact_branch_matches_chunked(monkeypatch):
     """Force the streamed round onto the benign-compacted fused finish
     (elided malicious prefix + virtual-multiplicity kernel, interpret
@@ -645,7 +629,6 @@ def test_streamed_step_compact_branch_matches_chunked(monkeypatch):
                                    atol=1e-6)
 
 
-@pytest.mark.slow  # duplicate compact-kernel compile fixture (~8 s; matches_full_kernel stays tier-1)
 def test_compact_caller_prepadded_rows_match_autopad():
     """num_real + caller +inf padding (the no-copy giant-scale path) must
     equal the concat-padding path."""
@@ -677,7 +660,6 @@ def test_compact_caller_prepadded_rows_match_autopad():
                                    rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # duplicate compact-kernel compile fixture (~6 s)
 def test_streamed_step_compact_with_row_padding(monkeypatch):
     """Compact streamed round where nb is NOT a sublane multiple: the
     pre-padded +inf rows must be invisible (parity vs chunked)."""

@@ -4,9 +4,8 @@
   compile the round program exactly once (the AOT executable cache);
 - donation: the pre-step RoundState's buffers are invalidated after a
   donated dispatch (and stay alive with ``donate_buffers=False``);
-- bit-identity: prefetch on/off, deferred metric fetches, and the
-  sweep's chained scan windows all reproduce the eager path exactly,
-  per aggregator.
+- bit-identity: prefetch on/off reproduces the eager path exactly, per
+  aggregator.
 """
 
 import json
@@ -272,149 +271,6 @@ def test_prefetch_bit_identity_per_aggregator(agg_name):
                     jax.tree.leaves(s_s.server.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=agg_name)
-
-
-# ---------------------------------------------------------------------------
-# chained scan windows + deferred metric fetches (sweep loop)
-# ---------------------------------------------------------------------------
-
-
-# Same scanned-key contract as tests/test_core.py's
-# test_multi_step_matches_sequential_steps, which stays tier-1; this
-# variant adds the carry-chaining angle at ~5 s of extra compile
-# (PR 20 budget rebalance).
-@pytest.mark.slow
-def test_multi_step_chained_matches_sequential_chain():
-    """The scanned key discipline reproduces the host driver's chain:
-    state AND the advanced carry match the sequential run bitwise."""
-    algo = tiny_config(prefetch=False).build()
-    fr, state0 = algo.fed_round, algo.state
-    arrays, mal = algo._train_arrays, algo.malicious
-    key0 = jax.random.PRNGKey(11)
-
-    seq_state, seq_key = state0, key0
-    step = jax.jit(fr.step)
-    for _ in range(4):
-        rk, seq_key = jax.random.split(seq_key)
-        seq_state, _ = step(seq_state, *arrays, mal, rk)
-
-    from functools import partial
-
-    win_state, win_key, metrics = jax.jit(
-        partial(fr.multi_step_chained, num_rounds=4)
-    )(state0, *arrays, mal, key0)
-    np.testing.assert_array_equal(np.asarray(seq_key), np.asarray(win_key))
-    for a, b in zip(jax.tree.leaves(seq_state.server.params),
-                    jax.tree.leaves(win_state.server.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert np.asarray(metrics["train_loss"]).shape == (4,)
-
-
-def _result_rows(summary):
-    rows = []
-    for ln in (Path(summary["dir"]) / "result.json").read_text().strip().splitlines():
-        r = json.loads(ln)
-        r.pop("timers", None)
-        r.pop("compile_cache_hits", None)
-        r.pop("compile_cache_misses", None)
-        rows.append(r)
-    return rows
-
-
-def _bi_experiments():
-    return {
-        "bi": {
-            "run": "FEDAVG",
-            "stop": {"training_iteration": 6},
-            "config": {
-                "dataset_config": {"type": "mnist", "num_clients": 6,
-                                   "train_bs": 8},
-                "global_model": "mlp",
-                "evaluation_interval": 3,
-                "num_malicious_clients": 2,
-                "adversary_config": {"type": "ALIE"},
-                "server_config": {"lr": 1.0,
-                                  "aggregator": {"type": "Median"}},
-            },
-        }
-    }
-
-
-@pytest.fixture(scope="module")
-def sequential_rows(tmp_path_factory):
-    """The eager round-per-dispatch baseline both identity tests compare
-    against (one shared run keeps tier-1 inside its wall-clock budget)."""
-    tmp = tmp_path_factory.mktemp("seq")
-    [seq] = run_experiments(_bi_experiments(), storage_path=str(tmp),
-                            verbose=0, lanes=False, scan_window=1)
-    return _result_rows(seq)
-
-
-def test_scan_window_rows_bit_identical_to_sequential(tmp_path,
-                                                      sequential_rows):
-    [win] = run_experiments(_bi_experiments(), storage_path=str(tmp_path),
-                            verbose=0, lanes=False, scan_window="auto")
-    assert win.get("scan_window", 1) > 1, "auto window did not engage"
-    win_rows = _result_rows(win)
-    assert len(sequential_rows) == len(win_rows) == 6  # one row per round
-    assert sequential_rows == win_rows
-
-
-def test_deferred_metric_rows_bit_identical(tmp_path, sequential_rows):
-    [dfr] = run_experiments(_bi_experiments(), storage_path=str(tmp_path),
-                            verbose=0, lanes=False, scan_window=1,
-                            metrics_every=4)
-    assert sequential_rows == _result_rows(dfr)
-
-
-def test_scan_window_respects_checkpoint_and_stop(tmp_path):
-    """Windows must divide eval/checkpoint cadence and the stop round —
-    checkpoints land on the same rounds as sequential execution."""
-    exps = _bi_experiments()
-    [s] = run_experiments(exps, storage_path=str(tmp_path), verbose=0,
-                          lanes=False, checkpoint_freq=3,
-                          scan_window="auto")
-    assert s["rounds"] == 6
-    tdir = Path(s["dir"])
-    assert (tdir / "ckpt_000003").exists() and (tdir / "ckpt_000006").exists()
-    from blades_tpu.tune.sweep import verify_result_rounds
-
-    assert verify_result_rounds(tdir / "result.json") == [1, 2, 3, 4, 5, 6]
-
-
-def test_auto_window_stays_off_for_pinned_dispatch(tmp_path):
-    """User-pinned rounds_per_dispatch keeps its classic one-row-per-
-    dispatch cadence (back-compat with the chunked driver)."""
-    exps = _bi_experiments()
-    exps["bi"]["config"]["rounds_per_dispatch"] = 3
-    [s] = run_experiments(exps, storage_path=str(tmp_path), verbose=0,
-                          lanes=False, scan_window="auto")
-    assert "scan_window" not in s
-    rows = _result_rows(s)
-    assert [r["training_iteration"] for r in rows] == [3, 6]
-
-
-@pytest.mark.slow
-def test_streamed_chained_dispatch_matches_streamed_sequential():
-    """chained_dispatch on the streamed path: windowed rounds consume
-    the exact keys the sequential driver would, so a chained 2-round
-    window reproduces two sequential streamed dispatches bitwise."""
-    def cfg(**kw):
-        c = tiny_config(prefetch=False)
-        c.update_from_dict({"update_dtype": "float32", "client_block": 3,
-                            "execution": "streamed", **kw})
-        return c
-
-    seq = cfg().build()
-    win = cfg(rounds_per_dispatch=2, chained_dispatch=True).build()
-    assert win._chained
-    for _ in range(4):
-        seq.train()
-    win.train()  # 2 windows of 2 rounds
-    win.train()
-    assert seq.iteration == win.iteration == 4
-    for a, b in zip(_params(seq), _params(win)):
-        np.testing.assert_array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -130,7 +130,6 @@ def test_cifar100_yaml_runs_two_rounds(tmp_path):
     # eval path is covered by every other integration test.
     spec["config"]["dataset_config"].update(num_clients=6, train_bs=4)
     spec["config"]["num_malicious_clients"] = 1
-    spec["config"]["rounds_per_dispatch"] = 1
     spec["config"]["evaluation_interval"] = 50
     spec["config"]["server_config"]["aggregator"] = {"type": "DnC"}
     summaries = run_experiments(

@@ -444,8 +444,6 @@ def test_validate_gates():
           num_devices=2)
     check("fault injection", backend="host", window=4,
           fault_config={"dropout_rate": 0.3})
-    check("rounds_per_dispatch", backend="host", window=4,
-          rounds_per_dispatch=2)
     check("nothing for a 'host' store", backend="host", window=0)
     check("num_devices>1 is an unsupported", backend="resident", window=0,
           num_devices=2)
@@ -524,7 +522,7 @@ def test_plan_state_knobs():
     from blades_tpu.perf.autotune import Plan, apply_plan, enumerate_plans
 
     # Store-free plans keep the byte-identical pre-knob id format.
-    assert Plan().plan_id == "dense|c131072|p1|mxu=off|w1|nopre"
+    assert Plan().plan_id == "dense|c131072|p1|mxu=off|nopre"
     windowed = Plan(state_store="host", state_window=256)
     assert windowed.plan_id.endswith("|ss=hostw256")
     with pytest.raises(ValueError):

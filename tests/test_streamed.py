@@ -35,11 +35,9 @@ def data():
 
 
 @pytest.mark.parametrize("aggregator,adversary", [
-    # Same streamed-vs-dense fixture at ~7-9 s/case; tier-1 keeps one
-    # aggregator/adversary shape (PR 7 rebalance, tightened in PR 20).
-    pytest.param("Median", "ALIE", marks=pytest.mark.slow),
+    ("Median", "ALIE"),
     ("Mean", "IPM"),
-    pytest.param("Trimmedmean", "ALIE", marks=pytest.mark.slow),
+    ("Trimmedmean", "ALIE"),
 ])
 def test_streamed_matches_dense_f32(data, aggregator, adversary):
     """f32 storage + deterministic coordinate-wise attacks: the chunked
@@ -154,11 +152,8 @@ def test_streamed_dp_noise_is_applied(data):
 
 @pytest.mark.parametrize("aggregator,adversary", [
     ("Median", "ALIE"),          # fused-eligible coordinate path (chunked on CPU)
-    # The row-geometry combinations compile near-identical streamed
-    # programs (~8 s each on this box); tier-1 keeps the headline pair,
-    # the full suite runs all three (PR 7 budget rebalance).
-    pytest.param("GeoMed", "IPM", marks=pytest.mark.slow),
-    pytest.param("Median", "MinMax", marks=pytest.mark.slow),
+    ("GeoMed", "IPM"),           # row-geometry aggregator
+    ("Median", "MinMax"),        # row-geometry forger
 ])
 def test_malicious_prefix_elision_is_exact(data, aggregator, adversary):
     """Skipping the dead malicious-lane training blocks must reproduce the
@@ -260,60 +255,6 @@ def test_malicious_prefix_promise_check_is_per_object(data):
         if id(bad) == freed_id:
             break  # the regression scenario itself was exercised
         del bad
-
-
-def test_streamed_multi_round_dispatch_matches_sequential(data):
-    """rounds_per_dispatch > 1 on the streamed path: k chained rounds
-    (no host sync between them) must equal k sequential streamed_step
-    calls bit-for-bit at f32 storage — same split(key, k) stream as the
-    dense multi_step."""
-    from blades_tpu.parallel.streamed import streamed_multi_step
-
-    x, y, ln, mal = data
-    fr = make_fr("Median", "ALIE")
-    key = jax.random.PRNGKey(11)
-    k = 3
-
-    st_a = fr.init(jax.random.PRNGKey(0), N)
-    multi = streamed_multi_step(fr, k, client_block=4, d_chunk=10_000,
-                                update_dtype=jnp.float32, donate=False)
-    st_a, m_a = multi(st_a, x, y, ln, mal, key)
-    assert m_a["train_loss"].shape == (k,)
-
-    st_b = fr.init(jax.random.PRNGKey(0), N)
-    step = streamed_step(fr, client_block=4, d_chunk=10_000,
-                         update_dtype=jnp.float32, donate=False)
-    keys = jax.random.split(key, k)
-    losses = []
-    for r in range(k):
-        st_b, m_b = step(st_b, x, y, ln, mal, keys[r])
-        losses.append(m_b["train_loss"])
-    for a, b in zip(jax.tree.leaves(st_a.server.params),
-                    jax.tree.leaves(st_b.server.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(m_a["train_loss"]),
-                                  np.asarray(jnp.stack(losses)))
-
-
-def test_streamed_rounds_per_dispatch_from_config():
-    """execution: streamed + rounds_per_dispatch: 8 builds and trains
-    through the Fedavg config path (VERDICT r3 item 4)."""
-    from blades_tpu.algorithms import FedavgConfig
-
-    cfg = (
-        FedavgConfig()
-        .data(dataset="mnist", num_clients=6, seed=1)
-        .training(global_model="mlp",
-                  aggregator={"type": "Median"}, server_lr=1.0)
-        .resources(execution="streamed", client_block=2,
-                   update_dtype="float32")
-        .evaluation(evaluation_interval=8)
-    )
-    cfg.rounds_per_dispatch = 8
-    algo = cfg.build()
-    r = algo.train()
-    assert r["training_iteration"] == 8
-    assert np.isfinite(r["train_loss"])
 
 
 def test_malicious_prefix_elision_exact_under_dp(data):

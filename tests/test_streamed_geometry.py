@@ -6,10 +6,6 @@ f32 storage the only divergence from the dense ``FedRound.step`` is
 chunk-level reduction reassociation, so whole-round equivalence holds to
 tight tolerances.  d and d_chunk are chosen so the matrix spans several
 chunks including a ragged overlapping tail.
-
-Tier-2 (``slow``): the many-chunk geometry makes each case compile-heavy
-(~2 min of wall clock for the file on a 2-core CPU host); tier-1 keeps
-the streamed path covered via ``test_streamed.py``.
 """
 
 import jax
@@ -20,8 +16,6 @@ import pytest
 from blades_tpu.adversaries import get_adversary, make_malicious_mask
 from blades_tpu.core import FedRound, Server, TaskSpec
 from blades_tpu.parallel.streamed import streamed_step
-
-pytestmark = pytest.mark.slow
 
 N, F = 12, 3
 D_CHUNK = 1024  # model d ~ 44k -> dozens of chunks + ragged tail

@@ -428,7 +428,7 @@ def test_sweep_trace_dir_exports_per_trial_tree(tmp_path):
     trace_dir = tmp_path / "traces"
     [s] = run_experiments(
         _experiments("traced", rounds=3), storage_path=str(tmp_path),
-        verbose=0, cost_analysis=False, scan_window=1,
+        verbose=0, cost_analysis=False,
         trace_dir=str(trace_dir), watchdog=True)
     out = trace_dir / "traced_00000.trace.json"
     assert out.exists()
@@ -484,7 +484,7 @@ def test_observability_off_rows_bit_identical_zoo(tmp_path, path_name):
 
 def _assert_identity(tmp_path, path_name):
     over = _IDENTITY_PATHS[path_name]
-    kw = dict(verbose=0, cost_analysis=False, scan_window=1, lanes=False)
+    kw = dict(verbose=0, cost_analysis=False, lanes=False)
     exps = _experiments("ab", rounds=3, **over)
     run_experiments(exps, storage_path=str(tmp_path / "off"),
                     flightrec_rounds=0, **kw)
@@ -728,10 +728,10 @@ def test_kill_and_resume_with_armed_watchdog(tmp_path):
     watchdog windows are rebuilt from disk on restore."""
     exps = _experiments("wd", rounds=6, evaluation_interval=0)
     run_experiments(exps, storage_path=str(tmp_path / "ref"), verbose=0,
-                    cost_analysis=False, scan_window=1, watchdog=True)
+                    cost_analysis=False, watchdog=True)
     [s] = run_experiments(
         exps, storage_path=str(tmp_path / "preempted"), verbose=0,
-        cost_analysis=False, scan_window=1, watchdog=True,
+        cost_analysis=False, watchdog=True,
         checkpoint_freq=2, max_failures=1, preempt_after=3)
     tdir = tmp_path / "preempted" / "wd" / "wd_00000"
     assert verify_result_rounds(tdir / "result.json") == list(range(1, 7))

@@ -94,39 +94,55 @@ def test_cli_file_command(tmp_path):
     assert (tmp_path / "out" / "cli_smoke").exists()
 
 
-def test_tuned_examples_parse_and_expand():
+_TUNED_EXAMPLES = Path(__file__).parent.parent / "blades_tpu" / "tuned_examples"
+
+
+@pytest.mark.parametrize(
+    "yaml_name", sorted(p.name for p in _TUNED_EXAMPLES.glob("*.yaml")))
+def test_tuned_examples_parse_and_expand(yaml_name):
     """Every shipped YAML grid must load and expand (the reference's
-    tuned_examples are its canonical envelope, SURVEY.md §6)."""
-    root = Path(__file__).parent.parent / "blades_tpu" / "tuned_examples"
-    yamls = sorted(root.glob("*.yaml"))
-    assert len(yamls) >= 5
-    for y in yamls:
-        exps = load_experiments_from_file(str(y))
-        for name, spec in exps.items():
-            trials = expand_grid(spec["config"])
-            assert len(trials) >= 1
+    tuned_examples are its canonical envelope, SURVEY.md §6), and every
+    trial of it must pass the config's own gates: no unknown key, no
+    refused pair."""
+    from blades_tpu.algorithms import get_algorithm_class
+
+    exps = load_experiments_from_file(str(_TUNED_EXAMPLES / yaml_name))
+    assert exps
+    for spec in exps.values():
+        trials = expand_grid(spec["config"])
+        assert len(trials) >= 1
+        for trial in trials:
+            _, config = get_algorithm_class(spec["run"], return_config=True)
+            config.update_from_dict(trial)
+            config.validate()
 
 
-def test_run_experiments_counts_rounds_not_calls(tmp_path):
-    """With rounds_per_dispatch > 1, the stop criterion is FL rounds."""
-    experiments = {
-        "chunked": {
-            "run": "FEDAVG",
-            "stop": {"training_iteration": 6},
-            "config": {
-                "dataset_config": {"type": "mnist", "num_clients": 4, "train_bs": 8},
-                "global_model": "mlp",
-                "rounds_per_dispatch": 3,
-                "evaluation_interval": 3,
-                "server_config": {"lr": 1.0},
-            },
-        }
-    }
-    [s] = run_experiments(experiments, storage_path=str(tmp_path), verbose=0)
-    assert s["rounds"] == 6
-    lines = (Path(s["dir"]) / "result.json").read_text().strip().splitlines()
-    assert len(lines) == 2  # two dispatches of 3 rounds
-    assert json.loads(lines[-1])["training_iteration"] == 6
+def test_tuned_examples_are_all_there():
+    assert len(list(_TUNED_EXAMPLES.glob("*.yaml"))) >= 5
+
+
+def test_a_round_row_is_on_disk_before_its_checkpoint(tmp_path, monkeypatch):
+    """The sweep's one loop: train(), write the row, then the checkpoint.
+    At every checkpoint save, result.json as another reader finds it on
+    disk already ends with the round the checkpoint covers."""
+    from blades_tpu.faults import host
+
+    seen = []
+    real = host.atomic_checkpoint
+
+    def spying(save_fn, path):
+        rows = (Path(path).parent / "result.json").read_text().splitlines()
+        seen.append((Path(path).name,
+                     [json.loads(r)["training_iteration"] for r in rows]))
+        return real(save_fn, path)
+
+    monkeypatch.setattr(host, "atomic_checkpoint", spying)
+    [s] = run_experiments(_resume_experiments(6), storage_path=str(tmp_path),
+                          verbose=0, cost_analysis=False, checkpoint_freq=2)
+    assert s["rounds"] == 6 and "scan_window" not in s
+    assert seen == [("ckpt_000002", [1, 2]),
+                    ("ckpt_000004", [1, 2, 3, 4]),
+                    ("ckpt_000006", [1, 2, 3, 4, 5, 6])]
 
 
 def _resume_experiments(rounds):

@@ -26,7 +26,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     p.add_argument("paths", nargs="*",
                    help="restrict to these files (default: the full tree — "
-                        "blades_tpu/, tests/, tools/, bench.py)")
+                        "blades_tpu/, tests/, tools/)")
     p.add_argument("--changed", action="store_true",
                    help="lint only files changed vs HEAD (+ untracked)")
     p.add_argument("--json", action="store_true", dest="as_json",

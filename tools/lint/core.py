@@ -169,10 +169,10 @@ class LintContext:
                        for p in prefixes)]
 
 
-# Roots scanned by default (ISSUE 8: blades_tpu/, bench.py, tests/ —
-# plus tools/ so the lint suite lints itself).  Fixture snippets are
+# Roots scanned by default (blades_tpu/, tests/ — plus tools/ so the
+# lint suite lints itself).  Fixture snippets are
 # DELIBERATE violations and must never enter the default tree scan.
-DEFAULT_ROOTS = ("blades_tpu", "tests", "tools", "bench.py")
+DEFAULT_ROOTS = ("blades_tpu", "tests", "tools")
 EXCLUDE_PARTS = ("lint_fixtures", "__pycache__")
 
 

@@ -4,9 +4,9 @@ The AST generalization of the retired ``tests/test_no_host_sync.py``
 grep: every ``device_get`` / ``block_until_ready`` / numpy conversion /
 ``.item()`` / ``float(<array expr>)`` inside the modules whose code runs
 inside (or builds) the jitted round stalls the dispatch pipeline once
-per round.  Sanctioned flush points live in HOST modules (fedavg
-finalize_row, the sweep's batched emit, perf/async_metrics), which are
-not scanned; a device-side line that must sync carries
+per round.  The sanctioned fetch lives in a HOST module
+(``Fedavg._train_raw``'s ``blades/fetch``), which is not scanned; a
+device-side line that must sync carries
 ``# blades-lint: disable=host-sync — <why>``.
 """
 
@@ -99,9 +99,9 @@ _SYNC_CALLS = {"jax.device_get", "jax.block_until_ready",
 _ARRAY_ROOTS = {"jnp", "jax"}
 _REDUCTIONS = {"sum", "mean", "max", "min", "all", "any", "prod"}
 
-_HINT = ("move the fetch to a sanctioned flush point (fedavg "
-         "finalize_row / sweep batched emit / perf.async_metrics), or "
-         "pragma the line if it is genuinely setup-time/once-per-object")
+_HINT = ("move the fetch to the sanctioned one (Fedavg._train_raw's "
+         "blades/fetch), or pragma the line if it is genuinely "
+         "setup-time/once-per-object")
 
 
 def _is_array_expr(node: ast.AST) -> bool:

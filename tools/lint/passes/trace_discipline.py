@@ -8,8 +8,8 @@ correlate with the jax profiler), and the sanctioned raw clock is
 duration nobody can see in a trace — the drift this pass freezes out,
 exactly like host-sync froze out stray ``device_get``\\ s.
 
-Scope: ``blades_tpu/`` only (bench.py and tools/ are measurement
-harnesses outside the traced driver).  The trace module itself is the
+Scope: ``blades_tpu/`` only (``tools/`` holds measurement harnesses
+outside the traced driver).  The trace module itself is the
 allowed home.  Detection covers the module-attribute
 form (``time.perf_counter()``), ``from time import perf_counter``
 aliases, and the ``_ns`` variants; ``time.sleep`` is not a measurement

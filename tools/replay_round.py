@@ -114,8 +114,8 @@ def replay(dump: dict, tick=None):
         row = algo.train()
     if row is None or row.get("training_iteration") != target:
         raise ValueError(
-            f"replay stopped at iteration {algo.iteration} "
-            f"(rounds_per_dispatch overshoots round {target}?)")
+            f"replay stopped at iteration {algo.iteration}, "
+            f"not at the recorded round {target}")
     return row, recorded
 
 
