@@ -272,6 +272,7 @@ class Task:
         round_begin_hook=identity_round_begin_hook,
         round_end_hook=identity_round_end_hook,
         out_dtype=None,
+        ravel_update: bool = True,
     ):
         """One client's full local round: scan SGD over ``num_batches``.
 
@@ -293,6 +294,13 @@ class Task:
                 concat — bit-identical values (cast commutes with
                 concatenation), but the flat-vector assembly passes run
                 at storage width instead of f32.
+            ravel_update: ``False`` returns the update as the params'
+                pytree of per-leaf differences, cast and NOT
+                concatenated, for a caller that lays the row out itself
+                (the streamed round's one-lane block, which must not
+                let a ``(1, d)`` row be assembled: parallel/streamed.py
+                ``_train_block``).  Only with ``out_dtype`` and the
+                identity ``round_end_hook``, the per-leaf cast's case.
 
         Returns:
             ``(update_vec, new_opt_state, mean_loss, stats)`` where
@@ -320,10 +328,15 @@ class Task:
         # Pseudo-grad is always vs the INCOMING global params (the
         # reference snapshots the global weights, ref: task.py:159-168).
         if out_dtype is not None and round_end_hook is identity_round_end_hook:
-            update = ravel(jax.tree.map(
+            update = jax.tree.map(
                 lambda p1, p0: (p1 - p0).astype(out_dtype),
                 params, global_params,
-            ))
+            )
+            if ravel_update:
+                update = ravel(update)
+        elif not ravel_update:
+            raise ValueError("ravel_update=False needs out_dtype and the "
+                             "identity round_end_hook")
         else:
             update = ravel(params) - ravel(global_params)
             update = round_end_hook(update, malicious)
@@ -345,10 +358,13 @@ class Task:
         round_begin_hook=identity_round_begin_hook,
         round_end_hook=identity_round_end_hook,
         out_dtype=None,
+        ravel_update: bool = True,
     ):
         """A whole client block's local rounds: ``(G, nb, B, ...)`` batches
         -> ``(updates (G, d), new_opt_states, losses (G,), stats)``, each
         lane's stats stacked (``{}`` for a model that sows nothing).
+        With ``ravel_update=False`` the updates are the params' pytree
+        with a leading ``G`` on every leaf (:meth:`local_round`).
 
         Semantically ``vmap(local_round)`` over the client axis.  (A
         merged-batch "FedSGD" formulation — one shared forward over
@@ -365,7 +381,7 @@ class Task:
             return self.local_round(
                 global_params, opt_state, cbx, cby, ck, mal,
                 data_hook, grad_hook, round_begin_hook, round_end_hook,
-                out_dtype=out_dtype,
+                out_dtype=out_dtype, ravel_update=ravel_update,
             )
 
         return jax.vmap(one_client)(

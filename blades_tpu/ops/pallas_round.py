@@ -24,6 +24,26 @@ radix-select Median, or Trimmedmean — same selection networks as
 :mod:`blades_tpu.ops.pallas_select`), and (e) accumulates per-row
 squared norms for the round metrics.
 
+Two layouts of the matrix, one body (:func:`_compact_kernel`).  Tall
+matrices (hundreds of clients) are ``(n, d)``: the rows lie on the
+sublanes, a stripe ``(n, cols)`` fills its vregs, and a count over the
+rows is a reduction across sublanes.  At 8-32 rows that is the wrong way
+round: every count of the 16 radix steps is a sublane reduction over one
+vreg, and the search's one-row values (``res``, ``cnt``, the forged key)
+fill as many vregs as the whole stripe at an eighth of their lanes.  A
+matrix whose blocks lie under a storage tile is therefore kept as row
+planes, ``(rows, d // 128, 128)`` (parallel/streamed.py::compact_matrix,
+which the one-row store needs for its own reasons: ops/pallas_store.py),
+and the compact finish takes blocks ``(rows, s, 128)``,
+``s x 128`` = :func:`~blades_tpu.ops.pallas_select.plane_cols` columns:
+a count over the rows is then a plain add of whole vregs and a one-row
+value is an eighth of the data (on a v5e, 8 x 4.1e8 bf16: 275 ms -> 81
+ms; PERF.md §6, PR 32).  The body does not know which it has: per-column
+values are ``(1,) + x.shape[1:]``, per-row values ``(rows, 1[, 1])``, and
+the float sums over the rows are added in one order (:func:`_sum_rows`),
+so both layouts of one matrix give the same aggregate and forged row to
+the bit.  ``sanitize`` is local to a grid step's block in either.
+
 Numerics: statistics run in f32 inside the kernel in the same formulas
 as :func:`blades_tpu.adversaries.base.benign_mean_std` (ddof=1), but
 reduction *order* differs from the XLA chunk path, so forged values can
@@ -41,6 +61,7 @@ d-chunk, draws differently (both are valid attack streams).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -54,6 +75,7 @@ from blades_tpu.ops.pallas_select import (
     _next_key_above,
     _vals_of,
     kernel_applicable,
+    plane_cols,
     stripe_cols,
     stripe_compiler_params,
 )
@@ -134,9 +156,10 @@ def _count_lt_mxu(keys, cand):
 def _kth_key16_mult(keys, k, fkey, mult: int, count=_count_lt_vpu):
     """:func:`_kth_key16` over the multiset ``keys + mult x fkey`` —
     ``fkey`` is a (1, c) virtual key counted ``mult`` times per column.
-    ``k`` may be a static int or a (1, c) per-column rank vector."""
-    c = keys.shape[1]
-    res = jnp.zeros((1, c), jnp.uint32)
+    ``k`` may be a static int or a (1, c) per-column rank vector.
+    Indifferent to the trailing shape, as every helper of the compact
+    kernel is: a per-column value is ``(1,) + keys.shape[1:]``."""
+    res = jnp.zeros((1,) + keys.shape[1:], jnp.uint32)
     for bit in range(15, -1, -1):
         cand = res | jnp.uint32(1 << bit)
         cnt = count(keys, cand)
@@ -160,8 +183,7 @@ def _next_key16_above_mult(keys, v, fkey):
 
 def _kth_key_mult(keys, k, fkey, mult: int, count=_count_lt_vpu):
     """32-step :func:`_kth_key16_mult` for full uint32 keys (f32 data)."""
-    c = keys.shape[1]
-    res = jnp.zeros((1, c), jnp.uint32)
+    res = jnp.zeros((1,) + keys.shape[1:], jnp.uint32)
     for bit in range(31, -1, -1):
         cand = res | jnp.uint32(1 << bit)
         cnt = count(keys, cand)
@@ -184,6 +206,45 @@ def _next_key_above_mult(keys, v, fkey):
     return jax.lax.bitcast_convert_type(m, jnp.uint32) ^ bias
 
 
+def _over_row(reduce, x):
+    """``reduce`` (``jnp.sum``, ``jnp.all``) over all of a row, which is
+    every axis but the first, to ``(rows, 1[, 1])``: one axis at a time,
+    because Mosaic aborts on a reduction over two axes at once
+    (``layout.h:320 Check failed: arr.size() >= layout_rank``), and the
+    sublane axis of a plane first, where it is whole-vreg arithmetic."""
+    for axis in range(1, x.ndim):
+        x = reduce(x, axis=axis, keepdims=True)
+    return x
+
+
+def _sum_rows(x):
+    """The FLOAT sum over the rows, ``(1,) + x.shape[1:]``, with the bits
+    it has where the rows lie on the sublanes.
+
+    Float addition is not associative, and the forged row is rounded to
+    bf16 from a float32 mean and deviation: one last place of a sum flips
+    that rounding in about one column of 10 000.  Mosaic's reduction
+    across sublanes (measured on a v5e, libtpu 0.0.34, on 2048 columns
+    each at 8, 10, 16 and 24 rows: all equal; PERF.md §6, PR 32) adds the
+    vregs of 8 rows elementwise, in order, the rows past the last as
+    zeros, and then folds the 8 sublanes as a butterfly of shifts 4, 2,
+    1: ``((x0 + x4) + (x2 + x6)) + ((x1 + x5) + (x3 + x7))``.  A sum
+    over the major axis of row planes would add them first to last; so
+    there it is written out in the sublanes' order, the same count of
+    adds, and the two layouts of one matrix give one aggregate and one
+    forged row to the bit.  (Counts, minima and maxima have no order.)"""
+    if x.ndim == 2:
+        return jnp.sum(x, axis=0, keepdims=True)
+    rows = x.shape[0]
+    zero = jnp.zeros_like(x[:1])
+    lane = [x[i:i + 1] if i < rows else zero for i in range(8)]
+    for j in range(8, -(-rows // 8) * 8):
+        lane[j % 8] = lane[j % 8] + (x[j:j + 1] if j < rows else zero)
+    for shift in (4, 2, 1):
+        lane = [lane[i] + lane[i + shift] for i in range(shift)]
+    return lane[0]
+
+
 def _row_weighted_colsum(m, wb, mxu: bool):
     """``sum(m * wb, axis=0)`` as (1, c): VPU reduction or an MXU
     ``wb.T @ m`` contraction — f32 accumulate, but the MXU multiplies
@@ -192,7 +253,7 @@ def _row_weighted_colsum(m, wb, mxu: bool):
         return jax.lax.dot_general(
             wb.reshape(1, -1), m, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    return jnp.sum(m * wb, axis=0, keepdims=True)
+    return _sum_rows(m * wb)
 
 
 def _forged_stripe(xs, wb, r_ref, forge, keys16: bool, mxu: bool = False):
@@ -328,7 +389,7 @@ def _fused_kernel(x_ref, wb_ref, fm_ref, r_ref, o_ref, sq_ref, bad_ref, *,
         raise ValueError(f"unknown aggregator {akind!r}")
 
 
-def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
+def _compact_kernel(x_ref, wb_ref, *refs,
                     nb_true: int, mult: int, forge: tuple, agg: tuple,
                     sanitize: bool, keys16: bool,
                     radix_mxu: bool = False, stats_mxu: bool = False):
@@ -336,7 +397,17 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
     (malicious training was elided), and the forged row participates in
     the order statistics as a VIRTUAL row of multiplicity ``mult`` —
     every per-row pass (load, keys, radix counts) runs over ``nb`` rows
-    instead of ``nb + mult``."""
+    instead of ``nb + mult``.
+
+    One body for both layouts of the matrix.  A block is ``(rows, c)``,
+    the rows on the sublanes, or ``(rows, s, 128)``, a row a plane of
+    whole vregs: every per-column value is ``(1,) + x.shape[1:]``, every
+    reduction over the rows is ``axis=0`` (across sublanes there, plain
+    adds of vregs here), every per-row value ``(rows, 1[, 1])``.  ``refs``:
+    the forge's uniforms where there are any (always in two dimensions,
+    only for the adaptive forge on planes), then the four outputs."""
+    *r_ref, o_ref, sq_ref, bad_ref, fr_ref = refs
+    r_ref = r_ref[0] if r_ref else None
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)          # (nbpad, c) benign stripe
     wb = wb_ref[...]                            # (nbpad, 1) real-row mask
@@ -347,7 +418,7 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
         bad_ref[...] = jnp.zeros_like(bad_ref)
 
     if sanitize:
-        row_ok = jnp.isfinite(x).all(axis=1, keepdims=True)
+        row_ok = _over_row(jnp.all, jnp.isfinite(x))
         row_bad = wb * (1.0 - row_ok.astype(jnp.float32))
         x = jnp.where(row_bad > 0, 0.0, x)
         bad_ref[...] = jnp.maximum(bad_ref[...], row_bad)
@@ -361,7 +432,7 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
             xs * xs, jnp.ones((xs.shape[1], 1), jnp.float32),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     else:
-        sq_ref[...] += jnp.sum(xs * xs, axis=1, keepdims=True)
+        sq_ref[...] += _over_row(jnp.sum, xs * xs)
 
     count = _count_lt_mxu if radix_mxu else _count_lt_vpu
     if keys16:
@@ -374,8 +445,7 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
     n_tot = nb_true + mult
     akind = agg[0]
     if akind == "mean":
-        o_ref[...] = (jnp.sum(xs, axis=0, keepdims=True)
-                      + mult * forged) / n_tot
+        o_ref[...] = (_sum_rows(xs) + mult * forged) / n_tot
         return
     keys = keys_of(jnp.where(wb > 0, xs, jnp.inf))
     fkey = keys_of(forged)
@@ -398,8 +468,7 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
         flo, fhi = vals(vlo), vals(vhi)
         between = (keys > vlo) & (keys < vhi)
         f_between = ((fkey > vlo) & (fkey < vhi)).astype(jnp.float32)
-        sum_mid = (jnp.sum(jnp.where(between, xm, 0.0), axis=0,
-                           keepdims=True)
+        sum_mid = (_sum_rows(jnp.where(between, xm, 0.0))
                    + mult * forged * f_between)
         cnt_lt_lo = (jnp.sum((keys < vlo).astype(jnp.int32), axis=0,
                              keepdims=True)
@@ -429,29 +498,41 @@ def _compact_kernel(x_ref, wb_ref, r_ref, o_ref, sq_ref, bad_ref, fr_ref, *,
 
 
 def _pad_to_stripes(updates, rbuf, cols: int):
-    """Zero-pad the matrix's and ``rbuf``'s columns to a whole number of
-    ``cols``-wide stripes (padding columns aggregate to values the
-    callers slice off).  Padding the matrix COPIES it: callers at giant
-    scale allocate it ``stripe_cols(rows)``-aligned
-    (parallel/streamed.py::step) and only the one-row ``rbuf`` is padded
-    here.  Returns ``(updates, rbuf, dpad)``."""
+    """Zero-pad the matrix's and ``rbuf``'s axis 1 (the columns, or a
+    plane's sublanes) to a whole number of ``cols``-wide blocks (padding
+    columns aggregate to values the callers slice off).  Padding the
+    matrix COPIES it: callers at giant scale allocate it aligned
+    (parallel/streamed.py::compact_matrix) and only the one-row ``rbuf``
+    is padded here.  Returns ``(updates, rbuf, dpad)``; ``rbuf`` may be
+    ``None``."""
+    def pad(a, to):
+        widths = [(0, 0)] * a.ndim
+        widths[1] = (0, to - a.shape[1])
+        return jnp.pad(a, widths)
+
     d = updates.shape[1]
     dpad = -(-d // cols) * cols
     if dpad != d:
-        updates = jnp.pad(updates, ((0, 0), (0, dpad - d)))
-    if rbuf.shape[1] != dpad:
-        rbuf = jnp.pad(rbuf, ((0, 0), (0, dpad - rbuf.shape[1])))
+        updates = pad(updates, dpad)
+    if rbuf is not None and rbuf.shape[1] != dpad:
+        rbuf = pad(rbuf, dpad)
     return updates, rbuf, dpad
 
 
-def _block_specs(npad: int, cols: int):
+def _block_specs(npad: int, *tail: int):
     """The three block shapes of a fused finish: the ``(npad, cols)``
     stripe and the ``(1, cols)`` row, both walking the columns with the
-    grid, and the resident ``(npad, 1)`` per-row column."""
+    grid, and the resident ``(npad, 1)`` per-row column.  With a
+    ``tail`` of ``(s, 128)`` the same three over planes: ``(npad, s,
+    128)``, ``(1, s, 128)`` and ``(npad, 1, 1)``."""
+    zeros = (0,) * (len(tail) - 1)
     return (
-        pl.BlockSpec((npad, cols), lambda i: (0, i), memory_space=pltpu.VMEM),
-        pl.BlockSpec((npad, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, cols), lambda i: (0, i), memory_space=pltpu.VMEM),
+        pl.BlockSpec((npad,) + tail, lambda i: (0, i) + zeros,
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((npad,) + (1,) * len(tail), lambda i: (0, 0) + zeros,
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1,) + tail, lambda i: (0, i) + zeros,
+                     memory_space=pltpu.VMEM),
     )
 
 
@@ -559,6 +640,18 @@ def _fused_finish_compact_jit(
     Returns ``(agg_vec (d,), sq_norms (nb,), bad (nb,), forged (d,))`` —
     the caller reconstructs malicious-row norms as ``||forged||^2``.
 
+    ``updates`` is ``(nb, d)``, or ``(nb, d // 128, 128)`` where the
+    matrix keeps a row a plane (the module text; ``d`` is then the
+    allocated width, and ``forge_noise`` is as wide).  On planes the grid
+    walks blocks ``(nb, s, 128)``, the two d-sized outputs are ``(1, d //
+    128, 128)`` inside (flat again on return: the same bytes), the rows
+    need no sublane padding (``num_real`` is accepted, and rows past it
+    are masked, whatever they hold), the MXU variants are off (there is
+    no sublane row to contract over), and the forge's uniforms are an
+    operand only where the forge is adaptive: the two-dimensional call
+    keeps its zero ``(1, d)`` buffer for the other forges, and with it
+    the program it always built.
+
     ``num_real``: benign row count when the CALLER pre-padded the matrix
     to a sublane multiple with +inf rows (row padding here would
     concat-copy the giant matrix; the streamed round allocates padded
@@ -573,13 +666,20 @@ def _fused_finish_compact_jit(
     static booleans; the public wrapper resolves the
     ``BLADES_TPU_MXU_FINISH`` env default per call.
 
-    ``cols``: the stripe's width, for the sake of the tests and of
-    ``tools/chip_kernels.py --sweep`` only (they force 512, or sweep it,
-    to compare widths); the public wrapper passes none and the width is
-    :func:`~blades_tpu.ops.pallas_select.stripe_cols` of the matrix's
-    height.
+    ``cols``: the columns of one grid step, for the sake of the tests and
+    of ``tools/chip_kernels.py --sweep`` only (they force 512, or sweep
+    it, to compare widths); the public wrapper passes none and the width
+    is :func:`~blades_tpu.ops.pallas_select.stripe_cols` of the matrix's
+    height, or :func:`~blades_tpu.ops.pallas_select.plane_cols` of it on
+    planes (``s = cols // 128`` sublanes of a plane).
     """
-    nb, d = updates.shape
+    nb, *tail = updates.shape
+    plane = len(tail) == 2
+    if plane and tail[1] != 128:
+        raise ValueError(f"a row-plane matrix is (rows, d // 128, 128), "
+                         f"got {updates.shape}")
+    d = math.prod(tail)
+    one = (1,) * len(tail)
     if num_real is not None:
         if not (0 < num_real <= nb):
             raise ValueError(f"num_real={num_real} out of range for {nb} rows")
@@ -600,10 +700,20 @@ def _fused_finish_compact_jit(
             raise ValueError(
                 f"forge_noise must be ({d},), got {forge_noise.shape}"
             )
-        rbuf = forge_noise.astype(jnp.float32)[None, :]
+        rbuf = forge_noise.astype(jnp.float32).reshape((1, *tail))
+    elif plane:
+        # No forge reads it: at d = 4.1e8 a zero row is 1.66 GB.
+        rbuf = None
     else:
         rbuf = jnp.zeros((1, d), jnp.float32)
-    if num_real is not None:
+    if plane:
+        # The rows lie on the major axis: any number of them is whole
+        # vregs, and the MXU variants (contractions over sublane rows)
+        # have nothing to contract.
+        npad = updates.shape[0]
+        wb = (jnp.arange(npad) < nb).astype(jnp.float32).reshape(npad, *one)
+        radix_mxu = stats_mxu = False
+    elif num_real is not None:
         # Caller pre-padded to a sublane multiple with +inf rows.
         npad = updates.shape[0]
         if npad % 8:
@@ -618,30 +728,33 @@ def _fused_finish_compact_jit(
             updates = jnp.concatenate([updates, pad], axis=0)
             wb = jnp.concatenate(
                 [wb, jnp.zeros((npad - nb, 1), jnp.float32)], axis=0)
-    cols = cols or stripe_cols(npad)
-    updates, rbuf, dpad = _pad_to_stripes(updates, rbuf, cols)
+    cols = cols or (plane_cols(npad) if plane else stripe_cols(npad))
+    # What the grid walks along axis 1: columns, or a plane's sublanes.
+    step = cols // 128 if plane else cols
+    updates, rbuf, dpad = _pad_to_stripes(updates, rbuf, step)
+    block = (step, 128) if plane else (cols,)
 
     kernel = functools.partial(
         _compact_kernel, nb_true=nb, mult=forged_mult, forge=forge, agg=agg,
         sanitize=sanitize, keys16=updates.dtype == jnp.bfloat16,
         radix_mxu=radix_mxu, stats_mxu=stats_mxu,
     )
-    stripe, rows1, row = _block_specs(npad, cols)
+    stripe, rows1, row = _block_specs(npad, *block)
+    row_shape = jax.ShapeDtypeStruct((1, dpad, *tail[1:]), jnp.float32)
+    rows1_shape = jax.ShapeDtypeStruct((npad, *one), jnp.float32)
     agg_vec, sq, bad, forged = pl.pallas_call(
         kernel,
-        grid=(dpad // cols,),
-        in_specs=[stripe, rows1, row],
+        grid=(dpad // step,),
+        in_specs=[stripe, rows1] + [row] * (rbuf is not None),
         out_specs=[row, rows1, rows1, row],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, dpad), jnp.float32),
-            jax.ShapeDtypeStruct((npad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((npad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, dpad), jnp.float32),
-        ],
+        out_shape=[row_shape, rows1_shape, rows1_shape, row_shape],
         compiler_params=stripe_compiler_params(npad, cols=cols),
         interpret=interpret,
-    )(updates, wb, rbuf)
-    return agg_vec[0, :d], sq[:nb, 0], bad[:nb, 0] > 0, forged[0, :d]
+    )(updates, wb, *(() if rbuf is None else (rbuf,)))
+    flat = ((lambda v: v.reshape(-1)[:d]) if plane
+            else (lambda v: v[0, :d]))
+    per_row = (slice(nb),) + (0,) * len(tail)
+    return flat(agg_vec), sq[per_row], bad[per_row] > 0, flat(forged)
 
 
 def fused_finish(

@@ -31,7 +31,10 @@ for.  Whoever allocates a matrix for them pads its columns to
 :func:`stripe_padded`, a multiple of 512, so every kernel here is served
 by the same allocation.  ``sanitize`` in the fused finishes is
 stripe-local, so its granularity is that width too: a non-finite value
-blanks its row over the columns of its stripe.
+blanks its row over the columns of its stripe.  A matrix whose rows are
+planes (``(rows, d // 128, 128)``: blocks under a storage tile,
+parallel/streamed.py::compact_matrix) is walked by the compact fused
+finish alone, in blocks of :func:`plane_cols` columns.
 """
 
 from __future__ import annotations
@@ -88,6 +91,31 @@ def stripe_padded(d: int, rows: int) -> int:
     at, so that no pad inside the call copies it."""
     cols = stripe_cols(rows)
     return -(-d // cols) * cols
+
+
+# The same for a matrix whose rows are planes, ``(rows, d // 128, 128)``
+# (the layout of a matrix whose blocks lie under a storage tile:
+# parallel/streamed.py::compact_matrix): a block of the fused compact
+# finish there is ``(rows, s, 128)``, and a one-row value is ``(1, s,
+# 128)``, an eighth of what it fills on the sublanes, so the search's two
+# are reckoned as two rows.  Chosen by the same sweep over 6.6 GB of bf16
+# on a v5e (``tools/chip_kernels.py --sweep planes``; PERF.md §6, PR 32):
+# the time is flat within 2% over 6144-10240 columns at 8 rows (79-81 ms
+# the whole call, 88 at 4096, 86 at 12288), over 4096-6144 at 16 and at 24
+# rows (68 and 64 ms; 90 and 75 at 2048), and this budget gives 10240,
+# 6144 and 4096.
+_PLANE_BUDGET = 448 << 10
+_PLANE_STEP = 16 * 128  # whole bf16 vregs: 16 sublanes of 128 lanes
+
+
+def plane_cols(rows: int) -> int:
+    """Columns (``s x 128``) of one grid step of the compact finish over
+    a row-plane matrix ``rows`` high: the multiple of 2048 (16 sublanes,
+    whole vregs of either storage width) at which the block and the
+    search's two one-row values fill ``_PLANE_BUDGET``; under the 8 rows
+    at which the kernels' gate opens, the width at 8."""
+    return max(_PLANE_STEP, _PLANE_BUDGET // ((max(rows, 8) + 2) * 4)
+               // _PLANE_STEP * _PLANE_STEP)
 
 
 def stripe_compiler_params(rows: int, extra_bytes: int = 0,

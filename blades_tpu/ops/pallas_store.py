@@ -1,14 +1,31 @@
-"""Whole-tile row-block store into the streamed round's update matrix.
+"""Row-block stores into the streamed round's update matrix.
 
-``lax.dynamic_update_slice`` at a runtime row offset takes XLA's general
-emitter on TPU: a read-modify-write of partial tiles with a runtime
-sublane shift, ~100 GB/s of rows stored on a v5e, whatever XLA knows of
-the offset's low bits (only a CONSTANT offset marks the op
-``is_index_aligned``; measured in PR 26).  Where a block is a whole
-number of storage tiles landing on a tile boundary, the store is a plain
-copy: this kernel streams the block's ``(lanes, d)`` rows through VMEM
-into row-block ``block_index`` of the matrix, which it aliases, so
-nothing else of the matrix moves.
+HBM holds a ``(rows, d)`` matrix in storage tiles of 8 sublanes of 32-bit
+words x 128 lanes: 8 rows of a 4-byte type, 16 of a 2-byte one (two rows
+share every word).  ``lax.dynamic_update_slice`` at a runtime row offset
+takes XLA's general emitter on TPU: a read-modify-write of every tile the
+rows touch, with a runtime sublane shift, whatever XLA knows of the
+offset's low bits (only a CONSTANT offset marks the op
+``is_index_aligned``; measured in PR 26).  For 16 rows of 752 that was
+~100 GB/s of rows stored on a v5e; for ONE row of 8 it is all of the
+matrix, read and written, for each row (36 ms for a 0.83 GB row of a 6.6
+GB matrix: 390 GB/s of traffic of which a sixteenth is the row; PR 32).
+Two plain copies take its place:
+
+- **Blocks of whole tiles** (:func:`store_row_block`).  Where a block is
+  a whole number of storage tiles landing on a tile boundary, this Mosaic
+  kernel streams the block's ``(lanes, d)`` rows through VMEM into
+  row-block ``block_index`` of the matrix, which it aliases, so nothing
+  else of the matrix moves.
+- **Blocks under a tile** (:func:`row_planes`).  No kernel can write part
+  of a tile: a DMA moves whole tiles in any layout that keeps the rows on
+  the second-minor axis (a Mosaic copy of one word-row of the matrix
+  viewed as ``u32[4, W]`` is refused: "Slice shape along dimension 0 must
+  be aligned to tiling (4), but is 1").  So the matrix itself keeps such
+  rows on the MAJOR axis, ``(rows, d_alloc // 128, 128)``, a row a plane
+  of whole tiles (parallel/streamed.py::compact_matrix), and there
+  ``lax.dynamic_update_slice`` of ``row_planes(upd)`` at ``(row, 0, 0)``
+  is XLA's own contiguous aligned copy, in place.
 
 A round whose trained lanes are no whole number of blocks pads its last
 block (parallel/streamed.py::block_plan): that block's first ``surplus``
@@ -53,6 +70,30 @@ def store_applicable(rows: int, width: int, lanes: int, row0: int,
     last block lands inside it."""
     return (kernel_applicable(lanes, width) and lanes % tile == 0
             and row0 % lanes == 0 and rows % lanes == 0)
+
+
+def row_planes(upd, tail):
+    """``(lanes, d)`` rows as ``(lanes,) + tail`` planes of a matrix
+    ``(rows,) + tail`` (``tail``: ``(d_alloc // 128, 128)``), the columns
+    past ``d`` zero: what ``lax.dynamic_update_slice`` then writes at
+    ``(row, 0, 0)`` as a contiguous copy.
+
+    ONE lane may come as the params' pytree of its update's leaves, each
+    ``(1, ...)`` (``Task.local_round_batched(ravel_update=False)``), and
+    is then concatenated in ONE dimension: a 2-byte ``(1, d)`` row is
+    laid out ``T(2,128)(2,1)``, a second, empty row in every 32-bit word
+    (1.66 GB for 0.83 GB of row at d = 4.1e8), every leaf is re-tiled on
+    its way into it and the whole of it once more on its way out (8.5 ms
+    a block on a v5e for that last pass alone: PERF.md §6, PR 32),
+    whereas a ``(d,)`` vector is dense and reaches the plane as a
+    bitcast."""
+    width = tail[0] * tail[1]
+    if not isinstance(upd, jax.Array):
+        flat = jnp.concatenate([leaf.reshape(-1)
+                                for leaf in jax.tree.leaves(upd)])
+        return jnp.pad(flat, (0, width - flat.size)).reshape((1, *tail))
+    return jnp.pad(upd, ((0, 0), (0, width - upd.shape[1]))).reshape(
+        (upd.shape[0], *tail))
 
 
 def _copy_kernel(scalars_ref, upd_ref, mat_ref, out_ref, *, d: int,

@@ -62,7 +62,13 @@ rows only and the forged row enters the order statistics as a virtual
 row of multiplicity f (``fused_finish_compact``) — per-row kernel work
 and matrix HBM shrink by the byzantine fraction (9.8 -> 7.4 GB at the
 benchmark scale, and ResNet-18 fits n=768 on one chip).  Every other
-configuration falls back to the chunked path.
+configuration falls back to the chunked path.  Where the compact matrix's
+blocks lie under a storage tile (a language model a lane, 8 rows in
+blocks of one) it keeps a row a PLANE, ``(rows, d_alloc // 128, 128)``
+(:func:`block_plan`, :func:`compact_matrix`): a row is then whole tiles,
+so its store is a contiguous copy and not a rewrite of the matrix, and
+the finish counts over whole vregs (ops/pallas_store.py,
+ops/pallas_round.py).  Every other path keeps ``(rows, d)``.
 
 1000 clients x ResNet-10 (d=4.9M) in bf16 = 9.8 GB: fits a single 16 GB
 v5e chip with ~1 GB chunk workspace.  ResNet-18 at n=1000 (22.3 GB bf16)
@@ -71,6 +77,7 @@ does NOT fit one chip — that is what the mesh is for.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Callable, NamedTuple
 
@@ -87,8 +94,14 @@ from blades_tpu.adversaries.update_attacks import (
     NoiseAdversary,
 )
 from blades_tpu.core.round import FedRound, RoundState
+from blades_tpu.core.task import identity_round_end_hook
 from blades_tpu.data.sampler import sample_client_batches_with_keys
 from blades_tpu.obs.trace import span
+# Imported here and not inside ``_train_block``: ``pallas_store`` binds the
+# kernels' gate by name when it is first imported, and a first import
+# inside a traced function would bind whatever a caller had put in the
+# gate's place at that moment (the tests' monkeypatches).
+from blades_tpu.ops import pallas_store
 from blades_tpu.ops.aggregators import Mean, Median, Trimmedmean
 
 _COORDWISE_FORGERS = (ALIEAdversary, IPMAdversary, NoiseAdversary,
@@ -122,8 +135,14 @@ class BlockPlan(NamedTuple):
     tile: int
     # Every store is a whole number of storage tiles at a whole-block
     # row of a matrix a whole number of blocks high: the tile copy's
-    # geometry (ops/pallas_store.py), whatever the backend.
+    # geometry (ops/pallas_store.py), whatever the backend.  Or every
+    # store is a row's whole plane (``planes``).
     whole_tiles: bool
+    # Blocks under a storage tile, stored for the compact fused finish:
+    # the matrix keeps a row a plane, ``(rows, d_alloc // 128, 128)``
+    # (:func:`compact_matrix`), where any number of rows is a whole
+    # number of tiles at an aligned offset.
+    planes: bool = False
 
     @property
     def aligned_stores(self) -> int:
@@ -139,7 +158,13 @@ def block_plan(n: int, prefix: int, client_block: int, dtype, *,
     tiles of 8 sublanes of 32-bit words, so a storage tile is 8 rows of
     a 4-byte ``dtype`` and 16 of a 2-byte one; a block stores as a plain
     copy (ops/pallas_store.py) only where it is a whole number of tiles
-    at a tile boundary.
+    at a tile boundary.  A block UNDER a tile is a part of every tile it
+    touches in a ``(rows, d)`` matrix, where no store can write it alone
+    (a DMA moves whole tiles; XLA reads and rewrites the matrix's every
+    tile for it).  So the compact matrix of such blocks keeps a row a
+    plane (``planes``; :func:`compact_matrix`): the rows lie on the major
+    axis, a row is whole tiles of its own, and the store is a contiguous
+    copy.  A rule of the shapes alone: ``block < tile and compact``.
     The dispatch size is the largest multiple of the tile's rows under
     ``client_block`` (and under the lanes there are), or that bound
     itself where it is under one tile.  Every dispatch has that size:
@@ -159,8 +184,35 @@ def block_plan(n: int, prefix: int, client_block: int, dtype, *,
         first_lane = first_row = prefix // block * block
     blocks = -(-(n - first_lane) // block)
     surplus = blocks * block - (n - first_lane)
+    planes = compact and block < tile
     return BlockPlan(first_lane, first_row, block, blocks, surplus, tile,
-                     block % tile == 0 and (compact or not surplus))
+                     planes or (block % tile == 0
+                                and (compact or not surplus)), planes)
+
+
+def compact_matrix(plan: BlockPlan, nb: int, d: int):
+    """``(shape, cols)``: the benign-compacted update matrix of ``nb``
+    rows of a ``d``-wide model under ``plan``, and the columns one grid
+    step of its fused finish takes (ops/pallas_round.py), of which the
+    matrix is allocated a whole number wide so that no pad inside the
+    call copies it.  Whoever builds that matrix (``step``, the tools'
+    compile-only twins, ``chip_smoke.py``) builds it here.
+
+    Blocks of whole tiles: ``(rows, d_alloc)``, a whole number of blocks
+    and of sublanes high, the rows past ``nb`` holding ``+inf``.  Blocks
+    under a tile (``plan.planes``): ``(nb, d_alloc // 128, 128)``, a row
+    a plane; the rows lie on no sublane and need no padding."""
+    from blades_tpu.ops.pallas_select import (
+        plane_cols,
+        stripe_cols,
+        stripe_padded,
+    )
+
+    if plan.planes:
+        cols = plane_cols(nb)
+        return (nb, -(-d // cols) * (cols // 128), 128), cols
+    rows = -(-(plan.blocks * plan.block) // 8) * 8
+    return (rows, stripe_padded(d, rows)), stripe_cols(rows)
 
 
 def _fused_spec(fr: FedRound):
@@ -359,19 +411,19 @@ def streamed_step(
         not stored (the host drops their losses and norms).
 
         Blocks of whole storage tiles are stored by the aliased tile
-        copy (ops/pallas_store.py) on a TPU; blocks under a tile, a full
-        matrix with a short last block and other backends take
-        ``lax.dynamic_update_slice``, whose TPU emitter read-modify-writes
-        partial tiles at any runtime offset, aligned or not.
+        copy (ops/pallas_store.py) on a TPU.  Into a matrix of row planes
+        (three-dimensional: ``compact_matrix``) the rows are padded to
+        its width here and written by ``lax.dynamic_update_slice`` at
+        ``(row, 0, 0)``: whole tiles at an aligned offset, a contiguous
+        copy in place.  Blocks under a tile of a ``(rows, d)`` matrix, a
+        full matrix with a short last block and other backends take the
+        same operation, whose TPU emitter there reads and rewrites every
+        tile the rows touch (all of the matrix, for one row), at any
+        runtime offset, aligned or not.
 
         Device scopes (trace-time metadata, the dense body's names):
         ``blades/sample``, ``blades/step``, and ``blades/store`` for the
         norms and the writes into the matrix and ``client_opt``."""
-        from blades_tpu.ops.pallas_store import (
-            store_applicable,
-            store_row_block,
-        )
-
         block = plan.block
         index = index.astype(jnp.uint32)
         row0 = index * jnp.uint32(block) + jnp.uint32(plan.first_lane)
@@ -403,10 +455,17 @@ def streamed_step(
         # Non-DP rounds cast per leaf inside the block (same bf16 bits,
         # half the assembly traffic); DP needs the f32 row norms BEFORE
         # storage rounding, so there the cast stays at the buffer write.
+        # Into a matrix of row planes ONE lane's update stays a pytree of
+        # leaves, which the store lays out in one dimension: a (1, d) row
+        # of a 2-byte type would be half padding (row_planes).
+        planes = updates_buf.ndim == 3
+        as_leaves = (planes and block == 1 and not dp
+                     and hooks.round_end is identity_round_end_hook)
         with jax.named_scope("blades/step"):
             upd, opt2, loss, stats = fr.task.local_round_batched(
                 params, opt_b, bx, by, sl(train_keys), sl(malicious), *hooks,
                 out_dtype=None if dp else update_dtype,
+                ravel_update=not as_leaves,
             )
         with jax.named_scope("blades/store"):
             # Full-row L2 norms, taken on the f32 updates BEFORE
@@ -414,21 +473,23 @@ def streamed_step(
             # cannot recover from the matrix later.  Gated: the O(n*d)
             # reduction is pure waste on non-DP rounds.
             norms = (jnp.linalg.norm(upd, axis=1) if dp
-                     else jnp.zeros((upd.shape[0],), jnp.float32))
+                     else jnp.zeros((block,), jnp.float32))
+            if planes:
+                upd = pallas_store.row_planes(upd, updates_buf.shape[1:])
             upd = upd.astype(update_dtype)
-            if store_applicable(*updates_buf.shape, block, plan.first_row,
-                                plan.tile):
+            if not planes and pallas_store.store_applicable(
+                    *updates_buf.shape, block, plan.first_row, plan.tile):
                 # Whole tiles at a tile boundary: a plain aliased copy of
                 # block `index`, the padded last block's included (its
                 # surplus rows dropped inside the copy).
-                updates_buf = store_row_block(
+                updates_buf = pallas_store.store_row_block(
                     updates_buf, upd,
                     index + jnp.uint32(plan.first_row // block),
                     head if plan.surplus else None, surplus=plan.surplus)
             else:
                 buf_row0 = lane0 - jnp.uint32(
                     plan.first_lane - plan.first_row)
-                at = (buf_row0, jnp.uint32(0))
+                at = (buf_row0,) + (jnp.uint32(0),) * (upd.ndim - 1)
                 if plan.surplus:
                     upd = keep(upd, lax.dynamic_slice(
                         updates_buf, at, upd.shape))
@@ -553,7 +614,7 @@ def streamed_step(
         if spec[0] is not None and spec[0][0] == "adaptive":
             with jax.named_scope("blades/forge"):
                 noise = jax.random.uniform(k_adv, (d,), jnp.float32)
-                d_alloc = updates_buf.shape[1]
+                d_alloc = math.prod(updates_buf.shape[1:])
                 if d_alloc != d:
                     noise = jnp.pad(noise, (0, d_alloc - d))
         return d, noise
@@ -846,39 +907,42 @@ def streamed_step(
                         "as benign on the compacted path)"
                     )
                 _checked_mask[0] = malicious
-            # The compact matrix is a whole number of blocks (and of
-            # sublanes) high: its +inf padding rows, which the kernel
-            # excludes via num_real, take a padded last block's surplus.
-            rows = -(-(plan.blocks * plan.block) // 8) * 8 if compact else n
-            # The fused pallas finishes want stripe-aligned columns; padding
-            # at allocation (zero columns, sliced off the aggregate) avoids a
-            # whole-matrix pad copy inside the kernel call.  Their stripe
-            # is as wide as the matrix's height allows (stripe_cols), a
-            # multiple of the 512 the row-stats kernel walks.  The
-            # row-geometry path pads for that kernel whenever it can serve
-            # its planner bundles (chunk traversals are bounded to d_model
-            # either way, so padding is inert on the fallback path).
+            # The fused pallas finishes want their columns a whole number
+            # of grid steps wide; padding at allocation (zero columns,
+            # sliced off the aggregate) avoids a whole-matrix pad copy
+            # inside the kernel call.  The compact matrix is also a whole
+            # number of blocks (and of sublanes) high, its +inf padding
+            # rows, which the kernel excludes via num_real, taking a
+            # padded last block's surplus; or it keeps a row a plane
+            # (compact_matrix).  The full fused finish's stripe is as wide
+            # as the matrix's height allows (stripe_cols), a multiple of
+            # the 512 the row-stats kernel walks.  The row-geometry path
+            # pads for that kernel whenever it can serve its planner
+            # bundles (chunk traversals are bounded to d_model either way,
+            # so padding is inert on the fallback path).
             from blades_tpu.ops.pallas_select import (
                 _BLOCK_D,
                 stripe_cols,
                 stripe_padded,
             )
 
-            finish_cols, d_alloc = None, d_model
-            if use_fused:
-                finish_cols = stripe_cols(rows)
-                d_alloc = stripe_padded(d_model, rows)
+            finish_cols, shape = None, (n, d_model)
+            if compact:
+                shape, finish_cols = compact_matrix(plan, nb, d_model)
+            elif use_fused:
+                finish_cols = stripe_cols(n)
+                shape = (n, stripe_padded(d_model, n))
             elif row_geom or row_forges:
                 from blades_tpu.ops.pallas_rowstats import (
                     kernel_applicable as _rowstats_ok,
                 )
 
                 if _rowstats_ok(n, d_model):
-                    d_alloc = -(-d_model // _BLOCK_D) * _BLOCK_D
-            if compact and rows != nb:
-                updates_buf = _alloc_row_padded(rows, nb, d_alloc)
+                    shape = (n, -(-d_model // _BLOCK_D) * _BLOCK_D)
+            if compact and shape[0] != nb:
+                updates_buf = _alloc_row_padded(shape[0], nb, shape[1])
             else:
-                updates_buf = jnp.zeros((rows, d_alloc), update_dtype)
+                updates_buf = jnp.zeros(shape, update_dtype)
             client_opt = state.client_opt
             if not donate:
                 client_opt = jax.tree.map(jnp.copy, client_opt)

@@ -158,7 +158,7 @@ def finish_that_ran(algo, compiles: CompileLog) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from blades_tpu.ops.pallas_select import stripe_padded
+    from blades_tpu.parallel.streamed import block_plan, compact_matrix
 
     want = "jit(_finish_fused_compact)"
     ran = sorted({"jit(_finish)", "jit(_finish_fused)", want}
@@ -168,9 +168,12 @@ def finish_that_ran(algo, compiles: CompileLog) -> dict:
                              f"expected only {want}")
     cfg = algo.config
     n, f = cfg.num_clients, cfg.num_malicious_clients
-    rows = -(-(n - f) // 8) * 8
-    cols = stripe_padded(algo._num_params, rows)
-    buf = jax.ShapeDtypeStruct((rows, cols), jnp.dtype(cfg.update_dtype))
+    # The matrix by the rule the round builds it by (two-dimensional for
+    # blocks of whole storage tiles, a row a plane for blocks under one).
+    dtype = jnp.dtype(cfg.update_dtype)
+    plan = block_plan(n, f, cfg.client_block, dtype, compact=True)
+    matrix, _ = compact_matrix(plan, n - f, algo._num_params)
+    buf = jax.ShapeDtypeStruct(matrix, dtype)
     losses = jax.ShapeDtypeStruct((n,), jnp.float32)
     compiled = compiles.programs[want]
     hlo = algo._step.finish_fused_compact.lower(
@@ -178,13 +181,13 @@ def finish_that_ran(algo, compiles: CompileLog) -> dict:
         jax.random.PRNGKey(0), nb_real=n - f).compile().as_text()
     if compiles.programs[want] != compiled:
         raise AssertionError(
-            f"lowering {want} at ({rows}, {cols}) compiled a new program: "
+            f"lowering {want} at {matrix} compiled a new program: "
             "the inspected program is not the one the rounds ran")
     calls = hlo.count("tpu_custom_call")
     if calls < 1:
         raise AssertionError("no tpu_custom_call in the compiled finish")
     return {"finish_program": want, "times_compiled": compiled,
-            "matrix": [rows, cols], "tpu_custom_calls_in_hlo": calls}
+            "matrix": list(matrix), "tpu_custom_calls_in_hlo": calls}
 
 
 def kernel_vs_reference() -> dict:

@@ -2,7 +2,9 @@
 
 - Every Pallas kernel lowers for ``platforms=["tpu"]`` at its gate's
   edge shapes (``tools/chip_kernels.CASES`` — the same table the chip
-  run compiles and compares): catches Pallas API drift in seconds.
+  run compiles and compares): catches Pallas API drift in seconds.  The
+  table's cases of XLA's own code (the store into row planes, ISSUE 32)
+  lower with no Mosaic call.
   Whether Mosaic then *compiles* them is the chip's answer
   (``chiprun -- python tools/chip_kernels.py``).
 - GSPMD cannot partition a Mosaic custom call, so the flat mesh round
@@ -29,7 +31,9 @@ from tools.chip_kernels import CASES  # noqa: E402
 def test_pallas_kernel_lowers_for_tpu(case):
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in case.inputs()]
     exported = jax.export.export(jax.jit(case.run), platforms=["tpu"])(*args)
-    assert "tpu_custom_call" in exported.mlir_module()
+    # A case that is XLA's own code (the store into row planes) holds no
+    # Mosaic call, and must hold none.
+    assert ("tpu_custom_call" in exported.mlir_module()) == case.mosaic
 
 
 def test_gspmd_round_traces_jnp_aggregators_not_mosaic(monkeypatch):

@@ -62,6 +62,55 @@ def test_local_round_update_is_param_delta(tiny):
     assert float(loss) > 0
 
 
+@pytest.mark.parametrize("lanes", [1, 3])
+def test_unraveled_update_is_the_raveled_one_leaf_by_leaf(tiny, lanes):
+    """``ravel_update=False`` (ISSUE 32): the block's updates as the
+    params' pytree, cast per leaf and not concatenated, for a caller
+    that lays a row out itself.  Concatenated leaf by leaf they are the
+    raveled updates to the bit, and ``row_planes`` lays one lane's leaves
+    out as it lays out that lane's raveled row."""
+    from blades_tpu.ops.pallas_store import row_planes
+
+    ds, fr, state, (x, y, ln) = tiny
+    bx, by = sample_client_batches(jax.random.PRNGKey(3), x, y, ln, 16, 2)
+    keys = jax.random.split(jax.random.PRNGKey(4), lanes)
+    args = (state.server.params,
+            jax.tree.map(lambda a: a[:lanes], state.client_opt),
+            bx[:lanes], by[:lanes], keys, jnp.zeros((lanes,), bool))
+    flat = fr.task.local_round_batched(*args, out_dtype=jnp.bfloat16)[0]
+    tree = fr.task.local_round_batched(*args, out_dtype=jnp.bfloat16,
+                                       ravel_update=False)[0]
+    assert (jax.tree.structure(tree)
+            == jax.tree.structure(state.server.params))
+    leaves = jax.tree.leaves(tree)
+    assert all(leaf.dtype == jnp.bfloat16 and leaf.shape[0] == lanes
+               for leaf in leaves)
+    np.testing.assert_array_equal(
+        np.asarray(jnp.concatenate(
+            [leaf.reshape(lanes, -1) for leaf in leaves], axis=1),
+            np.float32),
+        np.asarray(flat, np.float32))
+    if lanes == 1:
+        tail = (-(-flat.shape[1] // 2048) * 16, 128)
+        np.testing.assert_array_equal(
+            np.asarray(row_planes(tree, tail), np.float32),
+            np.asarray(row_planes(flat, tail), np.float32))
+
+
+def test_unraveled_update_needs_the_per_leaf_cast_s_case(tiny):
+    ds, fr, state, (x, y, ln) = tiny
+    bx, by = sample_client_batches(jax.random.PRNGKey(3), x, y, ln, 16, 2)
+    args = (state.server.params,
+            jax.tree.map(lambda a: a[0], state.client_opt),
+            bx[0], by[0], jax.random.PRNGKey(4), jnp.array(False))
+    with pytest.raises(ValueError, match="ravel_update"):
+        fr.task.local_round(*args, ravel_update=False)   # no out_dtype
+    with pytest.raises(ValueError, match="ravel_update"):
+        fr.task.local_round(*args, out_dtype=jnp.bfloat16,
+                            round_end_hook=lambda u, m: u,
+                            ravel_update=False)
+
+
 def test_server_step_applies_update_direction(tiny):
     ds, fr, state, _ = tiny
     ravel, _, d = ravel_fn(state.server.params)

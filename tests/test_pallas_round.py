@@ -16,6 +16,7 @@ from blades_tpu.adversaries.base import benign_mean_std
 from blades_tpu.ops.pallas_round import fused_finish
 from blades_tpu.ops.pallas_select import (
     _BLOCK_D,
+    plane_cols,
     stripe_cols,
     stripe_compiler_params,
     stripe_padded,
@@ -202,6 +203,201 @@ def test_compact_sanitize_is_local_to_the_wide_stripe():
                                       np.asarray(blanked[i]))
         np.testing.assert_array_equal(np.asarray(wide[i])[outside],
                                       np.asarray(narrow[i])[outside])
+
+
+# ---------------------------------------------------------------------------
+# A matrix whose rows are planes (ISSUE 32): the same body over
+# (rows, d // 128, 128), against the two-dimensional compact finish
+# ---------------------------------------------------------------------------
+
+
+def _planes(x):
+    """``(rows, d)`` as ``(rows, ceil(d / 128), 128)``, zero columns past
+    ``d``: the layout parallel/streamed.py::compact_matrix allocates."""
+    rows, d = x.shape
+    width = -(-d // 128) * 128
+    return jnp.pad(x, ((0, 0), (0, width - d))).reshape(rows, width // 128,
+                                                        128)
+
+
+@pytest.mark.parametrize("rows", [1, 8, 10, 16, 24, 32, 64, 2048])
+def test_plane_cols_is_whole_vregs_of_either_storage(rows):
+    """A grid step of the plane finish takes s x 128 columns, s a multiple
+    of 16 sublanes (whole vregs of bf16 and of float32 storage), never
+    more at a taller matrix, and within the VMEM that is granted."""
+    cols = plane_cols(rows)
+    assert cols % (16 * 128) == 0 and cols >= 16 * 128
+    assert plane_cols(rows + 1) <= cols
+    limit = stripe_compiler_params(rows, cols=cols).vmem_limit_bytes
+    assert (16 << 20) <= limit <= (8 * 2048 + 128) * 2048 * 4
+    if rows <= 64:
+        assert limit == 16 << 20
+
+
+_PLANE_AGGS = [("median",), ("trimmed", 2), ("mean",)]
+_PLANE_FORGES = [("alie", 0.7), ("ipm", 1.5), ("adaptive", 1.5)]
+# Heights 8, 10 and 24 with d on and off a block's edge; the aggregator x
+# forge grid whole at every geometry pair, the storage alternating.
+_PLANE_GRID = [
+    (rows, blocks * plane_cols(rows) + off, agg, forge,
+     (jnp.bfloat16, jnp.float32)[(i + j + k) % 2])
+    for i, (rows, blocks, off) in enumerate([
+        (8, 1, 0), (8, 1, 74), (10, 2, 0), (10, 1, -130), (24, 3, 0),
+        (24, 2, 1)])
+    for j, agg in enumerate(_PLANE_AGGS)
+    for k, forge in enumerate(_PLANE_FORGES)
+    if (i + j + k) % 2 == 0 or rows == 8
+]
+
+
+@pytest.mark.parametrize("rows", [3, 8, 10, 16, 24])
+def test_plane_row_sums_are_added_in_the_sublanes_order(rows):
+    """Where the rows lie on the sublanes Mosaic adds them vreg to vreg
+    (8 rows each, the rows past the last as zeros) and then folds the 8
+    sublanes as a butterfly of shifts 4, 2, 1 (measured on the chip,
+    PERF.md §6, PR 32).  Over row planes the float sums are written out
+    in that order, so that both layouts give the forged row the same
+    float32 mean and deviation, to the bit."""
+    from blades_tpu.ops.pallas_round import _sum_rows
+
+    rng = np.random.default_rng(rows)
+    x = (rng.normal(size=(rows, 16, 128))
+         * np.exp(2 * rng.normal(size=(rows, 16, 128)))).astype(np.float32)
+    padded = np.zeros((-(-rows // 8) * 8, 16, 128), np.float32)
+    padded[:rows] = x
+    v = padded[:8].copy()
+    for group in padded[8:].reshape(-1, 8, 16, 128):
+        v = v + group
+    want = (((v[0] + v[4]) + (v[2] + v[6]))
+            + ((v[1] + v[5]) + (v[3] + v[7])))
+    got = np.asarray(jax.jit(_sum_rows)(jnp.asarray(x)))
+    assert got.shape == (1, 16, 128)
+    np.testing.assert_array_equal(got[0].view(np.uint32),
+                                  want.view(np.uint32))
+    # Two dimensions: the reduction itself, as ever.
+    flat = jnp.asarray(x.reshape(rows, -1))
+    np.testing.assert_array_equal(
+        np.asarray(_sum_rows(flat)),
+        np.asarray(jnp.sum(flat, axis=0, keepdims=True)))
+
+
+@pytest.mark.parametrize("rows,d,agg,forge,dtype", _PLANE_GRID, ids=_width_id)
+def test_plane_finish_equals_the_two_dimensional_compact_finish(
+        rows, d, agg, forge, dtype):
+    """The same values as (rows, d) and as row planes give the same
+    aggregate and forged row, the row norms to 2e-6 relative (float32
+    sums in another order), no row flagged.  The plane call returns the
+    matrix's whole width: past d lie the zero columns.
+
+    To the bit on the chip, where both layouts add a column's rows in
+    one order (``_sum_rows``; ``tools/chip_kernels.py``'s ``plane_*``
+    cases hold ``same_bits`` there).  Here the interpreter runs the
+    two-dimensional body as an XLA:CPU program that adds the rows in an
+    order of its own, so the forge's float32 mean and deviation may
+    differ in the last place: float32 storage is held to 2e-6, as in the
+    widths' test above, and on bf16 storage that last place flips the
+    forged row's rounding in a column of some hundreds, by one bf16
+    place (and with it a Median or a kept sum that lands on the forged
+    row): every other column is equal to the bit."""
+    from blades_tpu.ops import pallas_round
+
+    rng = np.random.default_rng(seed=rows * 11 + d)
+    x = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32).astype(dtype)
+    x3 = _planes(x)
+    noise = noise3 = None
+    if forge[0] == "adaptive":
+        noise = jax.random.uniform(jax.random.PRNGKey(d), (d,), jnp.float32)
+        noise3 = jnp.pad(noise, (0, x3[0].size - d))
+
+    def call(x, noise):
+        return pallas_round._fused_finish_compact_jit(
+            x, noise, forged_mult=3, forge=forge, agg=agg, sanitize=True,
+            interpret=True)
+
+    flat, plane = call(x, noise), call(x3, noise3)
+    assert plane[0].shape == plane[3].shape == (x3[0].size,)
+    for i in (0, 3):   # the aggregate and the forged row
+        got, want = np.asarray(plane[i])[:d], np.asarray(flat[i])
+        if dtype == jnp.bfloat16 and (i == 3 or agg[0] == "median"):
+            off = got.view(np.uint32) != want.view(np.uint32)
+            assert off.mean() <= 1e-2
+            np.testing.assert_allclose(got[off], want[off], rtol=2.0 ** -7,
+                                       atol=1e-6)
+        else:   # float32 sums, or float32 storage
+            np.testing.assert_allclose(got, want, rtol=0, atol=2e-6 if
+                                       dtype == jnp.float32 else 2.0 ** -8)
+    assert plane[1].shape == plane[2].shape == (rows,)
+    assert not np.asarray(plane[2]).any()
+    np.testing.assert_allclose(np.asarray(plane[1]), np.asarray(flat[1]),
+                               rtol=2e-6)
+
+
+@pytest.mark.parametrize("rows", [8, 10, 24])
+def test_plane_sanitize_blanks_a_poisoned_row_over_its_block_only(rows):
+    """sanitize is local to a grid step's block, as it is local to a
+    stripe in two dimensions: a non-finite value blanks its row over the
+    plane_cols(rows) columns of its block, the flag is the 2-D kernel's,
+    and every column outside that block has the 2-D kernel's bits."""
+    from blades_tpu.ops import pallas_round
+
+    cols = plane_cols(rows)
+    d = 2 * cols + 300
+    rng = np.random.default_rng(seed=rows)
+    x = jnp.asarray(rng.normal(size=(rows, d)), jnp.bfloat16)
+    at = cols + 700                             # in the second block
+    x = x.at[5, at].set(jnp.inf)
+
+    def call(x):
+        return pallas_round._fused_finish_compact_jit(
+            x, None, forged_mult=2, forge=("alie", 0.7), agg=("median",),
+            sanitize=True, interpret=True)
+
+    plane, flat = call(_planes(x)), call(x)
+    blanked = call(_planes(x.at[5, cols:2 * cols].set(0)))
+    assert list(np.nonzero(np.asarray(plane[2]))[0]) == [5]
+    np.testing.assert_array_equal(np.asarray(plane[2]), np.asarray(flat[2]))
+    # The 2-D kernel blanks the row over ITS stripe, which may reach
+    # across this block's edge: compare where neither blanked anything.
+    stripe = stripe_cols(rows)
+    start = at // stripe * stripe
+    outside = np.r_[0:min(cols, start), max(2 * cols, start + stripe):d]
+    for i in (0, 3):
+        np.testing.assert_array_equal(np.asarray(plane[i]),
+                                      np.asarray(blanked[i]))
+        np.testing.assert_array_equal(np.asarray(plane[i])[outside],
+                                      np.asarray(flat[i])[outside])
+
+
+def test_plane_finish_takes_no_uniforms_unless_the_forge_is_adaptive():
+    """At d = 4.1e8 a (1, d) float32 zero row is 1.66 GB: the plane call
+    hands the kernel the uniforms only where a forge reads them.  The
+    two-dimensional call keeps its three inputs (the cells' programs)."""
+    from blades_tpu.ops import pallas_round
+
+    x = jnp.zeros((8, plane_cols(8)), jnp.bfloat16)
+
+    def operands(x, forge, noise=None):
+        jaxpr = jax.make_jaxpr(lambda x, r: (
+            pallas_round._fused_finish_compact_jit(
+                x, r, forged_mult=2, forge=forge, interpret=True)))(x, noise)
+        (call,) = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                   if e.primitive.name == "pallas_call"]
+        return len(call.invars)
+
+    noise = jnp.zeros((x.shape[1],), jnp.float32)
+    assert operands(_planes(x), ("alie", 0.7)) == 2
+    assert operands(_planes(x), ("ipm", 1.5)) == 2
+    assert operands(_planes(x), ("adaptive", 1.5), noise) == 3
+    assert operands(x, ("alie", 0.7)) == 3
+
+
+def test_plane_finish_refuses_a_plane_that_is_not_128_lanes():
+    from blades_tpu.ops import pallas_round
+
+    with pytest.raises(ValueError, match="row-plane matrix"):
+        pallas_round._fused_finish_compact_jit(
+            jnp.zeros((8, 16, 64), jnp.float32), None, forged_mult=2,
+            forge=("alie", 0.7), interpret=True)
 
 
 @pytest.mark.parametrize("n,d", [(24, 1000), (17, 700), (64, 2048)])
@@ -719,31 +915,45 @@ def test_streamed_step_compact_with_row_padding(monkeypatch):
                                    atol=1e-6)
 
 
-def test_streamed_round_at_8_rows_allocates_to_the_wide_stripe(monkeypatch):
+@pytest.mark.parametrize("client_block,blocks,surplus", [(1, 8, 0),
+                                                         (3, 3, 1)])
+def test_streamed_round_at_8_rows_keeps_a_row_a_plane(
+        monkeypatch, client_block, blocks, surplus):
     """The language-model cell's geometry on the MLP: 10 clients, 2 ALIE
-    elided, 8 benign rows stored.  The matrix is allocated a whole number
-    of the finish's WIDE stripes across (no pad inside the call copies
-    it), the round equals the chunked finish's, and the metrics carry the
-    width as a host int that the row's schema knows."""
+    elided, 8 benign rows stored in blocks under a storage tile (of one
+    lane, as the cell's; of three, the last one padded).  The matrix
+    keeps a row a plane, (8, d_alloc // 128, 128), a whole number of the
+    finish's blocks across (no pad inside the call copies it) and with no
+    padding row; every store counts as aligned; ONE executable trains and
+    stores every block (traced once: one call of ``row_planes``); the
+    round equals the chunked finish's; and the
+    metrics carry the block's columns as a host int that the row's schema
+    knows."""
     import functools
 
     from blades_tpu import parallel
     from blades_tpu.adversaries import get_adversary, make_malicious_mask
     from blades_tpu.core import FedRound, Server, TaskSpec
     from blades_tpu.obs.schema import ROUND_RECORD_FIELDS
-    from blades_tpu.ops import pallas_round, pallas_select
+    from blades_tpu.ops import pallas_round, pallas_select, pallas_store
 
-    seen = []
+    seen, stored = [], []
 
     def interpreted(updates, *args, **kw):
         seen.append(updates.shape)
         return compact(updates, *args, **kw, interpret=True)
 
+    def planes_of(upd, tail):
+        stored.append(isinstance(upd, jax.Array))
+        return row_planes(upd, tail)
+
     compact = pallas_round.fused_finish_compact
+    row_planes = pallas_store.row_planes
     monkeypatch.setattr(pallas_round, "should_use", lambda n, d: True)
     monkeypatch.setattr(pallas_select, "kernel_applicable",
                         lambda n, d: True)
     monkeypatch.setattr(pallas_round, "fused_finish_compact", interpreted)
+    monkeypatch.setattr(pallas_store, "row_planes", planes_of)
 
     n, f = 10, 2
     task = TaskSpec(model="mlp", input_shape=(8, 8, 1), num_classes=10,
@@ -759,25 +969,32 @@ def test_streamed_round_at_8_rows_allocates_to_the_wide_stripe(monkeypatch):
     mal = make_malicious_mask(n, f)
     key = jax.random.PRNGKey(3)
 
-    step = functools.partial(parallel.streamed.streamed_step, fr,
-                             client_block=1, update_dtype=jnp.float32,
-                             donate=False)
-    s1, m1 = step(malicious_prefix=f)(
-        fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal, key)
+    build = functools.partial(parallel.streamed.streamed_step, fr,
+                              client_block=client_block,
+                              update_dtype=jnp.float32, donate=False)
+    step = build(malicious_prefix=f)
+    s1, m1 = step(fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal, key)
 
-    width = stripe_cols(8)
+    width = plane_cols(8)
     d = sum(p.size for p in jax.tree.leaves(s1.server.params))
-    assert seen == [(8, stripe_padded(d, 8))] and seen[0][1] % width == 0
-    assert int(m1["finish_stripe_cols"]) == width > 512
+    assert seen == [(8, -(-d // width) * width // 128, 128)]
+    assert int(m1["finish_stripe_cols"]) == width
     assert isinstance(m1["finish_stripe_cols"], np.integer)  # a host stamp
     assert "finish_stripe_cols" in ROUND_RECORD_FIELDS
+    assert (int(m1["store_blocks"]), int(m1["store_blocks_aligned"]),
+            int(m1["surplus_lanes"])) == (blocks, blocks, surplus)
+    assert step.train_block._cache_size() == 1
+    # ONE lane reaches the store as its leaves, to be laid out in one
+    # dimension (a (1, d) row of bf16 is half padding); three as rows.
+    assert stored == [client_block != 1]
 
     monkeypatch.setattr(pallas_round, "should_use", lambda n, d: False)
     monkeypatch.setattr(pallas_select, "kernel_applicable",
                         lambda n, d: False)
-    s2, m2 = step()(fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal,
-                    key)
+    s2, m2 = build()(fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal,
+                     key)
     assert "finish_stripe_cols" not in m2   # the chunked finish: no stripe
+    assert int(m2["store_blocks_aligned"]) == 0   # (rows, d): part tiles
     for k in ("train_loss", "agg_norm", "update_norm_mean"):
         np.testing.assert_allclose(float(m1[k]), float(m2[k]), rtol=1e-5)
     for a, b in zip(jax.tree.leaves(s1.server.params),

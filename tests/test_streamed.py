@@ -312,8 +312,16 @@ def test_malicious_prefix_elision_exact_under_dp(data):
     (20, 0, 50, jnp.bfloat16, False, (0, 0, 16, 2, 12, 0)),
     (16, 0, 8, jnp.float32, False, (0, 0, 8, 2, 0, 2)),
     # Fewer benign lanes than a tile: the compact block is no larger
-    # than what it trains.
-    (20, 10, 50, jnp.bfloat16, True, (10, 0, 10, 1, 0, 0)),
+    # than what it trains.  Under a tile on the compact path the matrix
+    # keeps a row a plane (ISSUE 32), where every store is whole tiles.
+    (20, 10, 50, jnp.bfloat16, True, (10, 0, 10, 1, 0, 1)),
+    # The language-model cell: 8 blocks of one lane, each a plane.
+    (10, 2, 1, jnp.bfloat16, True, (2, 0, 1, 8, 0, 8)),
+    (10, 2, 1, jnp.float32, True, (2, 0, 1, 8, 0, 8)),
+    (10, 2, 3, jnp.float32, True, (2, 0, 3, 3, 1, 3)),
+    (26, 2, 15, jnp.bfloat16, True, (2, 0, 15, 2, 6, 2)),
+    # The same blocks off the compact path: (rows, d), part tiles.
+    (10, 2, 1, jnp.bfloat16, False, (2, 2, 1, 8, 0, 0)),
 ])
 def test_block_plan(n, prefix, client_block, dtype, compact, want):
     from blades_tpu.parallel.streamed import block_plan
@@ -321,6 +329,8 @@ def test_block_plan(n, prefix, client_block, dtype, compact, want):
     plan = block_plan(n, prefix, client_block, dtype, compact=compact)
     assert (plan.first_lane, plan.first_row, plan.block, plan.blocks,
             plan.surplus, plan.aligned_stores) == want
+    # The layout's rule, from the shapes alone.
+    assert plan.planes == (compact and plan.block < plan.tile)
     # Equal dispatches, none over the bound, cover the trained range with
     # less than one block of surplus, and the last one's early start
     # stays inside the lanes its matrix has rows for.
@@ -329,6 +339,39 @@ def test_block_plan(n, prefix, client_block, dtype, compact, want):
     assert plan.first_lane <= prefix
     assert n - plan.block >= (plan.first_lane if compact else 0)
     assert plan.first_row % plan.block == 0
+
+
+@pytest.mark.parametrize("n,prefix,client_block,dtype,shape", [
+    # Blocks of whole tiles keep the two-dimensional matrix, a whole
+    # number of blocks and sublanes high and of 512-column stripes wide:
+    # 16 of bf16 (the two ResNet cells, the first with its padded last
+    # block), 8 and 24 of float32.
+    (1000, 250, 25, jnp.bfloat16, (752, 4_903_424)),
+    (768, 192, 24, jnp.bfloat16, (576, 4_903_424)),
+    (21, 3, 10, jnp.float32, (24, 4_904_448)),
+    (768, 192, 24, jnp.float32, (576, 4_903_424)),
+    # Blocks under a tile: a row a plane, no padding row, a whole number
+    # of the plane finish's blocks across.
+    (10, 2, 1, jnp.bfloat16, (8, 38_320, 128)),
+    (10, 2, 3, jnp.float32, (8, 38_320, 128)),
+    (26, 2, 15, jnp.bfloat16, (24, 38_336, 128)),
+])
+def test_compact_matrix_is_two_dimensional_for_blocks_of_whole_tiles(
+        n, prefix, client_block, dtype, shape):
+    from blades_tpu.ops.pallas_select import plane_cols, stripe_cols
+    from blades_tpu.parallel.streamed import block_plan, compact_matrix
+
+    d = 4_903_242    # ResNet-10's
+    plan = block_plan(n, prefix, client_block, dtype, compact=True)
+    got, cols = compact_matrix(plan, n - prefix, d)
+    assert got == shape and plan.planes == (len(shape) == 3)
+    if plan.planes:
+        assert cols == plane_cols(n - prefix)
+        assert got[0] == n - prefix and got[1] * 128 % cols == 0
+        assert 0 <= got[1] * 128 - d < cols
+    else:
+        assert cols == stripe_cols(got[0])
+        assert got[0] % plan.block == 0 == got[0] % 8 and got[1] % cols == 0
 
 
 N_RAGGED, F_RAGGED = 21, 3

@@ -134,6 +134,46 @@ def test_aot_train_block_reads_the_store_out_of_an_hlo_line():
     other = line.replace("752,", "16,")
     assert aot_train_block.store_ops(line + "\n" + other, 752) == [{
         "name": "dynamic_update_slice.3", "shape": [752, 4903424],
+        "layout": "{1,0:T(8,128)(2,1)}",
         "index_known_zero_bits": [15, 4294967295],
         "is_index_aligned": [False, False],
         "aliasing_operands": [{"indices": ["0", "4"]}]}]
+    # A matrix of row planes (ISSUE 32): three indices, the row's a
+    # runtime one, the store aligned in the two that tile.
+    planes = ('  %dynamic-update-slice.1 = bf16[8,3234112,128]'
+              '{2,1,0:T(8,128)(2,1)} dynamic-update-slice(%buf, %upd, %row, '
+              '%c0, %c0), backend_config={"indices_config":{'
+              '"index_known_bits":[],'
+              '"is_index_aligned":[true,false,true]},'
+              '"aliasing_operands":{"lists":[{"indices":["0"]}]}}')
+    (store,) = aot_train_block.store_ops(planes + "\n" + line, 8)
+    assert store["shape"] == [8, 3234112, 128]
+    assert store["layout"] == "{2,1,0:T(8,128)(2,1)}"
+    assert store["is_index_aligned"] == [True, False, True]
+
+
+def test_aot_train_block_reads_a_fused_store_s_aliasing_off_its_fusion():
+    """The store into row planes is the root of a fusion (ISSUE 32): the
+    operation carries the alignment, the fusion that calls it the
+    aliasing."""
+    import aot_train_block
+
+    hlo = "\n".join([
+        "%fused_computation.94 (param_0.111: bf16[8,3234112,128], "
+        "param_1.144: u32[], param_2.8222: bf16[413966336]) -> "
+        "bf16[8,3234112,128] {",
+        "  ROOT %dynamic_update_slice.6 = bf16[8,3234112,128]"
+        "{2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.111, "
+        "%bitcast.1600, %param_1.144, %constant.3827, %constant.3827), "
+        'backend_config={"indices_config":{"index_known_bits":[],'
+        '"is_index_aligned":[true,true,true]}}',
+        "}",
+        "  %bitcast_dynamic-update-slice_fusion = bf16[8,3234112,128]"
+        "{2,1,0:T(8,128)(2,1)} fusion(%updates_buf.1, %copy.3222, "
+        "%pad_reduce_fusion), kind=kLoop, calls=%fused_computation.94, "
+        'backend_config={"aliasing_operands":{"lists":[{"indices":'
+        '["0","3"]}]}}',
+    ])
+    (store,) = aot_train_block.store_ops(hlo, 8)
+    assert store["is_index_aligned"] == [True, True, True]
+    assert store["aliasing_operands"] == [{"indices": ["0", "3"]}]

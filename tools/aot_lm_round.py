@@ -5,7 +5,11 @@ TPU compiler builds ``_train_block`` and ``_finish_fused_compact``
 ``v5e:2x2`` at ``tuned_examples/fedavg_lm_crosssilo.yaml``'s shapes (10
 clients, 2 elided, ``client_block`` 1, rows of 4096 tokens) and prints each
 one's ``memory_analysis()``: arguments + outputs - aliased + temporaries is
-what the program needs of the chip's 15.75 GB.
+what the program needs of the chip's 15.75 GB.  The matrix is built by the
+round's own rule (``parallel/streamed.py::compact_matrix``: a row a plane
+here, a block of one lane lying under a storage tile); each line also
+carries the layouts the compiler gave it and the block's stores into it
+(``is_index_aligned``, in place or not).
 
     JAX_PLATFORMS=cpu python3 tools/aot_lm_round.py [key=json ...]
 
@@ -18,6 +22,7 @@ Nothing runs on a device: a compile that passes is not a chip run
 
 import json
 import os
+import re
 import sys
 import time
 from unittest import mock
@@ -37,9 +42,13 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    from aot_train_block import store_ops
     from blades_tpu.algorithms import get_algorithm_class
-    from blades_tpu.ops.pallas_select import stripe_cols, stripe_padded
-    from blades_tpu.parallel.streamed import block_plan, streamed_step
+    from blades_tpu.parallel.streamed import (
+        block_plan,
+        compact_matrix,
+        streamed_step,
+    )
     from blades_tpu.tune import expand_grid, load_experiments_from_file
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -70,8 +79,9 @@ def main() -> int:
     plan = block_plan(n, f, block, dtype, compact=True)
     state = jax.eval_shape(lambda k: fr.init(k, n), jax.random.PRNGKey(0))
     d = sum(p.size for p in jax.tree.leaves(state.server.params))
-    rows = -(-(plan.blocks * plan.block) // 8) * 8
-    d_alloc = stripe_padded(d, rows)
+    # The matrix by the rule the round builds it by: a row a plane here,
+    # where a block of one lane lies under a storage tile.
+    matrix, finish_cols = compact_matrix(plan, n - f, d)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -84,7 +94,14 @@ def main() -> int:
         t = time.time()
         out = {"program": name}
         try:
-            m = lowered.compile().memory_analysis()
+            compiled = lowered.compile()
+            m = compiled.memory_analysis()
+            # The matrix's layout as the compiler chose it, and how the
+            # block stores into it.
+            hlo = compiled.as_text()
+            out["matrix_layouts"] = sorted(set(re.findall(
+                r"\w+\[%s\](\{[^}]*\})" % ",".join(map(str, matrix)), hlo)))
+            out["stores"] = store_ops(hlo, matrix[0])
             out.update({k: int(getattr(m, k)) for k in KEYS
                         if hasattr(m, k)})
             out["needs_bytes"] = (
@@ -97,19 +114,19 @@ def main() -> int:
 
     seq, cap = tuple(config.input_shape)[0], 16
     print(json.dumps({"model": model, "num_params": d, "plan": plan._asdict(),
-                      "matrix": [rows, d_alloc],
-                      "finish_stripe_cols": stripe_cols(rows),
+                      "matrix": list(matrix),
+                      "finish_stripe_cols": finish_cols,
                       "topology": "v5e:2x2"}),
           flush=True)
     with mock.patch.object(jax, "default_backend", return_value="tpu"):
         report("_train_block", step.train_block.lower(
-            shape((rows, d_alloc), dtype), on_chip(state.client_opt),
+            shape(matrix, dtype), on_chip(state.client_opt),
             on_chip(state.server.params), shape((n, cap, seq), jnp.int32),
             shape((n, cap, seq), jnp.int32), shape((n,), jnp.int32),
             shape((n,), jnp.bool_), shape((n, 2), jnp.uint32),
             shape((n, 2), jnp.uint32), shape((), jnp.uint32), plan=plan))
         report("_finish_fused_compact", step.finish_fused_compact.lower(
-            on_chip(state.server), shape((rows, d_alloc), dtype),
+            on_chip(state.server), shape(matrix, dtype),
             shape((n,), jnp.bool_), shape((n,), jnp.float32),
             shape((2,), jnp.uint32), nb_real=n - f))
     return 0

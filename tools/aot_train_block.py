@@ -28,23 +28,36 @@ CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def store_ops(hlo: str, rows: int) -> list[dict]:
-    """The dynamic-update-slices into a ``rows``-high matrix: what XLA
-    proved of their (row, column) offsets, and whether they alias their
-    operand (in place)."""
-    found = []
+    """The dynamic-update-slices into a ``rows``-high matrix, of two
+    dimensions or of row planes: its layout, what XLA proved of their
+    (row, column[, lane]) offsets, and whether they alias their operand
+    (in place): the operation's own word, or that of the fusion whose
+    root it is."""
+    found, inside = [], None
     for line in hlo.splitlines():
-        m = re.search(r"%(\S+) = \w+\[(\d+),(\d+)\]\S* dynamic-update-slice\(",
-                      line)
-        if not m or int(m.group(2)) != rows:
+        head = re.match(r"%(\S+) \(.*\) -> .* \{$", line)
+        inside = head.group(1) if head else inside
+        m = re.search(r"%(\S+) = \w+\[(\d+(?:,\d+)+)\](\S*) "
+                      r"dynamic-update-slice\(", line)
+        shape = [int(v) for v in m.group(2).split(",")] if m else [0]
+        if shape[0] != rows:
             continue
         cfg = json.loads(line[line.index("backend_config=") + 15:])
         idx = cfg.get("indices_config", {})
+        aliasing = cfg.get("aliasing_operands", {}).get("lists")
+        if aliasing is None and inside:
+            fusion = re.search(
+                r"fusion\(.*calls=%%%s[,)].*backend_config=(\{.*\})$"
+                % re.escape(inside), hlo, re.M)
+            if fusion:
+                aliasing = json.loads(fusion.group(1)).get(
+                    "aliasing_operands", {}).get("lists")
         found.append({
-            "name": m.group(1), "shape": [rows, int(m.group(3))],
+            "name": m.group(1), "shape": shape, "layout": m.group(3),
             "index_known_zero_bits": [int(b["zeroes"]) for b in
                                       idx.get("index_known_bits", [])],
             "is_index_aligned": idx.get("is_index_aligned"),
-            "aliasing_operands": cfg.get("aliasing_operands", {}).get("lists"),
+            "aliasing_operands": aliasing,
         })
     return found
 
@@ -63,8 +76,11 @@ def main() -> int:
     from jax.sharding import SingleDeviceSharding
 
     from blades_tpu.algorithms import get_algorithm_class
-    from blades_tpu.ops.pallas_select import stripe_padded
-    from blades_tpu.parallel.streamed import block_plan, streamed_step
+    from blades_tpu.parallel.streamed import (
+        block_plan,
+        compact_matrix,
+        streamed_step,
+    )
     from blades_tpu.tune import expand_grid, load_experiments_from_file
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -92,8 +108,9 @@ def main() -> int:
     plan = block_plan(n, f, client_block, dtype, compact=True)
     state = jax.eval_shape(lambda k: fr.init(k, n), jax.random.PRNGKey(0))
     d = sum(p.size for p in jax.tree.leaves(state.server.params))
-    rows = plan.blocks * plan.block
-    d_alloc = stripe_padded(d, rows)
+    # The matrix by the rule the round builds it by.
+    matrix, _ = compact_matrix(plan, n - f, d)
+    rows = matrix[0]
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -103,14 +120,14 @@ def main() -> int:
         return jax.ShapeDtypeStruct(dims, dt, sharding=chip)
 
     cap = 192
-    args = (shape((rows, d_alloc), dtype), on_chip(state.client_opt),
+    args = (shape(matrix, dtype), on_chip(state.client_opt),
             on_chip(state.server.params),
             shape((n, cap, 32, 32, 3), jnp.bfloat16),
             shape((n, cap), jnp.int32), shape((n,), jnp.int32),
             shape((n,), jnp.bool_), shape((n, 2), jnp.uint32),
             shape((n, 2), jnp.uint32), shape((), jnp.uint32))
     out = {"model": model, "num_clients": n, "client_block": client_block,
-           "plan": plan._asdict(), "matrix": [rows, d_alloc],
+           "plan": plan._asdict(), "matrix": list(matrix),
            "topology": "v5e:2x2"}
     t = time.time()
     # The kernel gates ask jax.default_backend() and would see the CPU:

@@ -19,7 +19,10 @@ admit.  Two consumers:
 
   ``--sweep [rows [d [dtype [width ...]]]]`` instead TIMES the compact finish
   alone at a short matrix over stripe widths (:func:`sweep`): the
-  readings ``pallas_select._STRIPE_BUDGET`` was chosen from.
+  readings ``pallas_select._STRIPE_BUDGET`` was chosen from.  ``--sweep
+  planes [rows ...]`` does so over a matrix whose rows are planes
+  (``s x 128`` columns a grid step: ``pallas_select._PLANE_BUDGET``'s
+  readings), and times the one-row store into it.
 
 - ``tests/test_chip_contract.py``, on the CPU: lowers each case for
   ``platforms=["tpu"]`` via ``jax.export`` — catches Pallas API drift in
@@ -65,6 +68,7 @@ class Case:
     inputs: Callable[[], Tuple[np.ndarray, ...]]  # seeded host inputs
     reference: Callable[..., Dict[str, np.ndarray]]  # float64 numpy twin
     rtol: float                               # relative to max |reference|
+    mosaic: bool = True                       # holds a Pallas kernel
 
 
 def _rng(name: str) -> np.random.Generator:
@@ -171,6 +175,93 @@ def _compact_case(nb: int, mult: int, dtype, agg: tuple, mxu: str = "") -> Case:
     # multiplies at bf16-pass precision whatever the storage.
     return Case(name, run, inputs, reference,
                 2.0 ** -6 if bf16 or stats_mxu else 1e-4)
+
+
+def _planes(x: np.ndarray) -> np.ndarray:
+    """``(rows, d)`` as ``(rows, d // 128, 128)``."""
+    return x.reshape(x.shape[0], -1, 128)
+
+
+def _plane_case(nb: int, mult: int, dtype, agg: tuple) -> Case:
+    """``fused_finish_compact`` over a matrix whose rows are planes, as
+    the streamed round calls it where a block lies under a storage tile
+    (parallel/streamed.py::compact_matrix): ``nb`` rows and no padding
+    row, two grid steps and a third padded inside the call.  Against
+    float64, and against the two-dimensional kernel on the same values
+    (``same_bits``: the aggregate's and the forged row's, which on bf16
+    storage must be equal to the bit; without the poisoned value, whose
+    blanking follows each layout's own block)."""
+    bf16 = dtype == jnp.bfloat16
+    name = f"plane_{agg[0]}_{jnp.dtype(dtype).name}_nb{nb}_mult{mult}"
+    block = pallas_select.plane_cols(nb)
+    d = 2 * block + 128 * 5
+
+    def inputs():
+        return (_planes(_matrix(name, nb, dtype, nan_at=(3, block + 7),
+                                d=d)),)
+
+    def finish(x):
+        return pallas_round.fused_finish_compact(
+            x, None, forged_mult=mult, forge=("alie", ALIE_Z), agg=agg,
+            sanitize=True)
+
+    def run(x):
+        agg_vec, sq, bad, forged = finish(x)
+        sound = jnp.where(jnp.isnan(x.astype(jnp.float32)), 0, x)
+        plane, flat = finish(sound), finish(sound.reshape(nb, d))
+        same = jnp.stack([
+            (jax.lax.bitcast_convert_type(plane[i], jnp.uint32)
+             == jax.lax.bitcast_convert_type(flat[i], jnp.uint32)).all()
+            for i in (0, 3)])
+        return {"agg": agg_vec, "sq": sq, "bad": bad, "forged": forged,
+                "same_bits": same}
+
+    def reference(x):
+        benign, bad = _ref_sanitize(_f64(x).reshape(nb, d),
+                                    np.ones(nb, bool), block)
+        forged = _ref_forged(benign, bf16)
+        full = np.concatenate([np.tile(forged, (mult, 1)), benign])
+        return {"agg": _ref_agg(full, agg), "sq": (benign ** 2).sum(axis=1),
+                "bad": bad, "forged": forged,
+                "same_bits": np.ones(2, bool) if bf16 else None}
+
+    return Case(name, run, inputs, reference, 2.0 ** -6 if bf16 else 1e-4)
+
+
+def _plane_store_case(rows: int, lanes: int, dtype,
+                      leaves: bool = False) -> Case:
+    """The store of a block under a storage tile: ``lanes`` rows, padded
+    to the matrix's width, into row planes at a runtime row (XLA's own
+    copy: ``pallas_store.row_planes`` + ``lax.dynamic_update_slice``, as
+    in ``_train_block``); every other row must stay.  ``leaves``: the one
+    lane comes as a pytree of its leaves, as the one-lane block hands it
+    over, and is laid out in one dimension."""
+    name = (f"plane_store_{jnp.dtype(dtype).name}_r{rows}_b{lanes}"
+            + "_leaves" * leaves)
+    tail = (48, 128)
+    d = tail[0] * tail[1] - 74
+
+    def inputs():
+        rng = _rng(name)
+        return (_store(rng.normal(size=(rows,) + tail), dtype),
+                _store(rng.normal(size=(lanes, d)), dtype),
+                np.uint32(rows - lanes - 1))
+
+    def run(mat, upd, row):
+        if leaves:   # three leaves, none a whole number of lanes
+            upd = {"a": upd[:, :1000].reshape(1, 8, 125),
+                   "b": upd[:, 1000:1003], "c": upd[:, 1003:]}
+        return {"rows": jax.lax.dynamic_update_slice(
+            mat, pallas_store.row_planes(upd, tail),
+            (row, jnp.uint32(0), jnp.uint32(0)))}
+
+    def reference(mat, upd, row):
+        want = _f64(mat).reshape(rows, -1)
+        want[row:row + lanes] = 0.0
+        want[row:row + lanes, :d] = _f64(upd)
+        return {"rows": want.reshape((rows,) + tail)}
+
+    return Case(name, run, inputs, reference, 0.0, mosaic=False)  # a copy
 
 
 def _full_case(n: int, f: int, dtype, agg: tuple) -> Case:
@@ -312,6 +403,15 @@ def _cases() -> Tuple[Case, ...]:
         _compact_case(8, 2, f32, ("trimmed", 2)),
         _compact_case(13, 3, bf16, ("trimmed", 3)),
         _compact_case(24, 8, f32, ("median",)),
+        # The same over row planes (blocks under a storage tile): the
+        # language-model cell's call, and heights that are no sublane
+        # multiple, which need no padding row there.
+        _plane_case(8, 2, bf16, ("median",)),
+        _plane_case(8, 2, f32, ("trimmed", 2)),
+        _plane_case(10, 3, bf16, ("trimmed", 3)),
+        _plane_case(13, 3, bf16, ("mean",)),
+        _plane_case(24, 8, bf16, ("median",)),
+        _plane_case(24, 8, f32, ("median",)),
         # Full-matrix finish (no elision).
         _full_case(1000, 250, bf16, ("median",)),
         _full_case(2048, 512, bf16, ("median",)),
@@ -336,6 +436,12 @@ def _cases() -> Tuple[Case, ...]:
         _store_case(16, bf16, surplus=2),
         _store_case(8, f32, surplus=5),
         _store_case(48, bf16, surplus=20),
+        # Blocks under a tile, into row planes: one row of 8 (the
+        # language-model cell's), three of 10, five of 24.
+        _plane_store_case(8, 1, bf16),
+        _plane_store_case(8, 1, bf16, leaves=True),
+        _plane_store_case(10, 3, f32),
+        _plane_store_case(24, 5, bf16),
     )
 
 
@@ -358,6 +464,8 @@ def check(case: Case) -> Dict[str, Any]:
         ref = case.reference(*host)
         errs, ok = {}, True
         for key, want in ref.items():
+            if want is None:   # not held in this case
+                continue
             got = np.asarray(out[key])
             if got.shape != want.shape:
                 raise AssertionError(
@@ -384,6 +492,7 @@ def check(case: Case) -> Dict[str, Any]:
 
 SWEEP_ROWS, SWEEP_D = 8, 413_959_168  # joyai_n10_median's stored matrix
 SWEEP_WIDTHS = (512, 1024, 2048, 3072, 4096, 8192, 32768)
+SWEEP_PLANE_WIDTHS = (2048, 4096, 8192, 16384, 32768, 65536)
 
 
 def _bits_sums(v):
@@ -405,20 +514,35 @@ def sweep(argv) -> int:
     around it), the grid, whether the aggregate and the forged row have
     the bits the 512-column kernel gave (by :func:`_bits_sums`), and the
     row norms' largest relative gap to that kernel's (their float32 sums
-    run across stripes).  A refused compile is a line, not a failure."""
+    run across stripes).  A refused compile is a line, not a failure.
+
+    With ``planes`` as the first argument the matrix keeps a row a plane
+    (``(rows, d // 128, 128)``, the same values): the widths are the
+    columns ``s x 128`` of a grid step (``plane_cols`` the rule's), the
+    bits are compared with the TWO-DIMENSIONAL kernel's at its own rule's
+    width, which runs first, and two last lines time the store of one row
+    at a runtime row index into either layout (``lax.dynamic_update_slice``
+    into the donated matrix, as ``_train_block`` stores it)."""
+    planes = bool(argv) and argv[0] == "planes"
+    argv = argv[1:] if planes else argv
     rows = int(argv[0]) if argv else SWEEP_ROWS
     d = int(argv[1]) if len(argv) > 1 else SWEEP_D
     dtype = jnp.dtype(argv[2] if len(argv) > 2 else "bfloat16")
-    widths = tuple(int(w) for w in argv[3:]) or SWEEP_WIDTHS
-    ruled = pallas_select.stripe_cols(rows)
+    widths = tuple(int(w) for w in argv[3:]) or (
+        SWEEP_PLANE_WIDTHS if planes else SWEEP_WIDTHS)
+    ruled = (pallas_select.plane_cols if planes
+             else pallas_select.stripe_cols)(rows)
 
-    @functools.partial(jax.jit, static_argnames=("dpad",))
-    def matrix(dpad):
+    @functools.partial(jax.jit, static_argnames=("dpad", "planes"))
+    def matrix(dpad, planes):
         """The matrix as the round allocates it, padded to the stripe
         with zero columns: values in [-1, 1) hashed from (row, column),
         one elementwise program with no temporary beside the 6.6 GB."""
-        col = jax.lax.broadcasted_iota(jnp.uint32, (rows, dpad), 1)
-        row = jax.lax.broadcasted_iota(jnp.uint32, (rows, dpad), 0)
+        shape = (rows, dpad // 128, 128) if planes else (rows, dpad)
+        iota = functools.partial(jax.lax.broadcasted_iota, jnp.uint32, shape)
+        row, col = iota(0), iota(1)
+        if planes:
+            col = col * jnp.uint32(128) + iota(2)
         h = col * jnp.uint32(2654435761) + row * jnp.uint32(40503)
         h = (h ^ (h >> 15)) * jnp.uint32(2246822519)
         h = h ^ (h >> 13)
@@ -430,24 +554,40 @@ def sweep(argv) -> int:
         agg, sq, _, forged = pallas_round._fused_finish_compact_jit(
             x, None, forged_mult=2, forge=("alie", ALIE_Z), agg=("median",),
             sanitize=True, num_real=rows, cols=cols)
-        return _bits_sums(agg), _bits_sums(forged), sq
+        return _bits_sums(agg[:d]), _bits_sums(forged[:d]), sq
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def store(x, upd, row):
+        if x.ndim == 3:
+            upd = pallas_store.row_planes(upd.astype(x.dtype), x.shape[1:])
+        zero = jnp.uint32(0)
+        return jax.lax.dynamic_update_slice(
+            x, upd, (row,) + (zero,) * (x.ndim - 1))
+
+    def timed(call, times=4):
+        ms = []
+        for _ in range(times):
+            t0 = time.perf_counter()
+            jax.block_until_ready(call())
+            ms.append(round((time.perf_counter() - t0) * 1e3, 2))
+        return ms
 
     records, want = [], None
-    for cols in sorted({STRIPE, ruled, *widths}):
+    runs = [(False, cols) for cols in sorted({STRIPE, ruled, *widths})]
+    if planes:
+        runs = ([(False, pallas_select.stripe_cols(rows))]
+                + [(True, cols) for cols in sorted({ruled, *widths})])
+    for plane, cols in runs:
         dpad = -(-d // cols) * cols
         rec = {"rows": rows, "d": d, "dtype": dtype.name, "cols": cols,
-               "grid": dpad // cols, "ruled": cols == ruled}
+               "planes": plane, "grid": dpad // cols,
+               "ruled": cols == ruled and plane == planes}
         try:
-            x = jax.block_until_ready(matrix(dpad))
+            x = jax.block_until_ready(matrix(dpad, plane))
             t0 = time.perf_counter()
             got = jax.block_until_ready(finish(x, cols))
             rec["first_call_s"] = round(time.perf_counter() - t0, 2)
-            ms = []
-            for _ in range(4):
-                t0 = time.perf_counter()
-                jax.block_until_ready(finish(x, cols))
-                ms.append(round((time.perf_counter() - t0) * 1e3, 2))
-            rec["ms"] = ms
+            rec["ms"] = timed(lambda: finish(x, cols))
             got = [np.asarray(g) for g in got]
             want = want or got   # the narrowest width comes first
             rec["agg_bits_equal"] = bool((got[0] == want[0]).all())
@@ -459,10 +599,39 @@ def sweep(argv) -> int:
             rec["error"] = f"{type(e).__name__}: {e}"[-600:]
         records.append(rec)
         print(json.dumps(rec), flush=True)
+    # The store of ONE row, as ``_train_block`` hands it over: in the
+    # storage type, where a ``(1, d)`` row of a 2-byte type is laid out
+    # with a second, empty row in every word (T(2,128)(2,1)), or in
+    # float32, converted on the way.
+    stores = [(False, dtype), (True, dtype), (True, jnp.dtype("float32"))]
+    for plane, row_dtype in stores if planes else ():
+        cols = ruled if plane else pallas_select.stripe_cols(rows)
+        rec = {"rows": rows, "d": d, "dtype": dtype.name, "planes": plane,
+               "store_of_rows": 1, "row_dtype": row_dtype.name}
+        try:
+            box = [jax.block_until_ready(
+                matrix(-(-d // cols) * cols, plane))]
+            upd = jax.block_until_ready(jnp.full((1, d), 0.5, row_dtype))
+            index = iter(range(1, 1000))
+
+            def one_store():
+                box[0] = store(box[0], upd,
+                               np.uint32(next(index) % rows))
+                return box[0]
+
+            t0 = time.perf_counter()
+            jax.block_until_ready(one_store())
+            rec["first_call_s"] = round(time.perf_counter() - t0, 2)
+            rec["ms"] = timed(one_store, times=8)
+            del box, upd
+        except Exception as e:
+            rec["error"] = f"{type(e).__name__}: {e}"[-600:]
+        records.append(rec)
+        print(json.dumps(rec), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open(os.path.join(
-            "chiprun_out", f"chip_kernels_sweep_{rows}x{d}_{dtype.name}.json"),
-            "w") as f:
+            "chiprun_out", "chip_kernels_sweep_"
+            f"{'planes_' * planes}{rows}x{d}_{dtype.name}.json"), "w") as f:
         json.dump(records, f, indent=1)
     return 0
 
