@@ -4,7 +4,7 @@ Resolves a model spec — substring-matched name ("cct"/"resnet"/"mlp"/"cnn",
 same matching rule as the reference), a flax Module instance, a custom
 registered name, or a dict ``{"type": name, **builder keywords}`` (how a
 YAML states a model that has a configuration of its own, e.g.
-``mla_moe_lm``) — to a linen module.
+``mla_moe_lm``, ``gqa_moe_lm``) — to a linen module.
 """
 
 from __future__ import annotations
@@ -16,6 +16,7 @@ import flax.linen as nn
 from blades_tpu.models.cct import VARIANTS as _CCT_VARIANTS
 from blades_tpu.models.cct import cct_2_3x2_32
 from blades_tpu.models.cnn import FashionCNN
+from blades_tpu.models.gqa_moe import gqa_moe_lm
 from blades_tpu.models.mla_moe import mla_moe_lm
 from blades_tpu.models.mlp import MLP
 from blades_tpu.models.resnet import (
@@ -30,7 +31,7 @@ from blades_tpu.models.resnet import (
 _CUSTOM: Dict[str, Callable[..., nn.Module]] = {}
 
 # Models built from keywords (a dict spec's keys beside "type").
-_CONFIGURED = {"mla_moe_lm": mla_moe_lm}
+_CONFIGURED = {"mla_moe_lm": mla_moe_lm, "gqa_moe_lm": gqa_moe_lm}
 
 _RESNETS = {
     "resnet10": ResNet10,

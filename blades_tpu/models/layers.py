@@ -27,11 +27,13 @@ cross-group terms), sized so narrow per-client matmuls still tile the MXU.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # --------------------------------------------------------------------------
@@ -285,50 +287,123 @@ def packed_positions(tokens: jnp.ndarray, bos_id: int):
     return segment, idx - last_start
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's blended rotary frequencies (Peng et al. 2023), static: pair
+    ``i`` of ``dim // 2`` turns at ``theta ** (-2i / dim)`` where it makes
+    over ``beta_fast`` turns inside the original context (extrapolated),
+    at that over ``factor`` where it makes under ``beta_slow``
+    (interpolated), and a linear blend of the two between.  A float32
+    numpy vector for :func:`rotary_interleaved`'s ``inv_freq``."""
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / factor
+
+    def correction_dim(turns):
+        return (dim * math.log(original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
 def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
-                       theta: float) -> jnp.ndarray:
+                       theta: float, inv_freq=None,
+                       attention_factor: float = 1.0) -> jnp.ndarray:
     """Rotary embedding over interleaved pairs ``(x[2i], x[2i+1])`` of the
     last axis, angle ``position * theta ** (-2i / dim)``, in float32.
-    ``x`` is ``(B, S, ..., dim)`` and ``positions`` ``(B, S)``."""
+    ``x`` is ``(B, S, ..., dim)`` and ``positions`` ``(B, S)``.  With
+    ``inv_freq`` ``(dim // 2,)`` the pairs turn at those frequencies
+    instead (:func:`yarn_inv_freq`), and ``attention_factor`` scales the
+    cosine and the sine alike (YaRN's ``mscale``)."""
     dim = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (dim // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def attention_key_start(q0: int, block: int, window=None) -> int:
+    """The first key a query block that starts at ``q0`` reads: 0 without
+    a window; under one, the first key its first query may see, ``q0 -
+    window + 1``, rounded down to a lane tile (128, or the block where
+    that is smaller) so that the slice stays aligned."""
+    if window is None:
+        return 0
+    align = min(block, 128)
+    return max(0, (q0 - window + 1) // align * align)
+
+
+def attention_scores_computed(s: int, block: int = 512, window=None) -> int:
+    """(query, key) positions of one row of ``s`` tokens whose score
+    :func:`packed_causal_attention` computes, a head: what its blocks
+    read, masked or not."""
+    block = min(block, s)
+    return sum(block * (q0 + block - attention_key_start(q0, block, window))
+               for q0 in range(0, s, block))
+
+
 def packed_causal_attention(q, k, v, segment, scale: float,
-                            block: int = 512) -> jnp.ndarray:
+                            block: int = 512, window=None) -> jnp.ndarray:
     """Softmax attention, causal within a document, in query blocks.
 
-    ``q``/``k`` are ``(B, S, H, dk)``, ``v`` ``(B, S, H, dv)``, ``segment``
-    ``(B, S)``.  Query block ``i`` reads keys ``[0, (i + 1) * block)`` only
-    and is rematerialised in the backward pass, so no ``(H, S, S)`` score
-    array outlives its block; scores and the softmax are float32."""
-    s = q.shape[1]
+    ``q`` is ``(B, S, H, dk)``, ``k`` ``(B, S, Hk, dk)``, ``v`` ``(B, S,
+    Hk, dv)``, ``segment`` ``(B, S)``.  ``H`` is a multiple of ``Hk``:
+    query head ``h`` reads key head ``h // (H // Hk)`` (grouped-query
+    attention; k and v are never repeated).  With ``window`` a query sees
+    only the keys less than ``window`` positions behind it.  The query
+    block at ``q0`` reads keys ``[attention_key_start(q0), q0 + block)``
+    only and is rematerialised in the backward pass, so no ``(H, S, S)``
+    score array outlives its block; scores and the softmax are float32."""
+    s, heads, kv_heads = q.shape[1], q.shape[2], k.shape[2]
     block = min(block, s)
     if s % block:
         raise ValueError(f"sequence length {s} is no multiple of the "
                          f"attention block {block}")
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads are no multiple of "
+                         f"{kv_heads} key heads")
+    group = heads // kv_heads
+    # Equal heads contract as they always did; grouped heads carry the
+    # group beside the key head, so one key head serves its whole group.
+    qk, pv = ("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd") if group == 1 else \
+        ("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd")
 
     @jax.checkpoint
-    def one_block(qi, kj, vj, seg_q, seg_k, q0):
-        sc = jnp.einsum("bqhd,bkhd->bhqk", qi, kj,
+    def one_block(qi, kj, vj, seg_q, seg_k, q0, k0):
+        if group > 1:
+            qi = qi.reshape(qi.shape[:2] + (kv_heads, group, qi.shape[-1]))
+        sc = jnp.einsum(qk, qi, kj,
                         preferred_element_type=jnp.float32) * scale
         qpos = q0 + jnp.arange(qi.shape[1])
+        kpos = jnp.arange(kj.shape[1])
+        if window is not None:
+            kpos = k0 + kpos
         ok = (seg_q[:, :, None] == seg_k[:, None, :]) \
-            & (jnp.arange(kj.shape[1])[None, :] <= qpos[:, None])
-        sc = jnp.where(ok[:, None], sc, -jnp.inf)
+            & (kpos[None, :] <= qpos[:, None])
+        if window is not None:
+            ok = ok & (qpos[:, None] - kpos[None, :] < window)
+        ok = ok[:, None] if group == 1 else ok[:, None, None]
+        sc = jnp.where(ok, sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1).astype(vj.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, vj)
+        o = jnp.einsum(pv, p, vj)
+        if group > 1:
+            o = o.reshape(o.shape[:2] + (heads, o.shape[-1]))
+        return o
 
     out = []
     for q0 in range(0, s, block):
-        end = q0 + block
-        out.append(one_block(q[:, q0:end], k[:, :end], v[:, :end],
-                             segment[:, q0:end], segment[:, :end], q0))
+        k0, end = attention_key_start(q0, block, window), q0 + block
+        out.append(one_block(q[:, q0:end], k[:, k0:end], v[:, k0:end],
+                             segment[:, q0:end], segment[:, k0:end], q0, k0))
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)

@@ -195,6 +195,15 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     "expert_tokens_mean": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "routed_here_share": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "zero_expert_blocks": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    # A model whose expert share is computed as routing and whose
+    # attention skips key blocks (models/gqa_moe.py): the selected pairs
+    # whose expert is held (expert_tokens summed), the pair rows the
+    # grouped product worked on (padding to its row tiles included), and
+    # the (query, key) positions whose score the attention computed, all
+    # over layers and trained lanes.
+    "expert_pairs_here": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "expert_rows_computed": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "attn_scores_computed": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     # Row-geometry pass fusion (parallel/streamed_geometry.py): planned
     # full-matrix HBM traversals the streamed row-geometry finish runs
     # this round under the fused pass plan, vs what the

@@ -2,8 +2,10 @@
 """Compile-only check of the language-model round's two big programs: the
 TPU compiler builds ``_train_block`` and ``_finish_fused_compact``
 (parallel/streamed.py) for device 0 of a described (not attached)
-``v5e:2x2`` at ``tuned_examples/fedavg_lm_crosssilo.yaml``'s shapes (10
-clients, 2 elided, ``client_block`` 1, rows of 4096 tokens) and prints each
+``v5e:2x2`` at a language-model YAML's shapes
+(``tuned_examples/fedavg_lm_crosssilo.yaml``: 10 clients, 2 elided,
+``client_block`` 1, rows of 4096 tokens; ``fedavg_codelm_crosssilo``: rows
+of 8192, the grouped product over the routed pairs) and prints each
 one's ``memory_analysis()``: arguments + outputs - aliased + temporaries is
 what the program needs of the chip's 15.75 GB.  The matrix is built by the
 round's own rule (``parallel/streamed.py::compact_matrix``: a row a plane
@@ -11,11 +13,13 @@ here, a block of one lane lying under a storage tile); each line also
 carries the layouts the compiler gave it and the block's stores into it
 (``is_index_aligned``, in place or not).
 
-    JAX_PLATFORMS=cpu python3 tools/aot_lm_round.py [key=json ...]
+    JAX_PLATFORMS=cpu python3 tools/aot_lm_round.py [yaml-stem] [key=json ...]
 
-``key=json`` pairs override the YAML's ``global_model`` (e.g.
-``num_nextn_predict_layers=1``: the MTP module at the chip's size, which
-PR 29 decided by these numbers).  About two minutes a block.
+``yaml-stem`` names the file under ``tuned_examples/`` (default
+``fedavg_lm_crosssilo``).  ``key=json`` pairs override the YAML's
+``global_model`` (e.g. ``num_nextn_predict_layers=1``: the MTP module at
+the chip's size, which PR 29 decided by these numbers), ``input_shape=``
+the row's length (PR 33 decided 8192 by them).  About two minutes a block.
 Nothing runs on a device: a compile that passes is not a chip run
 (tools/aot_train_block.py is the image cells' twin).
 """
@@ -55,17 +59,22 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
+    args = sys.argv[1:]
+    stem = args.pop(0) if args and "=" not in args[0] \
+        else "fedavg_lm_crosssilo"
     (spec,) = load_experiments_from_file(os.path.join(
-        CHECKOUT, "blades_tpu", "tuned_examples",
-        "fedavg_lm_crosssilo.yaml")).values()
+        CHECKOUT, "blades_tpu", "tuned_examples", stem + ".yaml")).values()
     (trial,) = [t for t in expand_grid(spec["config"])
                 if t["server_config"]["aggregator"]["type"] == "Median"]
     _, config = get_algorithm_class(spec["run"], return_config=True)
     config.update_from_dict(trial)
     model = dict(config.global_model)
-    for pair in sys.argv[1:]:
+    for pair in args:
         key, value = pair.split("=", 1)
-        model[key] = json.loads(value)
+        if key == "input_shape":
+            config.update_from_dict({key: json.loads(value)})
+        else:
+            model[key] = json.loads(value)
     config.update_from_dict({"global_model": model})
     config.validate()
     n, f = config.num_clients, config.num_malicious_clients

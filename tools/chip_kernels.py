@@ -22,7 +22,11 @@ admit.  Two consumers:
   readings ``pallas_select._STRIPE_BUDGET`` was chosen from.  ``--sweep
   planes [rows ...]`` does so over a matrix whose rows are planes
   (``s x 128`` columns a grid step: ``pallas_select._PLANE_BUDGET``'s
-  readings), and times the one-row store into it.
+  readings), and times the one-row store into it.  ``--grouped [tile ...]``
+  checks and times the grouped product over routed pairs
+  (:func:`grouped_product`: ``ops/grouped.py`` at the code-model cell's
+  shapes against the dense form, ``lax.ragged_dot`` and the dense masked
+  layer beside it: what decided which implementation ships, PR 33).
 
 - ``tests/test_chip_contract.py``, on the CPU: lowers each case for
   ``platforms=["tpu"]`` via ``jax.export`` — catches Pallas API drift in
@@ -636,6 +640,199 @@ def sweep(argv) -> int:
     return 0
 
 
+# (tm, tk, tn) the package's own ``gmm`` is timed at: what
+# ``ops/grouped.py::_TILE_KN`` was chosen from.  The projections' widths,
+# 2304 and 896, share no lane-tile divisor but 128; whole widths (256, 2304,
+# 896) are refused (18.88 MB of the 16 MiB scoped VMEM in ``tgmm``).
+PUBLIC_TILINGS = ((256, 128, 128), (256, 896, 896), (256, 1024, 1024),
+                  (512, 1024, 1024))
+
+GROUPED_LOADS = {
+    # pairs a held expert receives: 8 lanes x S 8192 x top-8 x 8/64 held
+    "even": (1024,) * 8,
+    "skewed": (3900, 2100, 900, 600, 300, 200, 100, 92),
+    "one_expert_takes_every_pair": (0, 0, 0, 8192, 0, 0, 0, 0),
+    "an_expert_without_a_pair": (2048, 0, 2048, 1024, 1024, 1024, 512, 512),
+    "every_token_selects_every_held_expert": (8192,) * 8,
+}
+
+
+def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
+                    impl="kernel") -> int:
+    """The grouped product of ``ops/grouped.py`` at the code-model cell's
+    shapes (a buffer of ``tokens x top_k`` pair rows, 8 held experts of
+    ``h x f``, bf16), for each load of ``GROUPED_LOADS``:
+
+    - one product ``(rows, h) x (8, h, f)``, forward and both cotangents,
+      against the dense form (every group's float32 product on every row,
+      kept where the row is the group's), over the rows in use;
+    - its time (forward + backward, the median of 5 calls) at each row tile
+      of ``argv`` (default 128 256 512) and its share of the MXU roofline
+      for the pairs in use; ``lax.ragged_dot``'s beside it;
+    - the whole routed layer (``routed_ffn``: sort, gather, three grouped
+      products, combine; forward + backward) under a drawn routing with
+      that load, beside the dense masked form (every held expert on every
+      token under a 0/1 weight: how ``models/mla_moe.py`` computes its
+      share)."""
+    from blades_tpu.ops import grouped
+
+    tiles = tuple(int(a) for a in argv) or (128, 256, 512)
+    rows, held, bf16 = tokens * top_k, 8, jnp.bfloat16
+    key = jax.random.split(jax.random.PRNGKey(33), 8)
+    lhs = jax.random.normal(key[0], (rows, h), bf16)
+    rhs = 0.02 * jax.random.normal(key[1], (held, h, f), jnp.float32)
+    ct = jax.random.normal(key[2], (rows, f), bf16)
+    x = jax.random.normal(key[3], (tokens, h), bf16)
+    gate, up = (0.02 * jax.random.normal(k, (held, h, f)).astype(bf16)
+                for k in key[4:6])
+    down = 0.02 * jax.random.normal(key[6], (held, f, h)).astype(bf16)
+
+    def timed(fn, *args, times=5):
+        jax.block_until_ready(fn(*args))
+        out = []
+        for _ in range(times):
+            t = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            out.append(time.perf_counter() - t)
+        return 1e3 * float(np.median(out))
+
+    def vjp_of(product):
+        def run(lhs, rhs, gs, ct):
+            out, vjp = jax.vjp(lambda a, b: product(a, b, gs), lhs, rhs)
+            return (out,) + vjp(ct)
+        return jax.jit(run)
+
+    @jax.jit
+    def dense_form(lhs, rhs, gs, ct):
+        ends = jnp.cumsum(gs)
+        row = jnp.arange(rows)[:, None]
+
+        def one(lhs, rhs):
+            out = 0.0
+            for g in range(held):
+                mine = (row >= ends[g] - gs[g]) & (row < ends[g])
+                out = out + jnp.where(mine, jnp.dot(
+                    lhs.astype(jnp.float32), rhs[g],
+                    precision=jax.lax.Precision.HIGHEST), 0)
+            return out
+
+        out, vjp = jax.vjp(one, lhs, rhs)
+        return (out,) + vjp(ct.astype(jnp.float32))
+
+    def routing(sizes, seed):
+        """``(expert, here)`` ``(tokens, top_k)``: a routing under which
+        held expert ``e`` is selected by ``sizes[e]`` tokens."""
+        rng = np.random.default_rng(seed)
+        expert = np.full((tokens, top_k), -1, np.int32)
+        for e, n in enumerate(sizes):
+            expert[rng.choice(tokens, n, replace=False), e] = e
+        return jnp.asarray(expert), jnp.asarray(expert >= 0)
+
+    def layer(fn):
+        def run(x, weight, gate, up, down):
+            y, vjp = jax.vjp(fn, x, weight, gate, up, down)
+            return (y,) + vjp(y)
+        return jax.jit(run)
+
+    def both_projections(gs):
+        """ms of one product forward + backward at the up projection's
+        shapes plus one at the down projection's: ``ops/grouped.py`` beside
+        the package's own ``megablox.gmm`` (its custom VJP, ONE tiling for
+        its three products) at each of ``PUBLIC_TILINGS``.  (Until the
+        review round of PR 33 ``ops/grouped.py`` called the kernels under
+        a VJP of its own with a tiling searched for each product: 4.02 ms
+        at 8192 pairs even, 14.5 at 65 536.)"""
+        from jax.experimental.pallas.ops.tpu import megablox
+
+        shapes = ((lhs, rhs, ct), (ct, rhs.swapaxes(1, 2), lhs))
+        products = {"ops_grouped": lambda a, b, g: grouped.grouped_matmul(
+            a, b.astype(bf16), g, impl=impl)}
+        for t in PUBLIC_TILINGS:
+            products["megablox_gmm_%dx%dx%d" % t] = (
+                lambda a, b, g, t=t: megablox.gmm(a, b.astype(bf16), g,
+                                                  bf16, t))
+        out = {}
+        for name, product in products.items():
+            run = vjp_of(product)
+            try:
+                out[name] = round(sum(timed(run, a, b, gs, c)
+                                      for a, b, c in shapes), 3)
+            except Exception as e:   # a refused tiling is a finding
+                out[name] = f"{type(e).__name__}: {e}"[-200:]
+        return out
+
+    records = []
+    for name, sizes in GROUPED_LOADS.items():
+        gs = jnp.asarray(sizes, jnp.int32)
+        pairs = int(sum(sizes))
+        used = np.arange(rows) < pairs
+        rec = {"load": name, "pairs": pairs, "sizes": list(sizes)}
+        want = [np.asarray(a, np.float64) for a in
+                dense_form(lhs, rhs, gs, ct)]
+        floor_ms = 1e3 * 3 * 2 * pairs * h * f / 197e12
+        for tile in tiles:
+            run = vjp_of(lambda a, b, g, t=tile: grouped.grouped_matmul(
+                a, b.astype(bf16), g, tile=t, impl=impl))
+            try:
+                got = run(lhs, rhs, gs, ct)
+                errs = []
+                for a, b in zip(got, want):
+                    a = np.asarray(a, np.float64)
+                    if a.shape[0] == rows:
+                        a, b = a[used], b[used]
+                    errs.append(float(np.abs(a - b).max()
+                                      / max(np.abs(b).max(), 1e-30))
+                                if b.size else 0.0)
+                ms = timed(run, lhs, rhs, gs, ct)
+                rec[f"tile{tile}"] = {
+                    "rel_err": [float(f"{e:.3g}") for e in errs],
+                    "ok": bool(max(errs) < 2e-2), "ms": round(ms, 3),
+                    "roofline_pct": round(100 * floor_ms / ms, 1),
+                    "rows_computed": int(grouped.rows_computed(gs, tile))}
+            except Exception as e:   # a refused tiling is a finding
+                rec[f"tile{tile}"] = {"ok": False, "error":
+                                      f"{type(e).__name__}: {e}"[-600:]}
+        if impl == "kernel":
+            rag = vjp_of(lambda a, b, g: jax.lax.ragged_dot(
+                a, b.astype(bf16), g))
+            try:
+                rec["ragged_dot_ms"] = round(timed(rag, lhs, rhs, gs, ct), 3)
+            except Exception as e:
+                rec["ragged_dot_error"] = f"{type(e).__name__}: {e}"[-300:]
+            rec["both_projections_ms"] = both_projections(gs)
+        expert, here = routing(sizes, 7)
+        weight = jax.random.uniform(key[7], (tokens, top_k), jnp.float32)
+        routed = layer(lambda x, w, g, u, d: grouped.routed_ffn(
+            x, expert, here, w, g, u, d, impl=impl)[0])
+
+        def masked(x, w, g, u, d):
+            w_held = (w[..., None] * (expert[..., None] == jnp.arange(held))
+                      ).sum(1)
+            a = jax.nn.silu(jnp.einsum("th,ehf->tef", x, g)) \
+                * jnp.einsum("th,ehf->tef", x, u)
+            return jnp.einsum("tef,efh->th", a * w_held.astype(
+                x.dtype)[..., None], d)
+
+        args = (x, weight, gate, up, down)
+        y_r, y_m = (np.asarray(fn(*args)[0], np.float64)
+                    for fn in (routed, layer(masked)))
+        rec["layer"] = {
+            "rel_err": float(f"{np.abs(y_r - y_m).max() / max(np.abs(y_m).max(), 1e-30):.3g}"),
+            "routed_ms": round(timed(routed, *args), 3),
+            "dense_masked_ms": round(timed(layer(masked), *args), 3),
+            "floor_ms": round(3 * floor_ms, 3)}
+        records.append(rec)
+        print(json.dumps(rec), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", "chip_grouped.json"), "w") as fh:
+        json.dump(records, fh, indent=1)
+    bad = [r["load"] for r in records
+           if not all(v.get("ok", True) for v in r.values()
+                      if isinstance(v, dict))]
+    print(json.dumps({"loads": len(records), "failed": bad}), flush=True)
+    return 1 if bad else 0
+
+
 def main(argv) -> int:
     dev = jax.devices()
     if dev[0].platform != "tpu":
@@ -644,6 +841,8 @@ def main(argv) -> int:
         return 2
     if argv and argv[0] == "--sweep":
         return sweep(argv[1:])
+    if argv and argv[0] == "--grouped":
+        return grouped_product(argv[1:])
     picked = [c for c in CASES
               if not argv or any(s in c.name for s in argv)]
     records = []
