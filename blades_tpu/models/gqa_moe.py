@@ -20,7 +20,12 @@ Every equation, by module (all from the published ``config.json``):
   kv_heads)`` and k, v are never repeated; rotary on q and k; scores
   ``q . k / sqrt(head_dim)``, softmax in float32; ``o = (P v) W_o``.  Query
   ``i`` sees key ``j`` iff they are of one document, ``j <= i`` and, in a
-  ``sliding_attention`` layer, ``i - j < sliding_window``;
+  ``sliding_attention`` layer, ``i - j < sliding_window``.  Computed by
+  :func:`~blades_tpu.models.layers.packed_causal_attention`: on a TPU, at
+  a sequence and head widths its tiles take, one fused kernel a call
+  (:mod:`blades_tpu.ops.attention`; a tile of scores never leaves VMEM);
+  elsewhere XLA query blocks of ``attn_block``, each rematerialised in the
+  backward pass (that path's own);
 - rotary by layer type (``rope_parameters[layer_type]``): ``default`` is
   ``theta ** (-2i / head_dim)``; ``yarn`` is static (applied at every
   length): :func:`~blades_tpu.models.layers.yarn_inv_freq` and cos, sin
@@ -42,7 +47,9 @@ Parameters the task must leave in float32 under mixed precision are named
 by ``float32_params`` (the router).  Device scopes: ``blades/attn_window``,
 ``blades/attn_full``, ``blades/router``, ``blades/experts``,
 ``blades/head``.  Each layer sows ``stats/attn_scores`` (the (query, key)
-positions its attention computes, from shapes), ``stats/expert_tokens``,
+positions its attention computes, from shapes: the XLA blocks' reach, or
+the fused kernel's own block list where that ran), ``stats/attn_fused``
+(1 where the fused kernel ran), ``stats/expert_tokens``,
 ``stats/routed_pairs`` and ``stats/expert_rows`` (pair rows the grouped
 product worked on); :meth:`GqaMoeLM.round_counters` reduces a round's to
 its row counters.
@@ -68,7 +75,7 @@ from blades_tpu.models.layers import (
     rotary_interleaved,
     yarn_inv_freq,
 )
-from blades_tpu.ops import grouped
+from blades_tpu.ops import attention, grouped
 
 # The token that starts a document in a packed row (data/datasets.py).
 BOS_ID = 0
@@ -186,10 +193,12 @@ class GroupedQueryAttention(nn.Module):
                                    rope["inv_freq"], rope["attention_factor"])
             o = packed_causal_attention(q, k, v, segment, dim ** -0.5,
                                         c.attn_block, window)
+            fused = attention.default_impl(s, dim, dim) != "jnp"
             # A host int from shapes; past int32 from two rows of 8192 on.
-            self.sow("stats", "attn_scores", jnp.float32(
-                b * heads * attention_scores_computed(s, c.attn_block,
-                                                      window)))
+            self.sow("stats", "attn_scores", jnp.float32(b * heads * (
+                attention.scores_computed(s, window) if fused else
+                attention_scores_computed(s, c.attn_block, window))))
+            self.sow("stats", "attn_fused", jnp.int32(fused))
             return Linear(c.hidden_size, name="o")(
                 o.reshape(b, s, heads * dim))
 
@@ -270,6 +279,7 @@ class GqaMoeLM(nn.Module):
             "expert_pairs_here": tokens.sum(),
             "expert_rows_computed": stats["expert_rows"].sum(),
             "attn_scores_computed": stats["attn_scores"].sum(),
+            "attn_fused_calls": stats["attn_fused"].sum(),
         }
 
     @nn.compact

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from blades_tpu.ops import attention
+
 # --------------------------------------------------------------------------
 # Hand-written batch-stats-norm VJP
 # --------------------------------------------------------------------------
@@ -230,7 +232,8 @@ class BatchStatsNorm(nn.Module):
 
 # --------------------------------------------------------------------------
 # Sequence-model primitives: RMS norm, interleaved rotary, SwiGLU, and
-# causal attention over packed documents in query blocks
+# causal attention over packed documents (a fused kernel on a TPU,
+# ops/attention.py; XLA query blocks elsewhere)
 # --------------------------------------------------------------------------
 
 
@@ -345,27 +348,44 @@ def attention_key_start(q0: int, block: int, window=None) -> int:
 
 
 def attention_scores_computed(s: int, block: int = 512, window=None) -> int:
-    """(query, key) positions of one row of ``s`` tokens whose score
-    :func:`packed_causal_attention` computes, a head: what its blocks
-    read, masked or not."""
+    """(query, key) positions of one row of ``s`` tokens whose score the
+    XLA query blocks of :func:`packed_causal_attention` compute, a head:
+    what its blocks read, masked or not.  (The fused kernel's count is
+    :func:`blades_tpu.ops.attention.scores_computed`.)"""
     block = min(block, s)
     return sum(block * (q0 + block - attention_key_start(q0, block, window))
                for q0 in range(0, s, block))
 
 
 def packed_causal_attention(q, k, v, segment, scale: float,
-                            block: int = 512, window=None) -> jnp.ndarray:
-    """Softmax attention, causal within a document, in query blocks.
+                            block: int = 512, window=None,
+                            impl=None) -> jnp.ndarray:
+    """Softmax attention, causal within a document.
 
     ``q`` is ``(B, S, H, dk)``, ``k`` ``(B, S, Hk, dk)``, ``v`` ``(B, S,
     Hk, dv)``, ``segment`` ``(B, S)``.  ``H`` is a multiple of ``Hk``:
     query head ``h`` reads key head ``h // (H // Hk)`` (grouped-query
     attention; k and v are never repeated).  With ``window`` a query sees
-    only the keys less than ``window`` positions behind it.  The query
-    block at ``q0`` reads keys ``[attention_key_start(q0), q0 + block)``
-    only and is rematerialised in the backward pass, so no ``(H, S, S)``
-    score array outlives its block; scores and the softmax are float32."""
+    only the keys less than ``window`` positions behind it.  Scores and
+    the softmax are float32 on either path:
+
+    - ``impl="kernel"`` (the default where :func:`blades_tpu.ops.attention.
+      kernel_applicable`: a TPU, a sequence and head widths its tiles
+      take): one fused kernel a call, which keeps a tile of scores in VMEM
+      and computes them again in its own backward kernels; ``block`` is
+      not read.  ``"interpret"`` is that kernel in Pallas's interpreter
+      (the tests);
+    - ``impl="jnp"`` (the default elsewhere): XLA query blocks of
+      ``block``.  The block at ``q0`` reads keys ``[attention_key_start(
+      q0), q0 + block)`` only and is rematerialised in the backward pass
+      (this path's own ``jax.checkpoint``), so no ``(H, S, S)`` score array
+      outlives its block."""
     s, heads, kv_heads = q.shape[1], q.shape[2], k.shape[2]
+    if impl is None:
+        impl = attention.default_impl(s, q.shape[-1], v.shape[-1])
+    if impl != "jnp":
+        return attention.fused_causal_attention(
+            q, k, v, segment, scale, window, interpret=impl == "interpret")
     block = min(block, s)
     if s % block:
         raise ValueError(f"sequence length {s} is no multiple of the "

@@ -16,7 +16,12 @@ Every equation, by module:
   ``[k_nope; v] = c_kv W_kvb``; ``k_r`` is one rotary key for all heads;
   scores ``(q_nope . k_nope + q_r . k_r) / sqrt(nope + rope)``, softmax in
   float32; ``o = (P v) W_o``.  Training materialises k and v from the
-  latent: no absorbed form, no cache;
+  latent: no absorbed form, no cache.  Computed by
+  :func:`~blades_tpu.models.layers.packed_causal_attention`: on a TPU, at
+  a sequence and head widths its tiles take, one fused kernel a call
+  (:mod:`blades_tpu.ops.attention`); elsewhere XLA query blocks of
+  ``attn_block``, each rematerialised in the backward pass (that path's
+  own);
 - :class:`ExpertShare`: ``s = sigmoid(x W_g)`` in float32 over ALL routed
   experts, top-k of ``s + b`` (``noaux_tc``, one group), weights
   ``s_i / sum_topk s * routed_scaling_factor``; the layer is told which
@@ -34,7 +39,8 @@ by ``float32_params`` (the router: bf16 scores flip near-tied top-k
 choices).  Device scopes: ``blades/attn``, ``blades/router``,
 ``blades/experts``, ``blades/head``.  Each expert layer sows
 ``stats/expert_tokens`` (tokens each held expert received) and
-``stats/routed_pairs`` (the (token, expert) pairs it routed, held or not);
+``stats/routed_pairs`` (the (token, expert) pairs it routed, held or not),
+each attention ``stats/attn_fused`` (1 where the fused kernel ran);
 :meth:`MlaMoeLM.round_counters` reduces a round's to its row counters.
 
 The model returns one float32 ``(batch, S, vocab)`` logits plane per
@@ -61,6 +67,7 @@ from blades_tpu.models.layers import (
     packed_positions,
     rotary_interleaved,
 )
+from blades_tpu.ops import attention
 
 
 # The token that starts a document in a packed row (data/datasets.py).
@@ -150,6 +157,8 @@ class LatentAttention(nn.Module):
             o = packed_causal_attention(
                 q, k, kvb[..., nope:], segment, (nope + rope) ** -0.5,
                 c.attn_block)
+            self.sow("stats", "attn_fused", jnp.int32(attention.default_impl(
+                s, nope + rope, c.v_head_dim) != "jnp"))
             return Linear(c.hidden_size, name="o")(
                 o.reshape(b, s, heads * c.v_head_dim))
 
@@ -231,7 +240,8 @@ class MlaMoeLM(nn.Module):
         layers sowed, stacked ``(lanes, layers, ...)`` over the trained
         lanes: the most and the mean tokens a held expert received; the
         share of routed (token, expert) pairs whose expert is held here;
-        the (lane, layer, held expert) blocks that received no token."""
+        the (lane, layer, held expert) blocks that received no token; the
+        (lane, layer)s whose attention ran the fused kernel."""
         tokens = stats["expert_tokens"]
         return {
             "expert_tokens_max": tokens.max(),
@@ -239,6 +249,7 @@ class MlaMoeLM(nn.Module):
             "routed_here_share": tokens.sum() / stats["routed_pairs"].sum()
             .astype(jnp.float32),
             "zero_expert_blocks": (tokens == 0).sum(),
+            "attn_fused_calls": stats["attn_fused"].sum(),
         }
 
     @nn.compact

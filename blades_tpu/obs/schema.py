@@ -200,10 +200,13 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     # whose expert is held (expert_tokens summed), the pair rows the
     # grouped product worked on (padding to its row tiles included), and
     # the (query, key) positions whose score the attention computed, all
-    # over layers and trained lanes.
+    # over layers and trained lanes.  attn_fused_calls (both language
+    # models): the (lane, layer)s whose attention ran the fused kernel
+    # (ops/attention.py); 0 wherever the XLA query blocks ran.
     "expert_pairs_here": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "expert_rows_computed": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "attn_scores_computed": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "attn_fused_calls": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     # Row-geometry pass fusion (parallel/streamed_geometry.py): planned
     # full-matrix HBM traversals the streamed row-geometry finish runs
     # this round under the fused pass plan, vs what the

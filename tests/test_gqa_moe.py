@@ -311,8 +311,10 @@ def test_the_counters_are_valid_rows_and_count_what_they_say():
         [[[6, 0, 2, 0]], [[0, 0, 8, 0]]], jnp.int32),
         "routed_pairs": jnp.full((2, 1), 64, jnp.int32),
         "expert_rows": jnp.asarray([[16], [8]], jnp.int32),
-        "attn_scores": jnp.asarray([[1000], [1000]], jnp.float32)}
+        "attn_scores": jnp.asarray([[1000], [1000]], jnp.float32),
+        "attn_fused": jnp.asarray([[1], [0]], jnp.int32)}
     got = jax.device_get(jax.jit(task.round_counters)(stats))
+    assert int(got["attn_fused_calls"]) == 1
     assert int(got["expert_tokens_max"]) == 8
     assert float(got["routed_here_share"]) == 16 / 128
     assert int(got["zero_expert_blocks"]) == 5

@@ -163,13 +163,16 @@ def test_router_bias_gets_no_gradient_and_evaluate_counts_tokens():
 
 
 def test_the_model_reduces_its_own_stats_to_the_rows_counters():
-    """(lanes, layers, held) tokens and (lanes, layers) routed pairs ->
-    the four counters; a model that sows nothing has none."""
+    """(lanes, layers, held) tokens, (lanes, layers) routed pairs and
+    fused attention calls -> the five counters; a model that sows nothing
+    has none."""
     task = _task()
     stats = {"expert_tokens": jnp.asarray(
         [[[6, 0, 2, 0]], [[0, 0, 8, 0]]], jnp.int32),
-        "routed_pairs": jnp.full((2, 1), 64, jnp.int32)}
+        "routed_pairs": jnp.full((2, 1), 64, jnp.int32),
+        "attn_fused": jnp.asarray([[1, 1], [1, 0]], jnp.int32)}
     got = jax.jit(task.round_counters)(stats)
+    assert int(got["attn_fused_calls"]) == 3
     assert int(got["expert_tokens_max"]) == 8
     assert float(got["expert_tokens_mean"]) == 2.0
     assert float(got["routed_here_share"]) == 16 / 128

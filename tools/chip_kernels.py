@@ -27,6 +27,10 @@ admit.  Two consumers:
   (:func:`grouped_product`: ``ops/grouped.py`` at the code-model cell's
   shapes against the dense form, ``lax.ragged_dot`` and the dense masked
   layer beside it: what decided which implementation ships, PR 33).
+  ``--attention [block ...]`` times the attention alone at the two
+  language-model cells' shapes, the XLA query blocks beside the fused
+  kernel at each tiling (:func:`attention_forms`: what
+  ``ops/attention.py::BLOCKS`` was chosen from, PR 34).
 
 - ``tests/test_chip_contract.py``, on the CPU: lowers each case for
   ``platforms=["tpu"]`` via ``jax.export`` — catches Pallas API drift in
@@ -647,6 +651,18 @@ def sweep(argv) -> int:
 PUBLIC_TILINGS = ((256, 128, 128), (256, 896, 896), (256, 1024, 1024),
                   (512, 1024, 1024))
 
+def _median_ms(fn, *args, times=5):
+    """ms of ``fn(*args)`` to completion: the median of ``times`` calls
+    after one that compiles."""
+    jax.block_until_ready(fn(*args))
+    out = []
+    for _ in range(times):
+        t = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        out.append(time.perf_counter() - t)
+    return 1e3 * float(np.median(out))
+
+
 GROUPED_LOADS = {
     # pairs a held expert receives: 8 lanes x S 8192 x top-8 x 8/64 held
     "even": (1024,) * 8,
@@ -687,14 +703,7 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
                 for k in key[4:6])
     down = 0.02 * jax.random.normal(key[6], (held, f, h)).astype(bf16)
 
-    def timed(fn, *args, times=5):
-        jax.block_until_ready(fn(*args))
-        out = []
-        for _ in range(times):
-            t = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            out.append(time.perf_counter() - t)
-        return 1e3 * float(np.median(out))
+    timed = _median_ms
 
     def vjp_of(product):
         def run(lhs, rhs, gs, ct):
@@ -833,6 +842,156 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
     return 1 if bad else 0
 
 
+# (S, query heads, key heads, key width, value width, window) of one row of
+# one lane, as the two language-model cells call the attention.
+ATTENTION_SHAPES = {
+    "mellum2_window": (8192, 32, 4, 128, 128, 1024),
+    "mellum2_full": (8192, 32, 4, 128, 128, None),
+    "joyai": (4096, 32, 32, 192, 128, None),
+}
+# Tilings timed by default: the six ``BlockSizes`` numbers (one number
+# stands for all six, three for the forward and the dk/dv kernel alike),
+# ``/split`` for a dq kernel of its own, ``/pad`` with the keys' features
+# padded with zeros to whole lane tiles (only where they are not).
+ATTENTION_BLOCKS = ("512,512,512,1024,1024,512",        # what ships
+                    "512,512,512,1024,1024,512/split",
+                    "512,512,512,1024,1024,512/pad", "512", "512/split",
+                    "256", "1024,1024,512", "1024,1024,512/split",
+                    "512,512,512,1024,1024,1024",
+                    "512,512,512,1024,2048,512",
+                    "512,512,512,512,1024,512")
+
+
+def attention_forms(argv, impl="kernel", shapes=None, times=5) -> int:
+    """The attention ALONE, bf16, one row of one lane under the models' two
+    ``vmap``s, at each shape of ``ATTENTION_SHAPES`` with documents of
+    2048 tokens on average:
+
+    - the XLA query blocks (``models/layers.py::packed_causal_attention``
+      with ``impl="jnp"``, blocks of 512 rematerialised as the models run
+      them): forward, and forward + backward;
+    - the fused kernel (``ops/attention.py``) at each tiling of ``argv``
+      (default ``ATTENTION_BLOCKS``; ``bq,bkv,bkv_compute,bq_dkv,bkv_dkv,
+      bkv_dkv_compute``, the first three for both kernels or one number
+      for all six; ``/split`` for a dq kernel of its own instead of the
+      fused backward kernel, ``/pad`` with q's and k's features padded
+      with zeros to whole lane tiles): the same two
+      times, its largest difference from the XLA form (output and the
+      three cotangents, relative to the form's largest value) and the
+      positions it scores over those required;
+    - ``err_f32``: each form's largest difference from the XLA blocks in
+      float32 at ``HIGHEST`` on the same bf16 operands, the same four
+      arrays: what either form's roundings cost;
+    - each time's share of the MXU roofline for the REQUIRED positions
+      (``sum_i min(i + 1, window)`` a head, documents not counted; 2
+      operations a multiply-add over both contractions, backward twice
+      the forward)."""
+    from blades_tpu.models import layers
+    from blades_tpu.ops import attention
+
+    bf16 = jnp.bfloat16
+    specs = tuple(argv) or ATTENTION_BLOCKS
+    interpret = impl == "interpret"
+
+    def timed(fn, *args):
+        return _median_ms(fn, *args, times=times)
+
+    def rel(got, ref):
+        """Largest difference of each array from its reference, relative
+        to the reference's largest value."""
+        return [float(f"{np.abs(np.asarray(a, np.float64) - b).max() / np.abs(b).max():.3g}")
+                for a, b in zip(got, ref)]
+
+    def both(form):
+        """``(forward, forward + backward)`` of ``form(q, k, v, seg)``
+        under the lanes' ``vmap``, jitted."""
+        def fwd(q, k, v, seg, ct):
+            return jax.vmap(form)(q, k, v, seg)
+
+        def fwd_bwd(q, k, v, seg, ct):
+            out, vjp = jax.vjp(
+                lambda q, k, v: jax.vmap(form)(q, k, v, seg), q, k, v)
+            return (out,) + vjp(ct)
+        return jax.jit(fwd), jax.jit(fwd_bwd)
+
+    records = []
+    for name, (s, heads, kv_heads, dk, dv, window) in (
+            shapes or ATTENTION_SHAPES).items():
+        key = jax.random.split(jax.random.PRNGKey(34), 5)
+        q = jax.random.normal(key[0], (1, 1, s, heads, dk), bf16)
+        k = jax.random.normal(key[1], (1, 1, s, kv_heads, dk), bf16)
+        v = jax.random.normal(key[2], (1, 1, s, kv_heads, dv), bf16)
+        ct = jax.random.normal(key[3], (1, 1, s, heads, dv), bf16)
+        seg = jnp.cumsum(jax.random.bernoulli(
+            key[4], max(1 / 2048, 4 / s), (1, 1, s)).astype(jnp.int32), -1)
+        args = (q, k, v, seg, ct)
+        required = sum(min(i + 1, window or s) for i in range(s))
+        fwd_floor = 1e3 * heads * 2 * required * (dk + dv) / 197e12
+        scale = dk ** -0.5
+
+        def line(fwd_ms, both_ms):
+            return {"fwd_ms": round(fwd_ms, 3),
+                    "fwd_bwd_ms": round(both_ms, 3),
+                    "fwd_roofline_pct": round(100 * fwd_floor / fwd_ms, 1),
+                    "fwd_bwd_roofline_pct": round(
+                        300 * fwd_floor / both_ms, 1)}
+
+        rec = {"shape": name, "s": s, "heads": heads, "kv_heads": kv_heads,
+               "dk": dk, "dv": dv, "window": window,
+               "documents": int(seg.max()) + 1,
+               "required_positions_a_head": required,
+               "fwd_floor_ms": round(fwd_floor, 3)}
+        def xla_blocks(q, k, v, seg):
+            return layers.packed_causal_attention(
+                q, k, v, seg, scale, 512, window, impl="jnp")
+
+        xla_fwd, xla_both = both(xla_blocks)
+        want = [np.asarray(a, np.float64) for a in xla_both(*args)]
+        with jax.default_matmul_precision("highest"):
+            exact = [np.asarray(a, np.float64) for a in both(xla_blocks)[1](
+                *(a.astype(jnp.float32) if a.dtype == bf16 else a
+                  for a in args))]
+        rec["xla_blocks"] = dict(
+            line(timed(xla_fwd, *args), timed(xla_both, *args)),
+            err_f32=rel(want, exact),
+            scored_over_required=round(layers.attention_scores_computed(
+                s, 512, window) / required, 4))
+        for spec in specs:
+            sizes, _, how = spec.partition("/")
+            blocks = tuple(int(b) for b in sizes.split(","))
+            blocks = blocks * (6 // len(blocks))
+            pad = -dk % 128 if how == "pad" else 0
+            if how == "pad" and not pad:
+                continue
+            wider = ((0, 0),) * 3 + ((0, pad),)
+            try:
+                k_fwd, k_both = both(lambda q, k, v, seg, b=blocks: (
+                    attention.fused_causal_attention(
+                        jnp.pad(q, wider), jnp.pad(k, wider), v, seg, scale,
+                        window, blocks=b, fused_bwd=how != "split",
+                        interpret=interpret)))
+                got = k_both(*args)
+                diff = rel(got, want)
+                rec[f"kernel_{spec}"] = dict(
+                    line(timed(k_fwd, *args), timed(k_both, *args)),
+                    rel_diff=diff, err_f32=rel(got, exact),
+                    ok=bool(max(diff) < 3e-2),
+                    scored_over_required=round(attention.scores_computed(
+                        s, window, blocks) / required, 4))
+            except Exception as e:   # a refused tiling is a finding
+                rec[f"kernel_{spec}"] = {
+                    "ok": False, "error": f"{type(e).__name__}: {e}"[-400:]}
+        records.append(rec)
+        print(json.dumps(rec), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", "chip_attention.json"), "w") as fh:
+        json.dump(records, fh, indent=1)
+    bad = [f"{r['shape']}:{k}" for r in records for k, v in r.items()
+           if isinstance(v, dict) and not v.get("ok", True)]
+    print(json.dumps({"shapes": len(records), "failed": bad}), flush=True)
+    return 1 if bad else 0
+
+
 def main(argv) -> int:
     dev = jax.devices()
     if dev[0].platform != "tpu":
@@ -843,6 +1002,8 @@ def main(argv) -> int:
         return sweep(argv[1:])
     if argv and argv[0] == "--grouped":
         return grouped_product(argv[1:])
+    if argv and argv[0] == "--attention":
+        return attention_forms(argv[1:])
     picked = [c for c in CASES
               if not argv or any(s in c.name for s in argv)]
     records = []
