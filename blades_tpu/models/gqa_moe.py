@@ -291,12 +291,19 @@ class GqaMoeLM(nn.Module):
         head = kernel_param(self, "head", (c.hidden_size, c.vocab_size))
         segment, position = packed_positions(tokens, BOS_ID)
         x = emb[tokens]
-        # A layer's forward pass is computed again in the backward pass: a
-        # row of 8192 tokens keeps a layer's input only, not its attention
-        # and pair rows.
-        layer = nn.remat(DecoderLayer)
+        # No layer is rematerialised: the backward pass reads what each
+        # layer's forward pass kept (q, k and v after the rotary, the
+        # attention's output and log-sum-exp, the projections' inputs, the
+        # routed layer's gathered rows and SwiGLU activations) and computes
+        # no forward pass again.  At the chip's shapes (rows of 8192, one
+        # lane a block, four layers) the compiler's account of the
+        # language-model round's ``_train_block`` is 13.76 of 15.75 GB
+        # with them, 10.21 GB under a layer-wide remat
+        # (tools/aot_lm_round.py fedavg_codelm_crosssilo).  A longer row or
+        # more rows a lane grows them in proportion: read that account
+        # again before either.
         for i in range(c.num_hidden_layers):
-            x = layer(c, c.layer_types[i], name=f"layer_{i}")(
+            x = DecoderLayer(c, c.layer_types[i], name=f"layer_{i}")(
                 x, segment, position)
         hidden = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
         with jax.named_scope("blades/head"):

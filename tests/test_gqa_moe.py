@@ -5,6 +5,7 @@ its attention (models/layers.py) and the grouped product
 top-2, vocabulary 256, rows of 32 tokens.  (The comparison with the plain
 reference lives in tests/perfbench/test_pb_gqa_moe_lm.py.)"""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +14,7 @@ import pytest
 
 from blades_tpu.core.task import TaskSpec
 from blades_tpu.data.datasets import build_packed_tokens, pack_documents
-from blades_tpu.models import layers, mla_moe
+from blades_tpu.models import gqa_moe, layers, mla_moe
 from blades_tpu.models.catalog import ModelCatalog
 from blades_tpu.models.gqa_moe import (
     GqaMoeConfig,
@@ -33,6 +34,14 @@ SMALL = dict(
 def _task(**kw):
     return TaskSpec(model=dict(SMALL, **kw), num_classes=256,
                     input_shape=(32,), lr=0.1).build()
+
+
+def _row():
+    """One client's two packed rows of 32 tokens and their targets."""
+    ds = build_packed_tokens(num_clients=1, seed=1, seq_len=32,
+                             vocab_size=256, train_rows=2, test_rows=1,
+                             doc_median=10)
+    return jnp.asarray(ds.train.x[0]), jnp.asarray(ds.train.y[0])
 
 
 # -- the grouped product ------------------------------------------------------
@@ -363,6 +372,102 @@ def test_two_rows_of_8192_count_their_scores_past_int32(kind, window):
     np.testing.assert_allclose(sown, want, rtol=1e-7)
 
 
+def _remat_layers(monkeypatch):
+    """Every decoder layer of the model under ``nn.remat``: the backward
+    pass then computes each layer's forward pass again."""
+    monkeypatch.setattr(gqa_moe, "DecoderLayer",
+                        nn.remat(gqa_moe.DecoderLayer))
+
+
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+def test_layers_without_remat_give_the_rematerialised_models_numbers(
+        monkeypatch, compute_dtype):
+    """Keeping the residuals changes what the backward pass reads, not what
+    it computes: in float32 logits, loss and gradients are those of the
+    model with each layer under ``nn.remat``, to the bit.  In bf16 the
+    forward pass is the same to the bit, but the backward pass then reads
+    the forward pass's own bf16 values where the remat read a
+    recomputation that XLA fuses (and so rounds) differently; a leaf's
+    gradient moves by a few bf16 roundings of its largest value."""
+    task = TaskSpec(model=SMALL, num_classes=256, input_shape=(32,), lr=0.1,
+                    compute_dtype=compute_dtype).build()
+    params = task.init_params(jax.random.PRNGKey(0))
+    x, y = _row()
+
+    def run():
+        # Fresh functions each time: a trace cached from the other model
+        # would compare the model with itself.
+        planes = jax.jit(lambda p: task.sequence_planes(
+            task.cast_to_compute(p), x))(params)
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: task.loss_fn(p, x, y)))(params)
+        return planes, loss, grads
+
+    mine = run()
+    _remat_layers(monkeypatch)
+    theirs = run()
+    for a, b in zip(jax.tree.leaves(mine[:2]), jax.tree.leaves(theirs[:2])):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(mine[2]), jax.tree.leaves(theirs[2])):
+        if compute_dtype is None:
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=0.04 * float(jnp.abs(b).max()))
+
+
+def _inner_jaxprs(eqn):
+    for param in eqn.params.values():
+        for sub in param if isinstance(param, (list, tuple)) else [param]:
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _primitives(jaxpr):
+    return {name for eqn in jaxpr.eqns for name in
+            {eqn.primitive.name}.union(*map(_primitives, _inner_jaxprs(eqn)))}
+
+
+def _checkpoints(jaxpr, kind, under=0):
+    """``(how many checkpoints enclose it, the primitives it encloses)``
+    for every equation of primitive ``kind`` in ``jaxpr`` and in the
+    jaxprs inside it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = eqn.primitive is kind
+        if here:
+            found.append((under, _primitives(eqn.params["jaxpr"])))
+        for sub in _inner_jaxprs(eqn):
+            found += _checkpoints(sub, kind, under + here)
+    return found
+
+
+def test_the_gradient_holds_no_checkpoint_around_a_layer(monkeypatch):
+    """The gradient's checkpoints are the XLA attention path's query blocks,
+    one a block a layer, none inside another and none around the routed
+    layer; a layer-wide remat put back (here by hand) fails each count."""
+    task = _task()
+    params = task.init_params(jax.random.PRNGKey(0))
+    x, y = _row()
+    blocks = SMALL["num_hidden_layers"] * 32 // SMALL["attn_block"]
+    kind = jax.make_jaxpr(jax.checkpoint(jnp.sin))(1.0).eqns[0].primitive
+
+    def checkpoints():
+        return _checkpoints(jax.make_jaxpr(jax.grad(
+            lambda p: task.loss_fn(p, x, y)))(params).jaxpr, kind)
+
+    mine = checkpoints()
+    assert len(mine) == blocks
+    assert all(under == 0 and "custom_vjp_call" not in inside
+               and "dot_general" in inside for under, inside in mine)
+    _remat_layers(monkeypatch)
+    theirs = checkpoints()
+    assert len(theirs) > blocks
+    assert any(under for under, _ in theirs)
+    assert any("custom_vjp_call" in inside for _, inside in theirs)
+
+
 def test_the_router_stays_float32_and_a_dict_spec_resolves():
     task = TaskSpec(model=SMALL, num_classes=256, input_shape=(32,),
                     compute_dtype="bfloat16").build()
@@ -427,10 +532,7 @@ def test_the_mla_models_logits_and_gradients_are_the_parents_bit_for_bit(
         v_head_dim=16, num_nextn_predict_layers=1, attn_block=8)
     task = TaskSpec(model=spec, num_classes=256, input_shape=(32,)).build()
     params = task.init_params(jax.random.PRNGKey(0))
-    ds = build_packed_tokens(num_clients=1, seed=1, seq_len=32,
-                             vocab_size=256, train_rows=2, test_rows=1,
-                             doc_median=10)
-    x, y = jnp.asarray(ds.train.x[0]), jnp.asarray(ds.train.y[0])
+    x, y = _row()
 
     def run():
         planes = jax.jit(task.sequence_planes)(params, x)
