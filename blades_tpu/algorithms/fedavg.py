@@ -31,7 +31,7 @@ import numpy as np
 from blades_tpu.adversaries import make_malicious_mask
 from blades_tpu.core import FedRound
 from blades_tpu.data import DatasetCatalog
-from blades_tpu.obs.trace import Timers, now
+from blades_tpu.obs.trace import Tracer, now
 
 
 class Fedavg:
@@ -39,7 +39,7 @@ class Fedavg:
 
     def __init__(self, config):
         self.config = config
-        self.timers = Timers()
+        self.timers = Tracer()
         with self.timers.span("blades/setup"):
             self._setup()
 
@@ -1200,7 +1200,7 @@ class Fedavg:
 
     def _train_raw(self) -> Dict:
         cycle_t0 = now() if self._async is not None else None
-        with self.timers.time("training_step"):
+        with self.timers.span("training_step"):
             if self._async is not None:
                 # One buffered-async cycle: the engine advances the
                 # virtual clock to the next full buffer and fires ONE
@@ -1602,7 +1602,7 @@ class Fedavg:
 
     def evaluate(self) -> Dict:
         """Weighted per-client evaluation (ref: fedavg.py:247-279)."""
-        with self.timers.time("evaluate"):
+        with self.timers.span("evaluate"):
             if self._eval_chunk_fn is not None:
                 # Streaming eval (blades_tpu/data/stream): walk the
                 # host test stack in bounded device-sized chunks — the

@@ -44,8 +44,11 @@ Every equation, by module (all from the published ``config.json``):
   would add is left out; no token is dropped, no capacity is set.
 
 Parameters the task must leave in float32 under mixed precision are named
-by ``float32_params`` (the router).  Device scopes: ``blades/attn_window``,
-``blades/attn_full``, ``blades/router``, ``blades/experts``,
+by ``float32_params`` (the router).  Device scopes: ``blades/embed``,
+``blades/attn_window``, ``blades/attn_full`` and inside either
+``blades/attn_kernel`` (the attention itself, apart from its projections
+and rotaries), ``blades/router``, ``blades/experts`` (and inside it
+``ops/grouped.py``'s ``blades/dispatch`` and ``blades/combine``),
 ``blades/head``.  Each layer sows ``stats/attn_scores`` (the (query, key)
 positions its attention computes, from shapes: the XLA blocks' reach, or
 the fused kernel's own block list where that ran), ``stats/attn_fused``
@@ -191,8 +194,9 @@ class GroupedQueryAttention(nn.Module):
                                    rope["inv_freq"], rope["attention_factor"])
             k = rotary_interleaved(k, position, rope["theta"],
                                    rope["inv_freq"], rope["attention_factor"])
-            o = packed_causal_attention(q, k, v, segment, dim ** -0.5,
-                                        c.attn_block, window)
+            with jax.named_scope("blades/attn_kernel"):
+                o = packed_causal_attention(q, k, v, segment, dim ** -0.5,
+                                            c.attn_block, window)
             fused = attention.default_impl(s, dim, dim) != "jnp"
             # A host int from shapes; past int32 from two rows of 8192 on.
             self.sow("stats", "attn_scores", jnp.float32(b * heads * (
@@ -290,7 +294,8 @@ class GqaMoeLM(nn.Module):
                          (c.vocab_size, c.hidden_size))
         head = kernel_param(self, "head", (c.hidden_size, c.vocab_size))
         segment, position = packed_positions(tokens, BOS_ID)
-        x = emb[tokens]
+        with jax.named_scope("blades/embed"):
+            x = emb[tokens]
         # No layer is rematerialised: the backward pass reads what each
         # layer's forward pass kept (q, k and v after the rotary, the
         # attention's output and log-sum-exp, the projections' inputs, the

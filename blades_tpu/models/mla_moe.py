@@ -36,9 +36,10 @@ Every equation, by module:
 
 Parameters the task must leave in float32 under mixed precision are named
 by ``float32_params`` (the router: bf16 scores flip near-tied top-k
-choices).  Device scopes: ``blades/attn``, ``blades/router``,
-``blades/experts``, ``blades/head``.  Each expert layer sows
-``stats/expert_tokens`` (tokens each held expert received) and
+choices).  Device scopes: ``blades/attn`` and inside it
+``blades/attn_kernel`` (the attention itself, apart from its projections
+and rotary), ``blades/router``, ``blades/experts``, ``blades/head``.
+Each expert layer sows ``stats/expert_tokens`` (tokens each held expert received) and
 ``stats/routed_pairs`` (the (token, expert) pairs it routed, held or not),
 each attention ``stats/attn_fused`` (1 where the fused kernel ran);
 :meth:`MlaMoeLM.round_counters` reduces a round's to its row counters.
@@ -154,9 +155,10 @@ class LatentAttention(nn.Module):
                 [kvb[..., :nope],
                  jnp.broadcast_to(k_r[:, :, None, :], (b, s, heads, rope))],
                 axis=-1)
-            o = packed_causal_attention(
-                q, k, kvb[..., nope:], segment, (nope + rope) ** -0.5,
-                c.attn_block)
+            with jax.named_scope("blades/attn_kernel"):
+                o = packed_causal_attention(
+                    q, k, kvb[..., nope:], segment, (nope + rope) ** -0.5,
+                    c.attn_block)
             self.sow("stats", "attn_fused", jnp.int32(attention.default_impl(
                 s, nope + rope, c.v_head_dim) != "jnp"))
             return Linear(c.hidden_size, name="o")(

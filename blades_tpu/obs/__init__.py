@@ -60,7 +60,6 @@ from blades_tpu.obs.schema import (  # noqa: F401
     validate_record,
 )
 from blades_tpu.obs.trace import (  # noqa: F401
-    Timers,
     Tracer,
     validate_chrome_trace,
 )

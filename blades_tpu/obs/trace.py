@@ -38,7 +38,7 @@ from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
-    "Span", "Tracer", "Timers", "now", "span", "trace",
+    "Span", "Tracer", "now", "span", "trace",
     "validate_chrome_trace",
 ]
 
@@ -51,7 +51,7 @@ def now() -> float:
 
 
 # Recorded-span cap: a pathological million-round sweep must degrade to
-# aggregation-only (the old Timers behavior), never OOM the host.  The
+# aggregation-only (an un-armed tracer's behavior), never OOM the host.  The
 # cap is per tracer; dropped spans are counted in the export metadata.
 MAX_RECORDED_SPANS = 200_000
 
@@ -59,8 +59,8 @@ MAX_RECORDED_SPANS = 200_000
 @dataclasses.dataclass
 class Span:
     """One timed region.  ``attrs`` carries provenance (plan_id,
-    hbm_passes, agg_domain, comm_bytes_up, ...) merged in via
-    :meth:`Tracer.annotate` / :meth:`Tracer.stamp_latest`."""
+    hbm_passes, agg_domain, comm_bytes_up, ...) given at
+    :meth:`Tracer.start` or merged in via :meth:`Tracer.stamp_latest`."""
 
     name: str
     start_s: float
@@ -201,18 +201,7 @@ class Tracer:
         finally:
             self.finish(sp)
 
-    def time(self, name: str, step: Optional[int] = None, **attrs):
-        """Back-compat alias for :meth:`span` — the PR-1 ``Timers.time``
-        phase API; every existing call site becomes a span for free."""
-        return self.span(name, step=step, **attrs)
-
     # -- provenance ----------------------------------------------------------
-
-    def annotate(self, **attrs) -> None:
-        """Merge attrs into the innermost OPEN span (no-op un-armed or
-        outside any span)."""
-        if self._stack:
-            self._stack[-1].attrs.update(attrs)
 
     def stamp_latest(self, name: str, attrs: Dict[str, Any]) -> None:
         """Merge attrs into the most recently FINISHED span named
@@ -231,7 +220,7 @@ class Tracer:
         if spans:
             max(spans, key=lambda s: s.end_s or 0.0).attrs.update(attrs)
 
-    # -- aggregation (the old Timers surface) --------------------------------
+    # -- aggregation (every row's ``timers``) --------------------------------
 
     def mean(self, name: str) -> float:
         c = self._counts.get(name, 0)
@@ -298,11 +287,6 @@ class Tracer:
         from blades_tpu.faults.host import atomic_write_json
 
         return atomic_write_json(self.to_chrome_trace(), path)
-
-
-class Timers(Tracer):
-    """The PR-1 name of the phase-timer object: a plain un-armed
-    tracer."""
 
 
 def span(name: str, step: Optional[int] = None):

@@ -21,7 +21,11 @@ and nothing reads it.
 - :func:`gather_rows`: ``x[idx]`` whose cotangent is a gather too (the
   caller hands it the inverse map), so that neither the dispatch nor the
   combine scatters.
-- :func:`routed_ffn`: dispatch -> SwiGLU experts -> weighted combine.
+- :func:`routed_ffn`: dispatch -> SwiGLU experts -> weighted combine,
+  under the device scopes ``blades/dispatch`` (the sort and the gather into
+  the buffer), ``blades/experts`` (the grouped products and the SwiGLU) and
+  ``blades/combine`` (the gather back and the weighted sum), each with its
+  cotangents.
 """
 
 from __future__ import annotations
@@ -147,18 +151,22 @@ def routed_ffn(x, expert, held, weight, gate, up, down, *, impl=None):
     tokens, top_k = expert.shape
     pairs = tokens * top_k
     tile = math.gcd(pairs, ROW_TILE)
-    order, pos, sizes = sort_pairs(expert.reshape(pairs),
-                                   held.reshape(pairs), gate.shape[0])
-    mm = partial(grouped_matmul, group_sizes=sizes, tile=tile, impl=impl)
-    xs = gather_rows(x, order // top_k, pos.reshape(tokens, top_k), held)
-    hs = jax.nn.silu(mm(xs, gate)) * mm(xs, up)
-    ys = mm(hs, down)
-    # Pair p reads its row; sorted row r is read by pair order[r] alone.
-    got = gather_rows(ys, pos, order[:, None],
-                      jnp.ones((pairs, 1), bool)).reshape(tokens, top_k, -1)
-    # A pair whose expert is absent reads a row nothing wrote: take its
-    # value out before anything multiplies it.
-    got = jnp.where(held[..., None], got, 0)
-    y = (weight[..., None].astype(got.dtype) * got).sum(
-        1, dtype=jnp.float32).astype(x.dtype)
+    with jax.named_scope("blades/dispatch"):
+        order, pos, sizes = sort_pairs(expert.reshape(pairs),
+                                       held.reshape(pairs), gate.shape[0])
+        xs = gather_rows(x, order // top_k, pos.reshape(tokens, top_k), held)
+    with jax.named_scope("blades/experts"):
+        mm = partial(grouped_matmul, group_sizes=sizes, tile=tile, impl=impl)
+        hs = jax.nn.silu(mm(xs, gate)) * mm(xs, up)
+        ys = mm(hs, down)
+    with jax.named_scope("blades/combine"):
+        # Pair p reads its row; sorted row r is read by pair order[r] alone.
+        got = gather_rows(ys, pos, order[:, None],
+                          jnp.ones((pairs, 1), bool)).reshape(
+                              tokens, top_k, -1)
+        # A pair whose expert is absent reads a row nothing wrote: take its
+        # value out before anything multiplies it.
+        got = jnp.where(held[..., None], got, 0)
+        y = (weight[..., None].astype(got.dtype) * got).sum(
+            1, dtype=jnp.float32).astype(x.dtype)
     return y, sizes, rows_computed(sizes, tile)

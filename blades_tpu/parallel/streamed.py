@@ -96,6 +96,7 @@ from blades_tpu.adversaries.update_attacks import (
 from blades_tpu.core.round import FedRound, RoundState
 from blades_tpu.core.task import identity_round_end_hook
 from blades_tpu.data.sampler import sample_client_batches_with_keys
+from blades_tpu.obs import device_phases
 from blades_tpu.obs.trace import span
 # Imported here and not inside ``_train_block``: ``pallas_store`` binds the
 # kernels' gate by name when it is first imported, and a first import
@@ -247,6 +248,14 @@ def _fused_spec(fr: FedRound):
 
 def _adv_forges(adv) -> bool:
     return adv is not None and type(adv).on_updates_ready is not Adversary.on_updates_ready
+
+
+def _dispatch(fn, *args, **kwargs):
+    """Run one of the round's programs, noted for the device trace's
+    phases (obs/device_phases.py: its first dispatch keeps the executable,
+    a later one costs a set lookup)."""
+    device_phases.note(fn, args, kwargs)
+    return fn(*args, **kwargs)
 
 
 def streamed_step(
@@ -955,10 +964,11 @@ def streamed_step(
                 # The index goes in as a NumPy scalar: an argument of the
                 # block's own launch, not an eager convert program.
                 updates_buf, client_opt, loss, blk_norms, blk_stats = \
-                    _train_block(
-                        updates_buf, client_opt, state.server.params, data_x,
-                        data_y, lengths, malicious, sample_keys, train_keys,
-                        np.uint32(b), plan=plan,
+                    _dispatch(
+                        _train_block, updates_buf, client_opt,
+                        state.server.params, data_x, data_y, lengths,
+                        malicious, sample_keys, train_keys, np.uint32(b),
+                        plan=plan,
                     )
             losses.append(loss)
             norms.append(blk_norms)
@@ -981,9 +991,9 @@ def streamed_step(
                     bad = jnp.zeros((n,), bool)
                     cat_norms = per_lane(norms)
                     for i in range(k_chunks):
-                        updates_buf, sq, bad = _rowgeom_mat_chunk(
-                            updates_buf, sq, bad, malicious, cat_norms,
-                            k_adv, k_dp, jnp.int32(i),
+                        updates_buf, sq, bad = _dispatch(
+                            _rowgeom_mat_chunk, updates_buf, sq, bad,
+                            malicious, cat_norms, k_adv, k_dp, jnp.int32(i),
                             jnp.int32(min(i * c, d_model - c)),
                         )
                 else:
@@ -995,35 +1005,36 @@ def streamed_step(
                 if row_forges:
                     # Stats passes -> forged (d,) row, then scatter it into
                     # the malicious lanes chunk by chunk (donated buffer).
-                    forged, sq = _forge_row(updates_buf, malicious, sq, k_adv)
+                    forged, sq = _dispatch(_forge_row, updates_buf, malicious,
+                                           sq, k_adv)
                     for i in range(k_chunks):
-                        updates_buf = _scatter_chunk(
-                            updates_buf, forged, malicious,
+                        updates_buf = _dispatch(
+                            _scatter_chunk, updates_buf, forged, malicious,
                             jnp.int32(min(i * c, d_model - c)),
                         )
                 if row_geom:
-                    server, metrics = _rowgeom_aggregate(
-                        state.server, updates_buf, malicious,
-                        per_lane(losses), sq, bad, k_agg,
+                    server, metrics = _dispatch(
+                        _rowgeom_aggregate, state.server, updates_buf,
+                        malicious, per_lane(losses), sq, bad, k_agg,
                     )
                 else:
-                    server, metrics = _coordwise_after_forge(
-                        state.server, updates_buf, malicious,
-                        per_lane(losses), sq, bad,
+                    server, metrics = _dispatch(
+                        _coordwise_after_forge, state.server, updates_buf,
+                        malicious, per_lane(losses), sq, bad,
                     )
             elif compact:
-                server, metrics = _finish_fused_compact(
-                    state.server, updates_buf, malicious,
-                    per_lane(losses), k_adv, nb_real=nb,
+                server, metrics = _dispatch(
+                    _finish_fused_compact, state.server, updates_buf,
+                    malicious, per_lane(losses), k_adv, nb_real=nb,
                 )
             elif use_fused:
-                server, metrics = _finish_fused(
-                    state.server, updates_buf, malicious,
+                server, metrics = _dispatch(
+                    _finish_fused, state.server, updates_buf, malicious,
                     per_lane(losses), k_adv,
                 )
             else:
-                server, metrics = _finish(
-                    state.server, updates_buf, malicious,
+                server, metrics = _dispatch(
+                    _finish, state.server, updates_buf, malicious,
                     per_lane(losses), per_lane(norms),
                     k_adv, k_dp,
                 )
@@ -1060,8 +1071,9 @@ def streamed_step(
                     * fr.batch_size * data_x.shape[-1])
             if jax.tree.leaves(stats):
                 metrics.update(
-                    ("counter_" + name, v) for name, v in _round_counters(
-                        stats, surplus=plan.surplus).items())
+                    ("counter_" + name, v) for name, v in _dispatch(
+                        _round_counters, stats,
+                        surplus=plan.surplus).items())
             # Store telemetry (schema-registered, host-side like
             # elided_lanes): the blocks whose rows were written into the
             # matrix, how many of those writes were whole storage tiles at

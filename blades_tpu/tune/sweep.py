@@ -447,7 +447,7 @@ def _run_lane_group(
     from blades_tpu.algorithms import get_algorithm_class
     from blades_tpu.obs import CsvSink, JsonlSink, MetricsLogger
     from blades_tpu.obs.flightrec import FlightRecorder
-    from blades_tpu.obs.trace import Timers
+    from blades_tpu.obs.trace import Tracer
     from blades_tpu.obs.watchdog import Watchdog
     from blades_tpu.tune.lanes import run_lanes
 
@@ -482,7 +482,7 @@ def _run_lane_group(
     cache_before = cache_stats()
     # Span tracing (obs/trace.py): the group's round dispatches become
     # spans of ONE tree, exported per group when --trace-dir is set.
-    tracer = Timers(record=bool(trace_dir))
+    tracer = Tracer(record=bool(trace_dir))
     gspan = tracer.start("lane_group", experiment=exp_name,
                          trials=len(group), rounds=max_rounds)
     # program_key: the group's SHARED static config (the lane signature
@@ -790,7 +790,7 @@ def run_experiments(
                                         atomic_checkpoint, retry_backoff)
     from blades_tpu.obs import CsvSink, JsonlSink, MetricsLogger, StdoutSink
     from blades_tpu.obs.flightrec import FlightRecorder
-    from blades_tpu.obs.trace import Timers
+    from blades_tpu.obs.trace import Tracer
     from blades_tpu.obs.watchdog import Watchdog
     from blades_tpu.perf import (cache_stats,
                                  enable_persistent_compilation_cache)
@@ -916,7 +916,7 @@ def run_experiments(
                        if resumed_from else "")
                 print(f"== trial {tname}: {max_rounds} rounds{tag} ==",
                       flush=True)
-            timers = Timers(record=bool(trace_dir))
+            timers = Tracer(record=bool(trace_dir))
             if trace_dir:
                 # One span tree per trial: the algorithm's phase timers
                 # (training_step / evaluate) nest inside this tracer's
@@ -1030,7 +1030,7 @@ def run_experiments(
                             # pollutes the other.  `step` puts the armed
                             # span under a StepTraceAnnotation, so device
                             # work correlates in a profiler capture.
-                            with timers.time("round" if compiled
+                            with timers.span("round" if compiled
                                              else "compile",
                                              step=algo.iteration):
                                 result = algo.train()
@@ -1056,7 +1056,7 @@ def run_experiments(
                                 f.flush()
                                 os.fsync(f.fileno())
                                 name = f"ckpt_{algo.iteration:06d}"
-                                with timers.time("checkpoint"):
+                                with timers.span("checkpoint"):
                                     atomic_checkpoint(algo.save_checkpoint,
                                                       tdir / name)
                                 ckpt_scores[name] = float(
@@ -1156,7 +1156,7 @@ def run_experiments(
                     if logger is not None:
                         logger.close()
             if checkpoint_at_end and failed_error is None:
-                with timers.time("checkpoint"):
+                with timers.span("checkpoint"):
                     atomic_checkpoint(algo.save_checkpoint, tdir / "ckpt_final")
             timers.finish(trial_span)
             wall = trial_span.duration
@@ -1171,7 +1171,7 @@ def run_experiments(
             # Sweep-level phase timings (satellite: compile / round / eval /
             # checkpoint): eval runs INSIDE algo.train(), so its phase
             # comes from the algorithm's own timers (getattr: custom
-            # trainables registered into ALGORITHMS may not carry Timers)
+            # trainables registered into ALGORITHMS may not carry a tracer)
             # and its time is also contained in the compile/round phases —
             # subtract 'eval' from 'round' for pure-training estimates.
             phase_timers = timers.summary()

@@ -3,12 +3,12 @@ the span layer, plus the legal non-measurement uses of ``time``."""
 
 import time
 
-from blades_tpu.obs.trace import Timers, now
+from blades_tpu.obs.trace import Tracer, now
 
 
 def span_timed():
-    timers = Timers()
-    with timers.time("phase"):
+    timers = Tracer()
+    with timers.span("phase"):
         busy = sum(range(10))
     return timers.summary(), busy
 

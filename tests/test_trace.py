@@ -3,7 +3,7 @@ anomaly watchdog.
 
 Four layers of coverage:
 
-1. **Unit** — span tree nesting + the old ``Timers`` aggregation
+1. **Unit** — span tree nesting + the rows' ``timers`` aggregation
    contract, Chrome-trace export/validation, watchdog rules (schema
    gate, NaN, spike, ceiling, round-time) + warm(), flight-recorder
    ring/check/dump semantics, the offline validator CLI's three modes.
@@ -37,7 +37,6 @@ from blades_tpu.obs.flightrec import (  # noqa: E402
 )
 import blades_tpu.obs.trace as trace_mod  # noqa: E402
 from blades_tpu.obs.trace import (  # noqa: E402
-    Timers,
     Tracer,
     validate_chrome_trace,
 )
@@ -56,15 +55,15 @@ from blades_tpu.tune.sweep import verify_result_rounds  # noqa: E402
 
 
 def test_tracer_summary_keeps_timers_contract():
-    """An un-armed tracer IS the PR-1 Timers object: same time() context
-    manager, same summary shape, same mean()."""
-    t = Timers()
+    """An un-armed tracer is the rows' phase timer: its span() context
+    manager aggregates into summary() and mean()."""
+    t = Tracer()
     fake = iter(range(100))
     t._clock = lambda: next(fake)
-    with t.time("round"):
-        with t.time("training_step"):
+    with t.span("round"):
+        with t.span("training_step"):
             pass
-    with t.time("round"):
+    with t.span("round"):
         pass
     s = t.summary()
     assert set(s) == {"round", "training_step"}
@@ -78,10 +77,9 @@ def test_tracer_summary_keeps_timers_contract():
 def test_tracer_records_nested_tree_and_attrs():
     tr = Tracer(record=True)
     root = tr.start("trial", trial="t0")
-    with tr.span("round", step=1) as sp:
+    with tr.span("round", step=1, extra=7) as sp:   # attrs at the open
         with tr.span("training_step"):
             pass
-        tr.annotate(extra=7)  # lands on the OPEN round span
     tr.stamp_latest("round", {"plan_id": "p"})
     tr.stamp_latest_of(("round", "compile"), {"hbm_passes": 2})
     tr.finish(root)
@@ -118,8 +116,9 @@ def test_chrome_validator_tolerates_torn_file(tmp_path):
 
 
 def test_shims_and_unused_wrappers_are_gone():
-    """The PR-1 shim modules and the two wrappers nothing called went
-    with them; the span layer keeps the names that have callers."""
+    """The PR-1 shim modules, the wrappers nothing called and the aliases
+    of ``Tracer`` and its ``span`` went; the span layer keeps the names
+    that have callers."""
     import importlib
 
     import blades_tpu.obs.trace as T
@@ -130,7 +129,9 @@ def test_shims_and_unused_wrappers_are_gone():
             importlib.import_module(name)
     assert not hasattr(U, "Timers")
     assert not hasattr(T, "annotate") and not hasattr(T, "xla_dump_flags")
-    assert callable(T.trace) and callable(T.span) and T.Timers is Timers
+    assert not hasattr(T, "Timers")
+    assert not hasattr(Tracer, "time") and not hasattr(Tracer, "annotate")
+    assert callable(T.trace) and callable(T.span) and T.Tracer is Tracer
 
 
 def test_unarmed_tracer_retains_nothing_and_still_aggregates():
