@@ -53,9 +53,10 @@ and rotaries), ``blades/router``, ``blades/experts`` (and inside it
 positions its attention computes, from shapes: the XLA blocks' reach, or
 the fused kernel's own block list where that ran), ``stats/attn_fused``
 (1 where the fused kernel ran), ``stats/expert_tokens``,
-``stats/routed_pairs`` and ``stats/expert_rows`` (pair rows the grouped
-product worked on); :meth:`GqaMoeLM.round_counters` reduces a round's to
-its row counters.
+``stats/routed_pairs``, ``stats/expert_rows`` (pair rows the grouped
+product worked on) and ``stats/dispatch_rows`` (buffer rows the dispatch
+wrote); :meth:`GqaMoeLM.round_counters` reduces a round's to its row
+counters.
 """
 
 from __future__ import annotations
@@ -235,13 +236,14 @@ class RoutedExperts(nn.Module):
             down = kernel_param(self, "experts_down", (held, f, h))
             dt = x.dtype
             k = c.num_experts_per_tok
-            y, sizes, rows = grouped.routed_ffn(
+            y, sizes, rows, moved = grouped.routed_ffn(
                 x.reshape(b * s, h), local.reshape(b * s, k),
                 here.reshape(b * s, k), w.reshape(b * s, k),
                 gate.astype(dt), up.astype(dt), down.astype(dt))
         self.sow("stats", "expert_tokens", sizes)
         self.sow("stats", "routed_pairs", jnp.int32(idx.size))
         self.sow("stats", "expert_rows", rows)
+        self.sow("stats", "dispatch_rows", moved)
         return y.reshape(b, s, h)
 
 
@@ -272,7 +274,8 @@ class GqaMoeLM(nn.Module):
         """A round's row counters (``obs/schema.py``) from what the layers
         sowed, stacked ``(lanes, layers, ...)`` over the trained lanes: the
         expert share's four (``models/mla_moe.py``), the pair rows the
-        grouped product worked on and the attention scores computed."""
+        grouped product worked on, the buffer rows the dispatch wrote and
+        the attention scores computed."""
         tokens = stats["expert_tokens"]
         return {
             "expert_tokens_max": tokens.max(),
@@ -282,6 +285,7 @@ class GqaMoeLM(nn.Module):
             "zero_expert_blocks": (tokens == 0).sum(),
             "expert_pairs_here": tokens.sum(),
             "expert_rows_computed": stats["expert_rows"].sum(),
+            "dispatch_rows_moved": stats["dispatch_rows"].sum(),
             "attn_scores_computed": stats["attn_scores"].sum(),
             "attn_fused_calls": stats["attn_fused"].sum(),
         }

@@ -198,13 +198,17 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     # A model whose expert share is computed as routing and whose
     # attention skips key blocks (models/gqa_moe.py): the selected pairs
     # whose expert is held (expert_tokens summed), the pair rows the
-    # grouped product worked on (padding to its row tiles included), and
+    # grouped product worked on (padding to its row tiles included), the
+    # buffer rows the dispatch wrote (the row tiles in use where the
+    # Pallas movers ran, the whole worst-case buffer where XLA's gathers
+    # did: ops/grouped.py::routed_ffn), and
     # the (query, key) positions whose score the attention computed, all
     # over layers and trained lanes.  attn_fused_calls (both language
     # models): the (lane, layer)s whose attention ran the fused kernel
     # (ops/attention.py); 0 wherever the XLA query blocks ran.
     "expert_pairs_here": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "expert_rows_computed": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "dispatch_rows_moved": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "attn_scores_computed": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "attn_fused_calls": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     # Row-geometry pass fusion (parallel/streamed_geometry.py): planned

@@ -120,11 +120,11 @@ def test_routed_ffn_equals_the_dense_form_under_the_routing_weights(impl):
     for a, b in zip(vjp_got(ct), vjp_want(ct)):
         assert bool(jnp.isfinite(a).all())
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
-    _, sizes, rows = grouped.routed_ffn(*args[:1], expert, here, *args[1:],
-                                        impl=impl)
+    _, sizes, rows, moved = grouped.routed_ffn(
+        *args[:1], expert, here, *args[1:], impl=impl)
     assert sizes.tolist() == [int(((expert == e) & here).sum())
                               for e in range(held)]
-    assert int(rows) >= int(sizes.sum())
+    assert int(rows) >= int(moved) >= int(sizes.sum())
 
 
 def test_shares_add_up_to_the_uncut_layer():
@@ -320,6 +320,7 @@ def test_the_counters_are_valid_rows_and_count_what_they_say():
         [[[6, 0, 2, 0]], [[0, 0, 8, 0]]], jnp.int32),
         "routed_pairs": jnp.full((2, 1), 64, jnp.int32),
         "expert_rows": jnp.asarray([[16], [8]], jnp.int32),
+        "dispatch_rows": jnp.asarray([[16], [16]], jnp.int32),
         "attn_scores": jnp.asarray([[1000], [1000]], jnp.float32),
         "attn_fused": jnp.asarray([[1], [0]], jnp.int32)}
     got = jax.device_get(jax.jit(task.round_counters)(stats))
@@ -329,6 +330,7 @@ def test_the_counters_are_valid_rows_and_count_what_they_say():
     assert int(got["zero_expert_blocks"]) == 5
     assert int(got["expert_pairs_here"]) == 16
     assert int(got["expert_rows_computed"]) == 24
+    assert int(got["dispatch_rows_moved"]) == 32
     assert float(got["attn_scores_computed"]) == 2000.0
     row = {"experiment": "e", "trial": "t", "training_iteration": 1,
            "train_loss": 1.0}
@@ -336,6 +338,8 @@ def test_the_counters_are_valid_rows_and_count_what_they_say():
     validate_record(row)
     with pytest.raises(Exception):
         validate_record(dict(row, expert_rows_computed=0.5))
+    with pytest.raises(Exception):
+        validate_record(dict(row, dispatch_rows_moved=0.5))
     # what the attention layers sow is their blocks' count, from shapes
     _, sown = jax.jit(lambda p, x: task.sequence_planes(p, x, stats=True))(
         task.init_params(jax.random.PRNGKey(0)),

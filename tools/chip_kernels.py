@@ -45,6 +45,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import json
+import math
 import os
 import sys
 import time
@@ -272,6 +273,74 @@ def _plane_store_case(rows: int, lanes: int, dtype,
     return Case(name, run, inputs, reference, 0.0, mosaic=False)  # a copy
 
 
+def _movers_case(held_a_token: int, tokens: int = 512, h: int = 2304
+                 ) -> Case:
+    """The routed layer's row movers (``ops/grouped.py``) at the code
+    model's width, bf16, top-8 over 8 held experts, every token selecting
+    ``held_a_token`` of them (1: one row a token, as the cell's routers
+    send; 8: the whole buffer, the worst case): the dispatch's rows, the
+    combine's weighted sums and the combine's cotangent rows, each
+    against float64 over the rows in use."""
+    from blades_tpu.ops import grouped
+
+    name = f"row_movers_t{tokens}_held{held_a_token}"
+    top_k = held = 8
+    pairs = tokens * top_k
+    cfg = grouped._Tiles(grouped.ROW_TILE, grouped._CHUNK,
+                         grouped._TOKEN_TILE, False)
+
+    def inputs():
+        rng = _rng(name)
+        expert = np.full((tokens, top_k), -1, np.int32)
+        for k in range(held_a_token):
+            expert[:, k] = (np.arange(tokens) + k) % held
+        return (expert, _store(rng.normal(size=(tokens, h)), jnp.bfloat16),
+                _store(rng.normal(size=(pairs, h)), jnp.bfloat16),
+                rng.uniform(size=(tokens, top_k)).astype(np.float32))
+
+    def run(expert, x, ys, w):
+        here = expert >= 0
+        order, pos, sizes = grouped.sort_pairs(expert.reshape(pairs),
+                                               here.reshape(pairs), held)
+        mine = here[..., None] & (expert[..., None] == jnp.arange(held))
+        route = grouped._route(mine, order, pos, sizes, cfg)
+        wg = jnp.where(mine, w.astype(jnp.bfloat16)[..., None].astype(
+            jnp.float32), 0.0).sum(1).T
+        used = (jnp.arange(pairs) < sizes.sum())[:, None]
+        w_row = jnp.where(route.tok >= 0, w.reshape(-1)[order], 0.0)
+        back, dots = grouped._spread(x, route, cfg, w_row.astype(
+            jnp.bfloat16).astype(jnp.float32), ys)
+        return {"dispatch": jnp.where(used, grouped._spread(x, route, cfg),
+                                      0),
+                "combine": grouped._collect([ys], route, cfg, wg),
+                "combine_bwd": jnp.where(used, back, 0),
+                "dots": jnp.where(used[:, 0], dots.reshape(-1), 0)}
+
+    def reference(expert, x, ys, w):
+        here = expert >= 0
+        key = np.where(here, expert, held).reshape(-1)
+        order = np.argsort(key, kind="stable")
+        n = int(here.sum())
+        tok = order[:n] // top_k
+        wb = _round_bf16(w).reshape(-1)
+        disp = np.zeros((pairs, h))
+        disp[:n] = _f64(x)[tok]
+        back = np.zeros((pairs, h))
+        back[:n] = _round_bf16(wb[order[:n], None] * _f64(x)[tok])
+        dots = np.zeros(pairs)
+        dots[:n] = (_f64(x)[tok] * _f64(ys)[:n]).sum(1)
+        pos = np.argsort(order).reshape(tokens, top_k)
+        prod = _round_bf16(wb.reshape(tokens, top_k)[..., None]
+                           * _f64(ys)[pos])
+        comb = np.where(here[..., None], prod, 0.0).sum(1)
+        return {"dispatch": disp, "combine": comb, "combine_bwd": back,
+                "dots": dots}
+
+    # Every row and product is exact; the one rounding of a sum of several
+    # rows to bf16, and the dots' float32 sums, are not.
+    return Case(name, run, inputs, reference, 2.0 ** -7)
+
+
 def _full_case(n: int, f: int, dtype, agg: tuple) -> Case:
     """``fused_finish`` over the full matrix with a malicious prefix."""
     bf16 = dtype == jnp.bfloat16
@@ -450,6 +519,10 @@ def _cases() -> Tuple[Case, ...]:
         _plane_store_case(8, 1, bf16, leaves=True),
         _plane_store_case(10, 3, f32),
         _plane_store_case(24, 5, bf16),
+        # The routed layer's row movers at the code model's width: a row
+        # a token, and every token selecting every held expert.
+        _movers_case(1),
+        _movers_case(8),
     )
 
 
@@ -673,6 +746,101 @@ GROUPED_LOADS = {
 }
 
 
+def row_movers(expert, here, x, weight, tile=256):
+    """The four row moves of one routed layer (``ops/grouped.py``) under
+    one routing: XLA's gathers over the whole buffer (the ``jnp`` form,
+    what the chip ran before the movers) beside the Pallas movers, each
+    one's time (the median of 5 calls) and the largest difference between
+    the two on what is read of it (the buffer's rows in use, every token,
+    every weight), relative to the largest value.  ``dispatch``: the rows
+    into the buffer; ``combine``: the weighted sum back; ``*_bwd``: their
+    cotangents (the dispatch's from the two input projections' cotangents,
+    which the jnp form adds over the whole buffer first)."""
+    from blades_tpu.ops import grouped
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    tokens, top_k = expert.shape
+    pairs, h, held = tokens * top_k, x.shape[1], 8
+    order, pos, sizes = grouped.sort_pairs(expert.reshape(pairs),
+                                           here.reshape(pairs), held)
+    # In Pallas's interpreter where there is no TPU (the tests' small size).
+    cfg = grouped._Tiles(tile, math.gcd(tokens, grouped._CHUNK),
+                         math.gcd(tokens, grouped._TOKEN_TILE),
+                         jax.default_backend() != "tpu")
+    mine = here[..., None] & (expert[..., None] == jnp.arange(held))
+    route = grouped._route(mine, order, pos, sizes, cfg)
+    pos2 = pos.reshape(tokens, top_k)
+    wg = jnp.where(mine, weight.astype(bf16)[..., None].astype(f32),
+                   0.0).sum(1).T
+    key = jax.random.split(jax.random.PRNGKey(39), 4)
+    ys, a, b = (jax.random.normal(k, (pairs, h), bf16) for k in key[:3])
+    g = jax.random.normal(key[3], (tokens, h), bf16)
+
+    def combine_xla(ys, w, pos2, here):
+        got = jnp.where(here[..., None], ys[pos2], 0)
+        return (w.astype(bf16)[..., None] * got).sum(1, dtype=f32).astype(
+            bf16)
+
+    def g_w(g_wg, expert, here):     # (G, T) -> (T, K), the jnp form's shape
+        return jnp.where(here, g_wg[jnp.clip(expert, 0, held - 1),
+                                    jnp.arange(tokens)[:, None]], 0.0)
+
+    # Every array is an argument: one captured by a closure would be a
+    # constant of the compiled program (GBs here), slow to compile.
+    v = dict(x=x, a=a, b=b, ys=ys, g=g, weight=weight, wg=wg, order=order,
+             pos2=pos2, here=here, expert=expert)
+    forms = {
+        "dispatch": (
+            lambda r, v: grouped.gather_rows(
+                v["x"], v["order"] // top_k, v["pos2"], v["here"]),
+            lambda r, v: grouped._spread(v["x"], r, cfg)),
+        "dispatch_bwd": (
+            lambda r, v: grouped._gather_bwd((v["pos2"], v["here"]),
+                                             v["a"] + v["b"])[0],
+            lambda r, v: grouped._collect([v["a"], v["b"]], r, cfg)),
+        "combine": (
+            lambda r, v: combine_xla(v["ys"], v["weight"], v["pos2"],
+                                     v["here"]),
+            lambda r, v: grouped._collect([v["ys"]], r, cfg, v["wg"])),
+        "combine_bwd": (
+            lambda r, v: jax.vjp(
+                lambda ys, w: combine_xla(ys, w, v["pos2"], v["here"]),
+                v["ys"], v["weight"])[1](v["g"]),
+            lambda r, v: (lambda o: (o[0], g_w(o[1], v["expert"],
+                                               v["here"])))(
+                grouped._combine_bwd(cfg, (v["ys"], v["wg"], r), v["g"]))),
+    }
+    n = int(sizes.sum())
+    used = np.arange(pairs) < n
+    out = {"pairs": n, "rows_moved": int(
+        grouped.rows_computed(sizes.sum(keepdims=True), tile))}
+    for name, (xla, pallas) in forms.items():
+        xla, pallas = jax.jit(xla), jax.jit(pallas)
+        rec = {}
+        try:
+            want, got = (jax.tree.leaves(jax.device_get(f(route, v)))
+                         for f in (xla, pallas))
+            errs = []
+            for w, o in zip(want, got):
+                w, o = np.asarray(w, np.float64), np.asarray(o, np.float64)
+                if w.shape[0] == pairs:
+                    w, o = w[used], o[used]
+                errs.append(float(np.abs(w - o).max()
+                                  / max(np.abs(w).max(), 1e-30))
+                            if w.size else 0.0)
+            rec["rel_err"] = [float(f"{e:.3g}") for e in errs]
+            rec["xla_ms"] = round(_median_ms(xla, route, v), 3)
+            rec["pallas_ms"] = round(_median_ms(pallas, route, v), 3)
+            rec["speedup"] = round(rec["xla_ms"] / rec["pallas_ms"], 2)
+            # The jnp form rounds the weight's cotangent to bf16 (its
+            # products and sum are bf16): up to 2**-7 of the largest.
+            rec["ok"] = bool(max(errs) < 2e-2)
+        except Exception as e:   # a refused kernel is a finding
+            rec = {"ok": False, "error": f"{type(e).__name__}: {e}"[-600:]}
+        out[name] = rec
+    return out
+
+
 def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
                     impl="kernel") -> int:
     """The grouped product of ``ops/grouped.py`` at the code-model cell's
@@ -685,14 +853,20 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
     - its time (forward + backward, the median of 5 calls) at each row tile
       of ``argv`` (default 128 256 512) and its share of the MXU roofline
       for the pairs in use; ``lax.ragged_dot``'s beside it;
-    - the whole routed layer (``routed_ffn``: sort, gather, three grouped
+    - the row movers beside XLA's gathers (:func:`row_movers`);
+    - the whole routed layer (``routed_ffn``: sort, move, three grouped
       products, combine; forward + backward) under a drawn routing with
-      that load, beside the dense masked form (every held expert on every
-      token under a 0/1 weight: how ``models/mla_moe.py`` computes its
-      share)."""
+      that load, with the movers (``routed_ms``) and with XLA's gathers
+      (``routed_xla_gathers_ms``), beside the dense masked form (every
+      held expert on every token under a 0/1 weight: how
+      ``models/mla_moe.py`` computes its share).
+
+    ``argv`` ``movers`` runs the last two alone."""
     from blades_tpu.ops import grouped
 
-    tiles = tuple(int(a) for a in argv) or (128, 256, 512)
+    movers_only = argv[:1] == ["movers"]
+    tiles = () if movers_only else (tuple(int(a) for a in argv)
+                                    or (128, 256, 512))
     rows, held, bf16 = tokens * top_k, 8, jnp.bfloat16
     key = jax.random.split(jax.random.PRNGKey(33), 8)
     lhs = jax.random.normal(key[0], (rows, h), bf16)
@@ -776,8 +950,8 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
         pairs = int(sum(sizes))
         used = np.arange(rows) < pairs
         rec = {"load": name, "pairs": pairs, "sizes": list(sizes)}
-        want = [np.asarray(a, np.float64) for a in
-                dense_form(lhs, rhs, gs, ct)]
+        want = [] if movers_only else [np.asarray(a, np.float64) for a in
+                                       dense_form(lhs, rhs, gs, ct)]
         floor_ms = 1e3 * 3 * 2 * pairs * h * f / 197e12
         for tile in tiles:
             run = vjp_of(lambda a, b, g, t=tile: grouped.grouped_matmul(
@@ -801,7 +975,7 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
             except Exception as e:   # a refused tiling is a finding
                 rec[f"tile{tile}"] = {"ok": False, "error":
                                       f"{type(e).__name__}: {e}"[-600:]}
-        if impl == "kernel":
+        if impl == "kernel" and not movers_only:
             rag = vjp_of(lambda a, b, g: jax.lax.ragged_dot(
                 a, b.astype(bf16), g))
             try:
@@ -811,8 +985,22 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
             rec["both_projections_ms"] = both_projections(gs)
         expert, here = routing(sizes, 7)
         weight = jax.random.uniform(key[7], (tokens, top_k), jnp.float32)
+        if impl == "kernel":
+            rec["movers"] = row_movers(expert, here, x, weight)
         routed = layer(lambda x, w, g, u, d: grouped.routed_ffn(
             x, expert, here, w, g, u, d, impl=impl)[0])
+        gathered = layer(lambda x, w, g, u, d: grouped.routed_ffn(
+            x, expert, here, w, g, u, d,
+            impl="jnp" if impl == "jnp" else None)[0])
+        # Traced (at its first call, here) with the movers' gate shut: the
+        # grouped product's kernels and XLA's gathers, the layer as it ran
+        # before the movers.
+        gate_open = grouped.movers_applicable
+        grouped.movers_applicable = lambda *a: False
+        try:
+            jax.block_until_ready(gathered(x, weight, gate, up, down))
+        finally:
+            grouped.movers_applicable = gate_open
 
         def masked(x, w, g, u, d):
             w_held = (w[..., None] * (expert[..., None] == jnp.arange(held))
@@ -823,11 +1011,14 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
                 x.dtype)[..., None], d)
 
         args = (x, weight, gate, up, down)
-        y_r, y_m = (np.asarray(fn(*args)[0], np.float64)
-                    for fn in (routed, layer(masked)))
+        y_r, y_g, y_m = (np.asarray(fn(*args)[0], np.float64)
+                         for fn in (routed, gathered, layer(masked)))
+        rel = lambda a, b: float(
+            f"{np.abs(a - b).max() / max(np.abs(b).max(), 1e-30):.3g}")
         rec["layer"] = {
-            "rel_err": float(f"{np.abs(y_r - y_m).max() / max(np.abs(y_m).max(), 1e-30):.3g}"),
+            "rel_err": rel(y_r, y_m), "rel_err_xla_gathers": rel(y_g, y_m),
             "routed_ms": round(timed(routed, *args), 3),
+            "routed_xla_gathers_ms": round(timed(gathered, *args), 3),
             "dense_masked_ms": round(timed(layer(masked), *args), 3),
             "floor_ms": round(3 * floor_ms, 3)}
         records.append(rec)
@@ -837,6 +1028,9 @@ def grouped_product(argv, tokens=8192, top_k=8, h=2304, f=896,
         json.dump(records, fh, indent=1)
     bad = [r["load"] for r in records
            if not all(v.get("ok", True) for v in r.values()
+                      if isinstance(v, dict))
+           or not all(v.get("ok", True)
+                      for v in r.get("movers", {}).values()
                       if isinstance(v, dict))]
     print(json.dumps({"loads": len(records), "failed": bad}), flush=True)
     return 1 if bad else 0
