@@ -18,9 +18,11 @@ the task a SEQUENCE task: ``x`` is ``(batch, S)`` int32 tokens of packed
 documents and ``y`` ``(batch, S)`` next-token targets, ``-1`` where the
 next token lies in another document (or past the row): the loss is the
 mean next-token cross-entropy over the positions that have a target, one
-term per logits plane the model returns (MTP), and ``evaluate`` counts
-tokens, so that ``exp(test_loss)`` is the perplexity.  Everything else (the
-hooks, the scan, the update vector, ``vmap`` over clients) is shared.
+term per logits plane the model returns (MTP; a looped model's exits,
+weighted position by position: :meth:`Task.sequence_loss`), and
+``evaluate`` counts tokens, so that ``exp(test_loss)`` is the perplexity.
+Everything else (the hooks, the scan, the update vector, ``vmap`` over
+clients) is shared.
 
 Adversary interposition happens through two per-lane hooks threaded into
 the scan — ``data_hook(x, y, malicious)`` (label-flip style, ref:
@@ -33,6 +35,7 @@ one jit program (SURVEY.md §7.3).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Optional, Tuple
 
@@ -154,16 +157,17 @@ class Task:
             else p, tree)
 
     def sequence_planes(self, params, tokens, *, stats: bool = False):
-        """The sequence model's float32 logits planes ``(B, S, V)`` (and,
-        with ``stats``, what its layers sowed, one stacked leaf a name)."""
+        """The sequence model's float32 logits planes ``(B, S, V)``, plane 0
+        its answer (and, with ``stats``, what it sowed, one stacked leaf a
+        name: its layers' counts, and the weighting of its planes that
+        :meth:`sequence_loss` reads)."""
         out = self.model.apply({"params": params}, tokens,
-                               mutable=["stats"] if stats else False)
+                               mutable=["stats", "loss"] if stats else False)
         if not stats:
             return out
         planes, state = out
         sown = {}
-        for path, leaf in jax.tree_util.tree_leaves_with_path(
-                state.get("stats", {})):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(state):
             name = [k.key for k in path if hasattr(k, "key")][-1]
             sown.setdefault(name, []).append(leaf)
         return planes, {k: jnp.stack(v) for k, v in sorted(sown.items())}
@@ -184,18 +188,40 @@ class Task:
     def sequence_loss(self, params, x, y):
         """``(loss, stats)``: the mean next-token cross-entropy over the
         positions with a target, logits and loss in float32, one weighted
-        term per plane; and what the model's layers sowed."""
+        term per plane; and what the model's layers sowed.
+
+        Plane ``i`` predicts ``plane_depths[i]`` tokens further (default
+        ``i``: MTP) and weighs ``loss_weights[i]`` (default 1).  A model
+        that sows into its ``loss`` collection weighs its planes position
+        by position instead (a looped model's exit distribution,
+        models/looped_lm.py): ``plane_weights`` ``(planes, B, S)`` multiply
+        each plane's cross-entropy, and ``position_term`` ``(B, S)`` is
+        added at every position with a target, all computed under the
+        model's ``loss_scope``.  A model that sows neither gets the static
+        sum, bit for bit."""
         params = self.cast_to_compute(params)
         planes, sown = self.sequence_planes(params, x, stats=True)
         weights = getattr(self.model, "loss_weights", (1.0,) * len(planes))
-        loss = 0.0
-        for depth, (logits, w) in enumerate(zip(planes, weights)):
-            t = self.plane_targets(y, depth)
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits.astype(jnp.float32), jnp.maximum(t, 0))
-            valid = (t >= 0).astype(jnp.float32)
-            loss = loss + w * (ce * valid).sum() / jnp.maximum(
-                valid.sum(), 1.0)
+        depths = getattr(self.model, "plane_depths", range(len(planes)))
+        per_position = sown.pop("plane_weights", None)
+        term = sown.pop("position_term", None)
+        scope = getattr(self.model, "loss_scope", None)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            loss = 0.0
+            for i, (logits, w, depth) in enumerate(
+                    zip(planes, weights, depths)):
+                t = self.plane_targets(y, depth)
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    logits.astype(jnp.float32), jnp.maximum(t, 0))
+                valid = (t >= 0).astype(jnp.float32)
+                if per_position is not None:
+                    ce = ce * per_position[i]
+                loss = loss + w * (ce * valid).sum() / jnp.maximum(
+                    valid.sum(), 1.0)
+            if term is not None:
+                valid = (y >= 0).astype(jnp.float32)
+                loss = loss + (term.sum(0) * valid).sum() / jnp.maximum(
+                    valid.sum(), 1.0)
         return jnp.clip(loss, 0.0, self.spec.loss_clamp), sown
 
     def loss_and_stats(self, params, x, y, dropout_key=None):

@@ -211,6 +211,12 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     "dispatch_rows_moved": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "attn_scores_computed": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     "attn_fused_calls": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    # A looped model (models/looped_lm.py): the steps its stack ran a
+    # forward pass, and the mean exit weight its gate left on the last step
+    # over every position of the trained rows (its attn_fused_calls count
+    # layer passes).
+    "loop_steps": ((int,), False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
+    "exit_last_step_mass": (_NUM, False),  # blades-lint: disable=schema-drift — stamped dynamically from counter_<name> metrics (parallel/streamed.py, fedavg.py::_fill_round_metrics)
     # Row-geometry pass fusion (parallel/streamed_geometry.py): planned
     # full-matrix HBM traversals the streamed row-geometry finish runs
     # this round under the fused pass plan, vs what the

@@ -46,13 +46,14 @@ def pytest_configure(config):
 # configuration cut and requires it to list.  Strict: when a `benchmark`
 # PR narrows the rule (PERF.md Open questions) the case passes, this mark
 # fails the suite, and it goes.  Nothing goes unchecked meanwhile:
-# `tests/perfbench/test_pb_mla_moe_lm.py` and `test_pb_gqa_moe_lm.py` hold
-# the contract's width list against each reduced key of their
-# configuration (`test_a_reduced_key_names_no_width`) and `num_params`
-# against the family's count.
+# `tests/perfbench/test_pb_mla_moe_lm.py`, `test_pb_gqa_moe_lm.py` and
+# `test_pb_looped_lm.py` hold the contract's width list against each
+# reduced key of their configuration (`test_a_reduced_key_names_no_width`)
+# and `num_params` against the family's count.
 _WIDTH_RULE_FALSE_POSITIVE = tuple(
     f"test_config_files_lie_under_paths_and_cut_no_width[{config}]"
-    for config in ("joyai_llm_flash_ep32", "mellum2_12b_a2p5b_ep8"))
+    for config in ("joyai_llm_flash_ep32", "mellum2_12b_a2p5b_ep8",
+                   "ouro_2p6b_pp12"))
 
 
 def pytest_collection_modifyitems(config, items):

@@ -5,7 +5,9 @@ TPU compiler builds ``_train_block`` and ``_finish_fused_compact``
 ``v5e:2x2`` at a language-model YAML's shapes
 (``tuned_examples/fedavg_lm_crosssilo.yaml``: 10 clients, 2 elided,
 ``client_block`` 1, rows of 4096 tokens; ``fedavg_codelm_crosssilo``: rows
-of 8192, the grouped product over the routed pairs) and prints each
+of 8192, the grouped product over the routed pairs;
+``fedavg_looplm_crosssilo``: rows of 4096 through a stack looped four
+times, 16 layer passes and 4 exits a token) and prints each
 one's ``memory_analysis()``: arguments + outputs - aliased + temporaries is
 what the program needs of the chip's 15.75 GB.  The matrix is built by the
 round's own rule (``parallel/streamed.py::compact_matrix``: a row a plane
@@ -20,6 +22,8 @@ carries the layouts the compiler gave it and the block's stores into it
 ``global_model`` (e.g. ``num_nextn_predict_layers=1``: the MTP module at
 the chip's size, which PR 29 decided by these numbers), ``input_shape=``
 the row's length (PR 33 decided 8192 by them).  About two minutes a block.
+``vocab_size=`` sets the vocabulary held: the looped model's cell holds
+half of Ouro-2.6B's by these numbers.
 Nothing runs on a device: a compile that passes is not a chip run
 (tools/aot_train_block.py is the image cells' twin).
 """
