@@ -1442,8 +1442,10 @@ class Fedavg:
                          "surplus_lanes"):
                 row[name] = int(metrics[name])
         if "finish_stripe_cols" in metrics:
-            # A fused finish served the round: its stripe's width.
-            row["finish_stripe_cols"] = int(metrics["finish_stripe_cols"])
+            # A fused finish served the round: its stripe's width, and the
+            # rows its Median ordered by a sorting network (0: radix).
+            for name in ("finish_stripe_cols", "finish_network_rows"):
+                row[name] = int(metrics[name])
         row.update(counters)
         if self.config.fault_config:  # chaos layer (blades_tpu/faults)
             # The round's participation counts, plus the static fault

@@ -178,6 +178,10 @@ ROUND_RECORD_FIELDS: Dict[str, Tuple[tuple, bool]] = {
     # (ops/pallas_select.py::stripe_cols: 512 from 64 rows up, wider
     # below) and to which the matrix's columns were padded at allocation.
     "finish_stripe_cols": ((int,), False),
+    # The rows whose keys the compact fused finish's Median orders with a
+    # sorting network (ops/pallas_round.py::median_network: on row
+    # planes), or 0 where it runs the radix search.
+    "finish_network_rows": ((int,), False),
     # A task's own row counters on the streamed path (the round stamps
     # `counter_<name>`, the row takes them by prefix: hence the per-line
     # pragmas).  A sequence task, and its expert-share layer

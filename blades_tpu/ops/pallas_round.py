@@ -42,7 +42,12 @@ ms; PERF.md §6, PR 32).  The body does not know which it has: per-column
 values are ``(1,) + x.shape[1:]``, per-row values ``(rows, 1[, 1])``, and
 the float sums over the rows are added in one order (:func:`_sum_rows`),
 so both layouts of one matrix give the same aggregate and forged row to
-the bit.  ``sanitize`` is local to a grid step's block in either.
+the bit.  ``sanitize`` is local to a grid step's block in either.  One
+selection differs by layout: on planes the Median orders each column's
+keys with a sorting network (:func:`median_network`; an exchange is a
+``min`` and a ``max`` of whole vregs) where that costs fewer operations
+than the 16 radix steps over every row, and reads the radix search's
+keys off the sorted rows.
 
 Numerics: statistics run in f32 inside the kernel in the same formulas
 as :func:`blades_tpu.adversaries.base.benign_mean_std` (ddof=1), but
@@ -204,6 +209,102 @@ def _next_key_above_mult(keys, v, fkey):
         jax.lax.bitcast_convert_type(fnext ^ bias, jnp.int32),
     )
     return jax.lax.bitcast_convert_type(m, jnp.uint32) ^ bias
+
+
+@functools.lru_cache(maxsize=None)
+def sorting_network(rows: int) -> Tuple[Tuple[int, int], ...]:
+    """Batcher's odd-even merge sort over ``rows`` inputs, as the pairs
+    ``(i, j)``, ``i < j``, of its compare-exchanges in order (the smaller
+    value to ``i``): 19 at 8 rows, 63 at 16, 191 at 32.  Built at the next
+    power of two; the pairs that touch an index from ``rows`` up are
+    dropped, which is the same network over inputs padded with values
+    above all others (an exchange with such a value moves nothing)."""
+    n = 1 << max(rows - 1, 0).bit_length()
+    pairs = []
+    p = 1
+    while p < n:
+        k = p
+        while k >= 1:
+            for j in range(k % p, n - k, 2 * k):
+                for i in range(min(k, n - j - k)):
+                    if (i + j) // (2 * p) == (i + j + k) // (2 * p):
+                        pairs.append((i + j, i + j + k))
+            k //= 2
+        p *= 2
+    return tuple((i, j) for i, j in pairs if j < rows)
+
+
+@functools.lru_cache(maxsize=None)
+def _pruned_network(rows: int, n_tot: int, mult: int, key_bits: int):
+    """The exchanges of :func:`sorting_network` that the Median of the
+    multiset ``rows`` keys ``+ mult`` forged keys reads (ranks
+    ``(n_tot - 1) // 2`` and ``n_tot // 2``, each from the sorted rows at
+    ``k - mult`` and ``k``: :func:`_ranks_by_network`), or ``None`` where
+    the radix search costs less: two vector operations an exchange
+    against ``key_bits`` steps, each about three operations a row (a
+    compare, a convert, an add)."""
+    ranks = {(n_tot - 1) // 2, n_tot // 2}
+    needed = {j for k in ranks for j in (k - mult, k) if 0 <= j < rows}
+    kept = []
+    for i, j in reversed(sorting_network(rows)):
+        if i in needed or j in needed:
+            kept.append((i, j))
+            needed |= {i, j}
+    if 2 * len(kept) >= key_bits * rows * 3:
+        return None
+    return tuple(reversed(kept))
+
+
+def median_network(shape, nb: int, mult: int, keys16: bool, agg: tuple):
+    """The compare-exchanges by which the compact finish orders each
+    column's rows for the Median over a block of ``shape`` (``nb`` real
+    rows, the forged key counted ``mult`` times), or ``None`` where it runs
+    the radix search.  Only on row planes, ``(rows, s, 128)``: an exchange
+    there is a ``min`` and a ``max`` of whole vregs, where across the
+    sublanes of ``(rows, c)`` it is no plain vector operation."""
+    if agg[0] != "median" or len(shape) != 3:
+        return None
+    return _pruned_network(shape[0], nb + mult, mult, 16 if keys16 else 32)
+
+
+def finish_network_rows(shape, dtype, *, forged_mult: int, agg: tuple,
+                        num_real: Optional[int] = None) -> int:
+    """The rows the compact finish's sorting network orders over a matrix
+    of ``shape`` and ``dtype`` (:func:`median_network`, which the kernel
+    asks too), or 0 where the radix search runs."""
+    net = median_network(shape, num_real or shape[0], forged_mult,
+                         jnp.dtype(dtype) == jnp.bfloat16, agg)
+    return 0 if net is None else shape[0]
+
+
+def _ranks_by_network(keys, fkey, mult: int, pairs, ranks, wide: bool):
+    """The keys at ``ranks`` of the multiset ``keys + mult x fkey`` — the
+    keys :func:`_kth_key16_mult` (or, ``wide``, :func:`_kth_key_mult`)
+    finds — by ordering each column's rows with the exchanges ``pairs``
+    and reading rank ``k`` as ``min(max(fkey, s[k - mult]), s[k])`` (``s[j]``
+    below 0 is -inf, from ``len(s)`` up +inf).  The keys compare as int32:
+    16-bit keys as they are, full ones through the order-keeping ``u ^
+    0x8000_0000`` (Mosaic has no unsigned ``min``)."""
+    bias = jnp.uint32(0x80000000)
+
+    def signed(k):
+        return jax.lax.bitcast_convert_type(k ^ bias if wide else k,
+                                            jnp.int32)
+
+    def unsigned(v):
+        k = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return k ^ bias if wide else k
+
+    ks = signed(keys)
+    s = [ks[r:r + 1] for r in range(keys.shape[0])]
+    for i, j in pairs:
+        s[i], s[j] = jnp.minimum(s[i], s[j]), jnp.maximum(s[i], s[j])
+    f = signed(fkey)
+    out = []
+    for k in ranks:
+        v = f if k < mult else jnp.maximum(f, s[k - mult])
+        out.append(unsigned(v if k >= len(s) else jnp.minimum(v, s[k])))
+    return out
 
 
 def _over_row(reduce, x):
@@ -451,14 +552,21 @@ def _compact_kernel(x_ref, wb_ref, *refs,
     fkey = keys_of(forged)
     if akind == "median":
         k1, k2 = (n_tot - 1) // 2, n_tot // 2
-        v1 = kth(keys, k1, fkey, mult)
+        net = median_network(x.shape, nb_true, mult, keys16, agg)
+        if net is not None:
+            # Row planes: sort each column's keys, read both ranks off.
+            v1, v2 = _ranks_by_network(keys, fkey, mult, net, (k1, k2),
+                                       wide=not keys16)
+        else:
+            v1 = kth(keys, k1, fkey, mult)
+            if k2 != k1:
+                cnt_le = (jnp.sum((keys <= v1).astype(jnp.int32), axis=0,
+                                  keepdims=True)
+                          + mult * (fkey <= v1).astype(jnp.int32))
+                v2 = jnp.where(cnt_le >= k2 + 1, v1, nxt(keys, v1, fkey))
         if k2 == k1:
             o_ref[...] = vals(v1)
         else:
-            cnt_le = (jnp.sum((keys <= v1).astype(jnp.int32), axis=0,
-                              keepdims=True)
-                      + mult * (fkey <= v1).astype(jnp.int32))
-            v2 = jnp.where(cnt_le >= k2 + 1, v1, nxt(keys, v1, fkey))
             o_ref[...] = (vals(v1) + vals(v2)) * 0.5
     elif akind == "trimmed":
         k_cut = agg[1]

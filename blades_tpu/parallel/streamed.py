@@ -935,9 +935,14 @@ def streamed_step(
                 stripe_padded,
             )
 
-            finish_cols, shape = None, (n, d_model)
+            finish_cols, finish_net, shape = None, 0, (n, d_model)
             if compact:
+                from blades_tpu.ops.pallas_round import finish_network_rows
+
                 shape, finish_cols = compact_matrix(plan, nb, d_model)
+                finish_net = finish_network_rows(
+                    shape, update_dtype, forged_mult=malicious_prefix,
+                    agg=spec[1], num_real=nb)
             elif use_fused:
                 finish_cols = stripe_cols(n)
                 shape = (n, stripe_padded(d_model, n))
@@ -1087,6 +1092,9 @@ def streamed_step(
                 # (pallas_select.stripe_cols): the columns its allocation
                 # was padded to, and the grid is d_alloc over it.
                 metrics["finish_stripe_cols"] = np.int32(finish_cols)
+                # The rows its Median ordered by a sorting network, 0
+                # where it ran the radix search (the kernel's own rule).
+                metrics["finish_network_rows"] = np.int32(finish_net)
         return RoundState(server=server, client_opt=client_opt), metrics
 
     # Expose the jitted phases for profiling / inspection.  A round runs

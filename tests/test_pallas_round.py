@@ -400,6 +400,108 @@ def test_plane_finish_refuses_a_plane_that_is_not_128_lanes():
             forge=("alie", 0.7), interpret=True)
 
 
+# ---------------------------------------------------------------------------
+# On row planes the Median orders each column's keys by a sorting network
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n", range(1, 13))
+def test_sorting_network_sorts_every_zero_one_vector(n):
+    """The 0-1 principle: a network of compare-exchanges that sorts every
+    vector of 0s and 1s of length n sorts every vector of length n.
+    Batcher's network has 19 exchanges at 8 inputs, 63 at 16, 191 at 32."""
+    from blades_tpu.ops.pallas_round import sorting_network
+
+    net = sorting_network(n)
+    assert all(0 <= i < j < n for i, j in net)
+    v = (np.arange(1 << n)[:, None] >> np.arange(n)) & 1
+    for i, j in net:
+        v[:, i], v[:, j] = (np.minimum(v[:, i], v[:, j]),
+                            np.maximum(v[:, i], v[:, j]))
+    assert (np.diff(v, axis=1) >= 0).all()
+    assert [len(sorting_network(r)) for r in (8, 16, 32)] == [19, 63, 191]
+
+
+def _select_values(rng, shape, wide):
+    """Values drawn from a small pool, so that columns hold ties: the bf16
+    grid, -0 and +0, both infinities and a NaN of either sign, and with
+    full keys float32 values off the bf16 grid too."""
+    grid = (rng.normal(size=12) * np.exp(2 * rng.normal(size=12)))
+    pool = np.concatenate([
+        np.asarray(jnp.asarray(grid, jnp.bfloat16).astype(jnp.float32)),
+        [0.0, -0.0, np.inf, -np.inf, np.nan, -np.nan]]).astype(np.float32)
+    if wide:
+        pool = np.concatenate([pool, rng.normal(size=6).astype(np.float32)])
+    return rng.choice(pool, size=shape)
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["keys16", "keys32"])
+@pytest.mark.parametrize("rows", [2, 3, 8, 10, 16, 24, 32])
+def test_network_ranks_are_the_radix_searchs_keys(rows, wide):
+    """The Median's two ranks, read off the rows sorted by the network and
+    the forged key counted ``mult`` times, are the keys the radix search
+    finds (``_kth_key16_mult`` then ``_next_key16_above_mult``, or the
+    32-step pair for float32 storage), with rows past ``num_real`` at +inf
+    as the kernel masks them.  With the whole network every rank of the
+    multiset is that of a sort of its keys."""
+    from blades_tpu.ops import pallas_round as pr
+
+    keys_of = pr._keys_of if wide else pr._keys16_of
+    kth = pr._kth_key_mult if wide else pr._kth_key16_mult
+    nxt = pr._next_key_above_mult if wide else pr._next_key16_above_mult
+    rng = np.random.default_rng(2 * rows + wide)
+    for mult in (1, 2, 3):
+        for nb in sorted({rows, rows - 1} - {0}):
+            x = _select_values(rng, (rows, 2, 128), wide)
+            x[nb:] = np.inf
+            keys = keys_of(jnp.asarray(x))
+            fkey = keys_of(jnp.asarray(_select_values(rng, (1, 2, 128),
+                                                      wide)))
+            k1, k2 = (nb + mult - 1) // 2, (nb + mult) // 2
+            net = pr.median_network(x.shape, nb, mult, not wide,
+                                    ("median",))
+            got = pr._ranks_by_network(keys, fkey, mult, net, (k1, k2),
+                                       wide=wide)
+            v1 = kth(keys, k1, fkey, mult)
+            cnt_le = (jnp.sum((keys <= v1).astype(jnp.int32), axis=0,
+                              keepdims=True)
+                      + mult * (fkey <= v1).astype(jnp.int32))
+            v2 = jnp.where(cnt_le >= k2 + 1, v1, nxt(keys, v1, fkey))
+            np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(v1))
+            np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(v2))
+            every = pr._ranks_by_network(
+                keys, fkey, mult, pr.sorting_network(rows),
+                range(rows + mult), wide=wide)
+            want = np.sort(np.concatenate(
+                [np.asarray(keys), np.repeat(np.asarray(fkey), mult, 0)]),
+                axis=0)
+            np.testing.assert_array_equal(
+                np.concatenate([np.asarray(v) for v in every]), want)
+
+
+def test_finish_network_rows_follows_the_shape():
+    """The network runs for the Median on row planes wherever it costs
+    fewer vector operations than the radix search (two an exchange
+    against about three a row a bit): the language-model cells' 8 rows,
+    not the tall matrices' (rows, d) layout, nor the Trimmed mean, nor
+    planes so tall that Batcher's n log^2 n exchanges outgrow the
+    search."""
+    from blades_tpu.ops.pallas_round import finish_network_rows
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    median = dict(agg=("median",))
+    assert finish_network_rows((8, 3234080, 128), bf16, forged_mult=2,
+                               **median) == 8
+    assert finish_network_rows((24, 32, 128), f32, forged_mult=8,
+                               **median) == 24
+    assert finish_network_rows((752, 4904960), bf16, forged_mult=250,
+                               num_real=750, **median) == 0
+    assert finish_network_rows((8, 80, 128), bf16, forged_mult=2,
+                               agg=("trimmed", 2)) == 0
+    assert finish_network_rows((2048, 16, 128), bf16, forged_mult=683,
+                               **median) == 0
+
+
 @pytest.mark.parametrize("n,d", [(24, 1000), (17, 700), (64, 2048)])
 @pytest.mark.parametrize(
     "forge,agg",
@@ -808,6 +910,9 @@ def test_streamed_step_compact_branch_matches_chunked(monkeypatch):
         fr, client_block=4, update_dtype=jnp.float32, donate=False,
         malicious_prefix=f)
     s1, m1 = step_compact(state0, x, y, lengths, mal, key)
+    # Blocks of 4 lie under float32's storage tile of 8: the 8 benign rows
+    # are planes, and the Median orders them by the sorting network.
+    assert int(m1["finish_network_rows"]) == 8
 
     monkeypatch.setattr(pallas_round, "should_use", lambda n, d: False)
     monkeypatch.setattr(pallas_select, "kernel_applicable",
@@ -898,6 +1003,7 @@ def test_streamed_step_compact_with_row_padding(monkeypatch):
         fr, client_block=4, update_dtype=jnp.float32, donate=False,
         malicious_prefix=f)
     s1, m1 = step_compact(state0, x, y, lengths, mal, key)
+    assert int(m1["finish_network_rows"]) == 12   # 12 planes, no padding
 
     monkeypatch.setattr(pallas_round, "should_use", lambda n, d: False)
     monkeypatch.setattr(pallas_select, "kernel_applicable",
@@ -907,6 +1013,67 @@ def test_streamed_step_compact_with_row_padding(monkeypatch):
         fr, client_block=4, update_dtype=jnp.float32, donate=False)
     s2, m2 = step_chunked(state0, x, y, lengths, mal, key)
 
+    for k in ("train_loss", "agg_norm", "update_norm_mean"):
+        np.testing.assert_allclose(float(m1[k]), float(m2[k]), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(s1.server.params),
+                    jax.tree.leaves(s2.server.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_streamed_round_of_whole_tiles_keeps_the_radix_search(monkeypatch):
+    """Blocks of whole storage tiles (8 float32 lanes) keep the matrix
+    ``(rows, d)``, the rows on the sublanes, where an exchange across them
+    is no plain vector operation: the Median runs the radix search, the
+    round stamps ``finish_network_rows`` 0 beside its stripe's width, and
+    the round equals the chunked finish's."""
+    from blades_tpu import parallel
+    from blades_tpu.adversaries import get_adversary, make_malicious_mask
+    from blades_tpu.core import FedRound, Server, TaskSpec
+    from blades_tpu.ops import pallas_round, pallas_select
+
+    seen = []
+    compact = pallas_round.fused_finish_compact
+
+    def interpreted(updates, *args, **kw):
+        seen.append(updates.shape)
+        return compact(updates, *args, **kw, interpret=True)
+
+    monkeypatch.setattr(pallas_round, "should_use", lambda n, d: True)
+    monkeypatch.setattr(pallas_select, "kernel_applicable",
+                        lambda n, d: True)
+    monkeypatch.setattr(pallas_round, "fused_finish_compact", interpreted)
+
+    n, f = 24, 8
+    task = TaskSpec(model="mlp", input_shape=(8, 8, 1), num_classes=10,
+                    lr=0.1).build()
+    server = Server.from_config(aggregator="Median", lr=0.5)
+    adv = get_adversary("ALIE", num_clients=n, num_byzantine=f)
+    fr = FedRound(task=task, server=server, adversary=adv, batch_size=4,
+                  num_batches_per_round=1)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(n, 8, 8, 8, 1)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 10, size=(n, 8)), jnp.int32)
+    lengths = jnp.full((n,), 8, jnp.int32)
+    mal = make_malicious_mask(n, f)
+    key = jax.random.PRNGKey(5)
+
+    step = parallel.streamed.streamed_step(
+        fr, client_block=8, update_dtype=jnp.float32, donate=False,
+        malicious_prefix=f)
+    s1, m1 = step(fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal, key)
+    assert len(seen) == 1 and len(seen[0]) == 2   # (rows, d_alloc)
+    assert int(m1["finish_stripe_cols"]) == stripe_cols(seen[0][0])
+    assert int(m1["finish_network_rows"]) == 0
+    assert isinstance(m1["finish_network_rows"], np.integer)
+
+    monkeypatch.setattr(pallas_round, "should_use", lambda n, d: False)
+    monkeypatch.setattr(pallas_select, "kernel_applicable",
+                        lambda n, d: False)
+    step_chunked = parallel.streamed.streamed_step(
+        fr, client_block=8, update_dtype=jnp.float32, donate=False)
+    s2, m2 = step_chunked(fr.init(jax.random.PRNGKey(0), n), x, y, lengths,
+                          mal, key)
     for k in ("train_loss", "agg_norm", "update_norm_mean"):
         np.testing.assert_allclose(float(m1[k]), float(m2[k]), rtol=1e-5)
     for a, b in zip(jax.tree.leaves(s1.server.params),
@@ -981,6 +1148,10 @@ def test_streamed_round_at_8_rows_keeps_a_row_a_plane(
     assert int(m1["finish_stripe_cols"]) == width
     assert isinstance(m1["finish_stripe_cols"], np.integer)  # a host stamp
     assert "finish_stripe_cols" in ROUND_RECORD_FIELDS
+    # The Median over 8 row planes orders them by the sorting network.
+    assert int(m1["finish_network_rows"]) == 8
+    assert isinstance(m1["finish_network_rows"], np.integer)
+    assert "finish_network_rows" in ROUND_RECORD_FIELDS
     assert (int(m1["store_blocks"]), int(m1["store_blocks_aligned"]),
             int(m1["surplus_lanes"])) == (blocks, blocks, surplus)
     assert step.train_block._cache_size() == 1
@@ -994,6 +1165,7 @@ def test_streamed_round_at_8_rows_keeps_a_row_a_plane(
     s2, m2 = build()(fr.init(jax.random.PRNGKey(0), n), x, y, lengths, mal,
                      key)
     assert "finish_stripe_cols" not in m2   # the chunked finish: no stripe
+    assert "finish_network_rows" not in m2
     assert int(m2["store_blocks_aligned"]) == 0   # (rows, d): part tiles
     for k in ("train_loss", "agg_norm", "update_norm_mean"):
         np.testing.assert_allclose(float(m1[k]), float(m2[k]), rtol=1e-5)

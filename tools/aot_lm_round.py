@@ -52,6 +52,7 @@ def main() -> int:
 
     from aot_train_block import store_ops
     from blades_tpu.algorithms import get_algorithm_class
+    from blades_tpu.ops.pallas_round import finish_network_rows
     from blades_tpu.parallel.streamed import (
         block_plan,
         compact_matrix,
@@ -95,6 +96,8 @@ def main() -> int:
     # The matrix by the rule the round builds it by: a row a plane here,
     # where a block of one lane lies under a storage tile.
     matrix, finish_cols = compact_matrix(plan, n - f, d)
+    net_rows = finish_network_rows(matrix, dtype, forged_mult=f,
+                                   agg=("median",), num_real=n - f)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -129,6 +132,7 @@ def main() -> int:
     print(json.dumps({"model": model, "num_params": d, "plan": plan._asdict(),
                       "matrix": list(matrix),
                       "finish_stripe_cols": finish_cols,
+                      "finish_network_rows": net_rows,
                       "topology": "v5e:2x2"}),
           flush=True)
     with mock.patch.object(jax, "default_backend", return_value="tpu"):

@@ -8,8 +8,10 @@ it is the aliased tile copy (ops/pallas_store.py) or a
 proved of its row offset; the count of rematerialised instructions; and
 ``memory_analysis()``.
 
-    JAX_PLATFORMS=cpu python3 tools/aot_train_block.py [model] [n] [client_block]
+    JAX_PLATFORMS=cpu python3 tools/aot_train_block.py [model] [n] [client_block] [--finish]
 
+``--finish`` also compiles the round's ``_finish_fused_compact`` at the
+same shapes and writes its optimized program beside the block's.
 ``resnet10 1000 25`` and ``resnet18 768 24`` are the two cells: the first
 has a padded last block (47 blocks of 16, 2 surplus lanes), the second
 none, and each is ONE program.  Nothing runs on a device and no cell runs
@@ -63,9 +65,10 @@ def store_ops(hlo: str, rows: int) -> list[dict]:
 
 
 def main() -> int:
-    model = sys.argv[1] if len(sys.argv) > 1 else "resnet10"
-    n = int(sys.argv[2]) if len(sys.argv) > 2 else 1000
-    client_block = int(sys.argv[3]) if len(sys.argv) > 3 else 25
+    args = [a for a in sys.argv[1:] if a != "--finish"]
+    model = args[0] if args else "resnet10"
+    n = int(args[1]) if len(args) > 1 else 1000
+    client_block = int(args[2]) if len(args) > 2 else 25
     f = n // 4
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -154,7 +157,22 @@ def main() -> int:
     with open(path, "w") as fh:
         fh.write(hlo)
     out["hlo"] = path
-    print(json.dumps(out))
+    print(json.dumps(out), flush=True)
+    if "--finish" in sys.argv:
+        # The round's compact fused finish at the same shapes, its
+        # optimized program written beside the block's.
+        t = time.time()
+        with mock.patch.object(jax, "default_backend", return_value="tpu"):
+            lowered = step.finish_fused_compact.lower(
+                on_chip(state.server), shape(matrix, dtype),
+                shape((n,), jnp.bool_), shape((n,), jnp.float32),
+                shape((2,), jnp.uint32), nb_real=n - f)
+        hlo = lowered.compile().as_text()
+        path = os.path.join(out_dir, f"aot_finish_{model}_{n}.hlo")
+        with open(path, "w") as fh:
+            fh.write(hlo)
+        print(json.dumps({"program": "_finish_fused_compact",
+                          "compile_s": time.time() - t, "hlo": path}))
     return 0
 
 
